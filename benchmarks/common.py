@@ -97,7 +97,7 @@ class Csv:
         payload = {
             "meta": {
                 "backend": jax.default_backend(),
-                "interpret_mode": jax.default_backend() != "tpu",
+                "interpret_mode": jax.default_backend() == "cpu",
                 "python": platform.python_version(),
                 "jax": jax.__version__,
             },

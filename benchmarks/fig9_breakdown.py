@@ -9,7 +9,7 @@ where the paper's Fig. 9 gains are largest for the layout step):
                         k independent full-hash evaluations
       3. +multhash      one base hash + salt multiplies (paper §4.2)
       4. +vectorized    bulk lockstep engine (hash phase + gathered word
-                        tests — the Θ/Φ vectorization analogue, §4.1/§4.3)
+                        tests — the Θ vectorization analogue, §4.1/§4.3)
     add:
       5. cbf_add        k scattered RMWs per key (sequential, exact)
       6. sbf_add        one block RMW per key
@@ -48,7 +48,7 @@ def _khash_masks(spec, keys):
 
 def _contains_loop(spec, filt, keys, masks):
     """Per-key sequential probe (the unvectorized execution model)."""
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     starts = (H.block_index(h2, spec.n_blocks) * jnp.uint32(spec.s)
               ).astype(jnp.int32)
 
@@ -99,7 +99,7 @@ def run(csv: Csv):
     t6 = time_fn(jax.jit(lambda f, k: V.add_loop(sbf, f, k)),
                  V.init(sbf), add_keys, warmup=1, reps=3)
     # block-sorted insertion order = partition locality
-    h2 = H.xxh32_u64x2(add_keys, H.SEED_BLOCK)
+    h2 = H.block_hash(add_keys)
     order = jnp.argsort(H.block_index(h2, sbf.n_blocks))
     sorted_keys = add_keys[order]
     t7 = time_fn(jax.jit(lambda f, k: V.add_loop(sbf, f, k)),
@@ -114,7 +114,7 @@ def run(csv: Csv):
                 n_ops=N_ADD)
 
     # ---- probe-strategy column (kernel schedule, interpret mode) -----------
-    # The Pallas kernels on a small VMEM-resident spec: per-key (Θ, Φ) loop
+    # The Pallas kernels on a small VMEM-resident spec: per-key Θ loop
     # vs the whole-tile gather engine. Interpret-mode wall time tracks the
     # number of scheduled ops, so the ratio is the schedule-count win the
     # vectorized path must show (acceptance: gather wins or ties).

@@ -1,12 +1,12 @@
-"""Paper Table 1/2 (Θ, Φ) layout grid on the Pallas kernels.
+"""Paper Table 1/2 Θ layout grid on the Pallas kernels.
 
-This container is CPU-only, so the kernels execute in interpret mode; the
-grid therefore measures the SCHEDULE STRUCTURE (loads issued, loop trip
-counts, per-step vector widths) rather than TPU wall-clock. Two artifacts:
+Off the TPU the kernels execute in interpret mode; the grid then measures
+the SCHEDULE STRUCTURE (loop trip counts) rather than TPU wall-clock. The
+paper's second axis, Φ words per load, has no counterpart here: every probe
+loads the whole row holding its block. Two artifacts:
 
-  * structural metrics per layout: loads per block, unrolled steps,
-    vector width per compare — derived analytically from (Θ, Φ, s) exactly
-    as the paper's Section 4.1 derivations;
+  * structural metrics per layout: the tuner's structural score and the
+    loop/gather schedule-step counts;
   * interpret-mode relative times (same engine overhead for all layouts, so
     ratios indicate schedule cost on the traced graph).
 
@@ -23,40 +23,26 @@ from benchmarks.common import Csv, keys_u64x2, time_fn
 from repro.core import tuning
 from repro.core import variants as V
 from repro.kernels import ops
-from repro.kernels.sbf import Layout, default_layout
+from repro.kernels.sbf import default_layout
 
 M_BITS = 1 << 20
 N_KEYS = 2048
 K = 16
 
 
-def structural_cost(s: int, theta: int, phi: int, op: str) -> dict:
-    """Analytical schedule metrics (paper §4.1 reasoning, S=32 words)."""
-    loads_per_block = s // phi                       # wide loads issued
-    steps = max(s // (theta * phi), 1)               # strided loop trips
-    vec_width = theta * phi                          # lanes per compare
-    return {"loads": loads_per_block, "steps": steps, "vec_width": vec_width}
-
-
 def run(csv: Csv, measure: bool = True):
     for B in (128, 256, 512):
         spec = V.FilterSpec("sbf", M_BITS, K, block_bits=B)
-        s = spec.s
         keys = keys_u64x2(N_KEYS, seed=3)
         filt = V.add_scatter(spec, V.init(spec), keys)
-        layouts = sorted({(t, p) for t in (1, 2, 4, 8) for p in (1, 2, 4, 8)
-                          if p <= s and t * p <= max(s, 8)})
         base_t = None
-        for theta, phi in layouts:
-            lay = Layout(theta, phi)
-            sc = structural_cost(s, theta, phi, "contains")
+        for lay in tuning.valid_layouts(spec, tile=256):
             steps_loop = tuning.probe_schedule_steps(spec, lay, "contains",
                                                      256, "loop")
             steps_gather = tuning.probe_schedule_steps(spec, lay, "contains",
                                                        256, "gather")
             probe_win = "gather" if steps_gather <= steps_loop else "loop"
-            derived = (f"loads={sc['loads']} steps={sc['steps']} "
-                       f"vec={sc['vec_width']} "
+            derived = (f"score={tuning.structural_score(spec, lay, 'contains'):.3f} "
                        f"probe_steps(loop/gather)={steps_loop:.0f}/"
                        f"{steps_gather:.0f} probe_best={probe_win}")
             if measure:
@@ -67,7 +53,7 @@ def run(csv: Csv, measure: bool = True):
                     filt, keys, warmup=1, reps=3)
                 base_t = base_t or t
                 derived += f" rel_time={t/base_t:.2f}"
-            csv.add(f"layout/B{B}/Θ{theta}Φ{phi}", (t * 1e6) if measure else 0,
+            csv.add(f"layout/B{B}/{lay}", (t * 1e6) if measure else 0,
                     derived, n_ops=N_KEYS)
         # the whole-tile gather engine is layout-free: one row per (B, op)
         for op in ("contains", "add"):
@@ -92,7 +78,7 @@ def run(csv: Csv, measure: bool = True):
         d = default_layout(spec, "contains")
         plan = tuning.tune_plan(spec, "contains", regime="vmem", tile=256)
         csv.add(f"layout/B{B}/default", 0,
-                f"picked=Θ{d.theta}Φ{d.phi} (paper rule Θ̂=max(1,B/256)) "
+                f"picked={d} (paper rule Θ̂=max(1,B/256)) "
                 f"plan_probe={plan.probe} plan_depth={plan.depth}")
 
 

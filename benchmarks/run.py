@@ -4,7 +4,7 @@ Prints ``name,us_per_call,derived`` CSV. Mapping to the paper:
     gups          -> §5.2 random-access speed-of-light bound
     table1_dram   -> Table 1 (DRAM-resident sweep over block size)
     table2_cache  -> Table 2 (cache-resident sweep)
-    layout_grid   -> Tables 1/2 (Θ, Φ) dimension (structural, Pallas kernels)
+    layout_grid   -> Tables 1/2 Θ dimension (structural, Pallas kernels)
     fig4_frontier -> Figure 4 (throughput vs FPR frontier, measured FPR)
                      + per-configuration speed-of-light fraction: measured
                      Mops/s / calibrated perfmodel ceiling (repro.perfmodel)
@@ -40,6 +40,7 @@ import argparse
 import sys
 
 from benchmarks.common import Csv
+from repro.compile_cache import enable_compile_cache
 
 # Records faster than this in the BASELINE are dominated by dispatch/
 # allocator noise, not schedule cost, and swing up to ~1.5x run-to-run on
@@ -165,6 +166,7 @@ def main(argv=None) -> None:
                     help="where the replay bench writes its span-trace "
                          "JSONL + Prometheus snapshot (CI artifact)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     csv = Csv()
     csv.header()

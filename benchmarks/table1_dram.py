@@ -6,7 +6,7 @@ Sweeps block size B over the same words-per-block range as the paper
 vectorized execution engine, and reports GElem/s + fraction of the GUPS
 speed-of-light (paper's headline metric).
 
-The (Θ, Φ) layout dimension of Table 1 is swept structurally on the Pallas
+The Θ layout dimension of Table 1 is swept structurally on the Pallas
 kernels by benchmarks/layout_grid.py (interpret mode — schedule structure,
 not wall-clock).
 """

@@ -22,6 +22,7 @@ from jax.sharding import Mesh
 from repro import api
 from repro.data import dedup as D
 from repro.data import pipeline as DP
+from repro.compile_cache import enable_compile_cache
 
 
 def single_host():
@@ -117,6 +118,7 @@ def multi_host_replicated():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     single_host()
     streaming_with_eviction()
     streaming_with_fingerprint_eviction()

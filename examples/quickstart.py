@@ -6,9 +6,11 @@ import numpy as np
 
 from repro import api
 from repro.core.hashing import random_u64x2
+from repro.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     print(f"registered engines: {api.backends()}")
 
     # Size for 100k items at 16 bits/key; sectorized layout, 256-bit blocks.

@@ -17,6 +17,7 @@ from repro.configs import get_config
 from repro.models.model import build_model
 from repro.serving.engine import Engine, Request
 from repro.serving.ngram_guard import NGramGuard
+from repro.compile_cache import enable_compile_cache
 
 
 def tiny_model():
@@ -27,6 +28,7 @@ def tiny_model():
 
 
 def main():
+    enable_compile_cache()
     cfg = tiny_model()
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))

@@ -24,6 +24,7 @@ from repro.data import pipeline as DP
 from repro.models.model import build_model
 from repro.runtime.fault_tolerance import DriverConfig, TrainingDriver
 from repro.training.train_step import make_train_step, train_state_init
+from repro.compile_cache import enable_compile_cache
 
 
 def build_100m():
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = build_100m()
     model = build_model(cfg)

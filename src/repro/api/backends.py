@@ -1,5 +1,5 @@
 """Single-host engines: jnp reference, two Pallas regimes, and the
-forgetting engines (counting, windowed).
+forgetting and fingerprint engines (counting, windowed, cuckoo, quotient).
 
 Cost model (relative, lower = better): the jnp engine is the baseline at
 1.0 on every platform. On TPU the Pallas kernels win (the whole point of
@@ -9,9 +9,15 @@ magnitude slower, so ``"auto"`` keeps them for validation only.
 The ``counting`` and ``windowed`` engines claim their workloads
 *exclusively*: ``countingbf`` specs belong to ``counting`` and a context
 with ``generations`` set belongs to ``windowed``, so the plain bit engines
-decline both (see ``_plain_bits``). Each dispatches internally — Pallas
-kernels on TPU, jnp reference elsewhere — because there is exactly one
-engine per forgetting strategy and it must be fast everywhere.
+decline both (see ``_plain_bits``).
+
+Which engines run Pallas kernels on a TPU is declared per engine
+(``Backend.tpu_impl``, shown by ``registry.describe()``): ``pallas-vmem``,
+``pallas-hbm`` and ``counting`` do; ``windowed``, ``cuckoo`` and
+``quotient`` run their XLA (jnp) implementation there, because their
+kernels use in-kernel sorts, gathers and vector slicing the chip's
+compiler refuses. Their kernels stay reachable in interpret mode
+(cuckoo/quotient ``options.impl="pallas"``, ``repro.kernels.ops``).
 """
 from __future__ import annotations
 
@@ -99,6 +105,7 @@ class JnpBackend(Backend):
 
 class _PallasBackend(Backend):
     regime = "auto"
+    tpu_impl = "pallas"
 
     def _fits_vmem(self, spec: FilterSpec) -> bool:
         from repro.kernels.sbf import VMEM_FILTER_BYTES
@@ -130,7 +137,7 @@ class _PallasBackend(Backend):
 
 class PallasVmemBackend(_PallasBackend):
     """Pallas TPU kernels with the filter pinned in VMEM — the paper's
-    cache-resident regime ((Θ, Φ) layout selectable via options.layout).
+    cache-resident regime (Θ layout selectable via options.layout).
     Banks run natively: the whole (B, n_words) bank is pinned in VMEM and
     B members execute as ONE launch (member-offset block starts)."""
 
@@ -151,6 +158,8 @@ class PallasVmemBackend(_PallasBackend):
         if not (_single_host(ctx) and _plain_bits(spec, ctx)
                 and ops.kernel_supported(spec)):
             return False
+        if spec.variant == "cbf" and ctx.platform != "cpu":
+            return False          # the classical kernel does not lower
         if ctx.bank is not None:
             # the bank kernels need block locality and a whole-bank VMEM fit
             return (spec.variant != "cbf"
@@ -205,14 +214,16 @@ class PallasHbmBackend(_PallasBackend):
 
 class CountingBackend(Backend):
     """Counting Bloom filter (variant='countingbf'): packed 4-bit saturating
-    counters enabling ``remove`` and ``decay``. Pallas kernels on TPU
-    (ownership-partitioned RMW instead of atomicAdd), jnp bit-plane
-    reference elsewhere. 4x the memory of the equivalent bit filter.
+    counters enabling ``remove`` and ``decay``. Pallas kernels on TPU (the
+    sbf row engine on counter rows; sequential-ownership RMW instead of
+    atomicAdd), jnp bit-plane reference on the CPU. 4x the memory of the
+    equivalent bit filter.
     Banks run natively (counter super-filter; one launch in VMEM) — the
     generic fill-trick fallback is FORBIDDEN here because counting updates
     are not idempotent."""
 
     name = "counting"
+    tpu_impl = "pallas"
     supports_remove = True
     supports_decay = True
     supports_bank = True
@@ -234,7 +245,8 @@ class CountingBackend(Backend):
         return V.init(spec)                      # (4*n_words,) counters
 
     def _tpu(self) -> bool:
-        return jax.default_backend() == "tpu"
+        from repro.kernels import ops
+        return not ops._interpret()
 
     def _kw(self, options):
         kw = {"layout": options.layout, "probe": options.probe,
@@ -403,10 +415,11 @@ class CuckooBackend(Backend):
     eviction. ``remove`` at ~1x storage — half to a quarter of the
     counting filter's 4-bit counters — with an EXPLICIT insert-failure
     signal accumulated in the traced ``Filter.state`` leaf
-    (``Filter.insert_failures``); no counters, no decay. Pallas VMEM
-    kernels on TPU (whole-tile gather contains, block-sorted sequential
-    inserts), jnp reference elsewhere — bit-identical by construction
-    (``options.impl`` pins one path explicitly). Banks run through the
+    (``Filter.insert_failures``); no counters, no decay. jnp reference on
+    every platform; ``options.impl="pallas"`` runs the Pallas VMEM kernels
+    (whole-tile gather contains, block-sorted sequential inserts) in
+    interpret mode — bit-identical by construction; they do not lower for
+    the TPU. Banks run through the
     generic vmap machinery with proper valid masks — the OR-idempotent
     fill trick is FORBIDDEN (fingerprint inserts are not idempotent)."""
 
@@ -437,12 +450,8 @@ class CuckooBackend(Backend):
         return jnp.zeros((), jnp.uint32)   # cumulative failed inserts
 
     def _use_kernels(self, spec: FilterSpec, options) -> bool:
-        if options.impl == "pallas":
-            return True
-        if options.impl == "jnp":
-            return False
-        assert options.impl is None, options.impl
-        return jax.default_backend() == "tpu"
+        assert options.impl in (None, "jnp", "pallas"), options.impl
+        return options.impl == "pallas"
 
     def _tile(self, options):
         return options.tile        # None -> fingerprint.CUCKOO_ADD_TILE
@@ -527,9 +536,10 @@ class QuotientBackend(CuckooBackend):
     exactly recoverable, so union = decode both + rebuild, and resize =
     re-split p = q + r at the new table size — no raw keys anywhere
     (DESIGN.md §15). Capacity failures accumulate in the traced
-    ``Filter.insert_failures`` state leaf exactly like cuckoo's. Pallas
-    VMEM kernels on TPU (fused run-scan contains, sequential-ownership
-    decode+rebuild updates), jnp reference elsewhere — bit-identical by
+    ``Filter.insert_failures`` state leaf exactly like cuckoo's. jnp
+    reference on every platform; ``options.impl="pallas"`` runs the Pallas
+    VMEM kernels (fused run-scan contains, sequential-ownership
+    decode+rebuild updates) in interpret mode — bit-identical by
     construction. Banks: vmapped scalar ops with REAL valid masks
     (fingerprint inserts are not idempotent)."""
 

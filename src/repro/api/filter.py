@@ -36,6 +36,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import hashing as H
 from repro.core import variants as V
 from repro.core.variants import FilterSpec
 from repro.api import registry
@@ -90,6 +91,18 @@ def as_keys(keys) -> jnp.ndarray:
     if keys.dtype != jnp.uint32:
         keys = keys.astype(jnp.uint32)
     return keys
+
+
+def check_hash_layout(layout) -> None:
+    """Refuse words written under another hash layout (``None``: a state
+    saved before the layout was recorded, i.e. layout 1). Their keys sit in
+    other blocks, so such a filter would answer 'absent' for keys it holds;
+    it has to be rebuilt from its keys."""
+    if layout != H.HASH_LAYOUT:
+        raise ValueError(
+            f"filter state written under hash layout {layout or 1}; this "
+            f"build reads layout {H.HASH_LAYOUT} and would give false "
+            "negatives on it. Rebuild the filter from its keys.")
 
 
 def _prod(shape: Tuple[int, ...]) -> int:
@@ -540,7 +553,8 @@ class Filter:
         see DESIGN.md §10)."""
         state = {"words": self.dense_words(),
                  "spec": dataclasses.asdict(self.spec),
-                 "backend": self.backend}
+                 "backend": self.backend,
+                 "hash_layout": H.HASH_LAYOUT}
         if self.engine.stateful_ops and self.state is not None:
             # fingerprint engines: the table IS canonical and the failure
             # counter is real operational state — both round-trip exactly
@@ -557,6 +571,7 @@ class Filter:
     @classmethod
     def from_state(cls, state: dict, backend: Optional[str] = None,
                    options: BackendOptions = BackendOptions()) -> "Filter":
+        check_hash_layout(state.get("hash_layout"))
         spec = FilterSpec(**{k: (v if isinstance(v, str) else int(v))
                              for k, v in state["spec"].items()})
         name = backend or state.get("backend", "jnp")

@@ -115,6 +115,10 @@ class Backend:
     # (and why ``jax.vmap`` over the bank axis sees valid scalar filters).
     words_ndim: int = 1
 
+    # What this engine runs on a TPU: "pallas" where its kernels compile
+    # for the chip, "xla" where it runs its jnp implementation there.
+    tpu_impl: str = "xla"
+
     # -- capability / ranking ------------------------------------------------
     def supports(self, spec: FilterSpec, ctx: SelectionContext) -> bool:
         raise NotImplementedError
@@ -156,6 +160,7 @@ class Backend:
                 "supports_count": self.supports_count,
                 "supports_merge": self.supports_merge,
                 "supports_resize": self.supports_resize,
+                "tpu_impl": self.tpu_impl,
                 "bits_per_key_at_ref_fpr":
                     None if bpk is None else round(bpk, 2),
                 "ref_fpr": self.REF_FPR}

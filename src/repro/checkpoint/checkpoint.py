@@ -49,10 +49,19 @@ def _flatten(tree):
     return keys, [l for _, l in flat], treedef
 
 
+def _holds_filter(tree) -> bool:
+    from repro.api import Filter
+    return any(isinstance(x, Filter) for x in jax.tree_util.tree_leaves(
+        tree, is_leaf=lambda x: isinstance(x, Filter)))
+
+
 def save(ckpt_dir: str, step: int, state: Any, *, sync: bool = True,
          keep: int = 3, extra: Optional[Dict] = None):
     """Write ``state`` (any pytree of arrays) atomically under ckpt_dir."""
     keys, leaves, _ = _flatten(state)
+    if _holds_filter(state):
+        from repro.core.hashing import HASH_LAYOUT
+        extra = {**(extra or {}), "filter_hash_layout": HASH_LAYOUT}
     # snapshot to host BEFORE going async — device buffers may be donated
     host_leaves = [np.asarray(l) for l in leaves]
 
@@ -125,7 +134,8 @@ def save_filter(ckpt_dir: str, step: int, filt, *, sync: bool = True,
     state = filt.to_state()
     extra = dict(extra or {})
     extra.update({"filter_spec": state["spec"],
-                  "filter_backend": state["backend"]})
+                  "filter_backend": state["backend"],
+                  "filter_hash_layout": state["hash_layout"]})
     if "bank_shape" in state:
         extra["filter_bank_shape"] = state["bank_shape"]
     if "options" in state:
@@ -157,6 +167,7 @@ def restore_filter(ckpt_dir: str, *, step: Optional[int] = None,
     ``backend``/``options`` re-home the state onto a different engine than
     the one that wrote it (default: the writer's engine)."""
     from repro.api import BackendOptions, Filter
+    from repro.api.filter import check_hash_layout
     from repro.core.variants import FilterSpec
 
     if step is None:
@@ -167,11 +178,13 @@ def restore_filter(ckpt_dir: str, *, step: Optional[int] = None,
     with open(os.path.join(d, "manifest.json")) as f:
         manifest = json.load(f)
     extra = manifest["extra"]
+    check_hash_layout(extra.get("filter_hash_layout"))
     spec_d = extra["filter_spec"]
     spec = FilterSpec(**spec_d)
     words = np.load(os.path.join(d, manifest["leaves"]["filter_words"]["file"]))
     state = {"words": words, "spec": spec_d,
-             "backend": extra["filter_backend"]}
+             "backend": extra["filter_backend"],
+             "hash_layout": extra["filter_hash_layout"]}
     if "filter_state" in manifest["leaves"]:
         state["engine_state"] = np.load(
             os.path.join(d, manifest["leaves"]["filter_state"]["file"]))
@@ -199,6 +212,9 @@ def restore(ckpt_dir: str, template: Any, *, step: Optional[int] = None,
     d = os.path.join(ckpt_dir, f"step_{step:08d}")
     with open(os.path.join(d, "manifest.json")) as f:
         manifest = json.load(f)
+    if _holds_filter(template):
+        from repro.api.filter import check_hash_layout
+        check_hash_layout(manifest["extra"].get("filter_hash_layout"))
     keys, leaves, treedef = _flatten(template)
     shard_leaves = (jax.tree.leaves(shardings,
                                     is_leaf=lambda x: hasattr(x, "device_set"))

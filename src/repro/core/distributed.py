@@ -41,12 +41,18 @@ from __future__ import annotations
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import hashing as H
 from repro.core import variants as V
-from repro.core.variants import FilterSpec, WORD_BITS
+from repro.core.variants import FilterSpec
+
+
+def _zeros(shape, sharding) -> jnp.ndarray:
+    """Zeroed uint32 words created in place on every device's shard; a
+    host-side or single-device array would have to fit one device first."""
+    return jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
+                   out_shardings=sharding)()
 
 
 # ---------------------------------------------------------------------------
@@ -60,9 +66,7 @@ def or_allreduce(x: jnp.ndarray, axis_name: str, method: str = "butterfly"
     butterfly: log2(n) ppermute rounds (n must be a power of two).
     gather:    all_gather + local OR fold (any n; more memory).
     """
-    # psum of a literal folds to the static axis size (works across jax
-    # versions; jax.lax.axis_size only exists in newer releases)
-    n = int(jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     if method == "gather" or (n & (n - 1)) != 0:
         g = jax.lax.all_gather(x, axis_name, axis=0)         # (n, ...)
         acc = g[0]
@@ -84,7 +88,7 @@ def or_allreduce(x: jnp.ndarray, axis_name: str, method: str = "butterfly"
 def _local_fingerprints(spec: FilterSpec, keys: jnp.ndarray, blocks_per_seg: int):
     """(local word starts, masks) for keys known to belong to this segment."""
     h1 = H.xxh32_u64x2(keys, H.SEED_PATTERN)
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     blk = H.block_index(h2, spec.n_blocks)
     blk_local = blk & jnp.uint32(blocks_per_seg - 1)
     masks = V.block_patterns(spec, h1)
@@ -102,16 +106,12 @@ def _segment_contains(spec: FilterSpec, seg_words: jnp.ndarray,
 
 def _segment_add(spec: FilterSpec, seg_words: jnp.ndarray, keys: jnp.ndarray,
                  valid: jnp.ndarray, blocks_per_seg: int) -> jnp.ndarray:
+    """OR the keys' masks into the resident segment (``variants.or_rows``:
+    row gathers and scatters, no temporary the size of the segment)."""
     starts, masks = _local_fingerprints(spec, keys, blocks_per_seg)
     masks = masks * valid[:, None].astype(jnp.uint32)
-    idx = (starts[:, None] + jnp.arange(spec.s, dtype=jnp.int32)[None, :]).reshape(-1)
-    vals = masks.reshape(-1)
-    acc = seg_words
-    for b in range(WORD_BITS):                                # bit-plane OR scatter
-        plane = (vals >> jnp.uint32(b)) & jnp.uint32(1)
-        cnt = jnp.zeros_like(seg_words).at[idx].add(plane)
-        acc = acc | ((cnt > 0).astype(jnp.uint32) << jnp.uint32(b))
-    return acc
+    return V.or_rows(spec, seg_words, starts // spec.s, masks,
+                     n_rows=blocks_per_seg)
 
 
 # ---------------------------------------------------------------------------
@@ -121,10 +121,8 @@ def _segment_add(spec: FilterSpec, seg_words: jnp.ndarray, keys: jnp.ndarray,
 def replicated_init(spec: FilterSpec, mesh: Mesh, axis: str = "data"
                     ) -> jnp.ndarray:
     """(n_dev, n_words) zeroed replicas, one per device along ``axis``."""
-    n_dev = mesh.shape[axis]
-    sharding = NamedSharding(mesh, P(axis))
-    return jax.device_put(jnp.zeros((n_dev, spec.n_words), jnp.uint32),
-                          sharding)
+    return _zeros((mesh.shape[axis], spec.n_words),
+                  NamedSharding(mesh, P(axis)))
 
 
 def replicated_add_local(spec: FilterSpec, mesh: Mesh, axis: str,
@@ -135,8 +133,8 @@ def replicated_add_local(spec: FilterSpec, mesh: Mesh, axis: str,
     def body(w, keys):
         return V.add_scatter(spec, w[0], keys[0])[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded)
 
 
@@ -147,7 +145,8 @@ def replicated_sync(spec: FilterSpec, mesh: Mesh, axis: str,
     def body(w):
         return or_allreduce(w, axis, method=method)
 
-    fn = shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                       check_vma=True)
     return fn(words)
 
 
@@ -159,8 +158,8 @@ def replicated_contains_local(spec: FilterSpec, mesh: Mesh, axis: str,
     def body(w, keys):
         return V.contains(spec, w[0], keys[0])[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded)
 
 
@@ -174,8 +173,8 @@ def replicated_contains_merged(spec: FilterSpec, mesh: Mesh, axis: str,
         merged = or_allreduce(w[0], axis)
         return V.contains(spec, merged, keys[0])[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded)
 
 
@@ -189,8 +188,7 @@ def sharded_init(spec: FilterSpec, mesh: Mesh, axis: str = "data"
     n_dev = mesh.shape[axis]
     assert spec.n_blocks % n_dev == 0
     assert (n_dev & (n_dev - 1)) == 0, "device count must be pow2 (segments)"
-    sharding = NamedSharding(mesh, P(axis))
-    return jax.device_put(jnp.zeros((spec.n_words,), jnp.uint32), sharding)
+    return _zeros((spec.n_words,), NamedSharding(mesh, P(axis)))
 
 
 def _route(spec: FilterSpec, keys: jnp.ndarray, n_dev: int, capacity: int):
@@ -200,7 +198,7 @@ def _route(spec: FilterSpec, keys: jnp.ndarray, n_dev: int, capacity: int):
     """
     blocks_per_seg = spec.n_blocks // n_dev
     n = keys.shape[0]
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     blk = H.block_index(h2, spec.n_blocks)
     seg = (blk // jnp.uint32(blocks_per_seg)).astype(jnp.int32)
     order = jnp.argsort(seg, stable=True)
@@ -232,8 +230,8 @@ def sharded_add(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
         flat_v = recv_v.reshape(-1)
         return _segment_add(spec, w, flat_k, flat_v, bps)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded)
 
 
@@ -255,9 +253,7 @@ def bankshard_init(spec: FilterSpec, mesh: Mesh, axis: str, bank: int
     """(bank, n_words) zeroed members, bank axis sharded along ``axis``."""
     n_dev = mesh.shape[axis]
     assert bank % n_dev == 0, (bank, n_dev)
-    sharding = NamedSharding(mesh, P(axis))
-    return jax.device_put(jnp.zeros((bank, spec.n_words), jnp.uint32),
-                          sharding)
+    return _zeros((bank, spec.n_words), NamedSharding(mesh, P(axis)))
 
 
 def _route_members(keys: jnp.ndarray, member: jnp.ndarray,
@@ -303,9 +299,9 @@ def bankshard_add(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
         return V.bank_add_rows(spec, w, rk.reshape(-1, 2), rm.reshape(-1),
                                valid=rv.reshape(-1))
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis), P(axis), P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis), P(axis), P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded, member_sharded, valid_sharded)
 
 
@@ -331,8 +327,8 @@ def bankshard_contains(spec: FilterSpec, mesh: Mesh, axis: str,
         mine = back.reshape(-1)[dest * capacity + rank]
         return jnp.where(keep, mine, True)[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded, member_sharded)
 
 
@@ -354,8 +350,8 @@ def sharded_contains(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
         mine = back.reshape(-1)[seg * capacity + rank]
         return jnp.where(keep, mine, True)[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=True)
     return fn(words, keys_sharded)
 
 

@@ -75,7 +75,7 @@ def cuckoo_hashes(spec: FilterSpec, keys: jnp.ndarray):
     block stream — same split as the Bloom kernels' phase 1. ``fp == 0``
     is remapped to 1 (0 encodes an empty slot)."""
     h1 = H.xxh32_u64x2(keys, H.SEED_PATTERN)
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     fp = H.mulshift(h1, _FP_SALT, spec.slot_bits)
     fp = jnp.where(fp == 0, jnp.uint32(1), fp)
     b1 = H.block_index(h2, spec.n_buckets).astype(jnp.int32)

@@ -47,7 +47,7 @@ def segment_ids(spec: FilterSpec, keys: jnp.ndarray, n_segments: int) -> jnp.nda
     """Segment owning each key's block. Segments are contiguous block ranges."""
     assert spec.n_blocks % n_segments == 0
     blocks_per_seg = spec.n_blocks // n_segments
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK) if keys.shape[-1] == 2 else H.xxh32_u32(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys) if keys.shape[-1] == 2 else H.xxh32_u32(keys, H.SEED_BLOCK)
     blk = H.block_index(h2, spec.n_blocks)
     return (blk // jnp.uint32(blocks_per_seg)).astype(jnp.int32)
 

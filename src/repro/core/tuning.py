@@ -1,9 +1,9 @@
-"""(Θ, Φ, probe, depth, segments) autotuner — the paper's Table 1/2 grid
+"""(Θ, probe, depth, segments) autotuner — the paper's Table 1/2 grid
 search as a library, extended to every axis the kernels expose.
 
 The paper's headline empirical result is that the optimal vectorization
 layout depends on (operation, block size, residency). ``tune_layout`` sweeps
-the valid (Θ, Φ) grid for a (spec, tile) and returns the fastest layout;
+the valid Θ grid for a (spec, tile) and returns the fastest layout;
 ``tune_plan`` additionally picks the probe strategy (per-key loop vs
 whole-tile gather), the HBM DMA pipeline depth and the partitioned-add
 segment count, returning a :class:`Plan` that `api.backends` threads into
@@ -62,14 +62,13 @@ class Plan:
     mix: str = "full"              # "full" | "cheap" fused double-hash
 
     def to_dict(self) -> dict:
-        return {"theta": self.layout.theta, "phi": self.layout.phi,
-                "probe": self.probe, "depth": self.depth,
+        return {"theta": self.layout.theta, "probe": self.probe, "depth": self.depth,
                 "n_segments": self.n_segments, "coop": self.coop,
                 "mix": self.mix}
 
     @classmethod
     def from_dict(cls, d: dict) -> "Plan":
-        return cls(Layout(int(d["theta"]), int(d["phi"])), str(d["probe"]),
+        return cls(Layout(int(d["theta"])), str(d["probe"]),
                    int(d["depth"]), int(d["n_segments"]),
                    str(d.get("coop", "none")), str(d.get("mix", "full")))
 
@@ -133,35 +132,26 @@ def _plan_key(spec: FilterSpec, op: str, regime: str, mode: str,
 
 
 # ---------------------------------------------------------------------------
-# (Θ, Φ) layout grid
+# Θ layout grid
 # ---------------------------------------------------------------------------
 
 def valid_layouts(spec: FilterSpec, tile: int = DEFAULT_TILE) -> List[Layout]:
-    s = spec.s
-    out = []
-    for theta in (1, 2, 4, 8, 16):
-        if tile % theta:
-            continue
-        for phi in (1, 2, 4, 8, 16, 32):
-            if phi <= s and s % phi == 0 and theta * phi <= max(s, 8):
-                out.append(Layout(theta, phi))
-    return out
+    return [Layout(theta) for theta in (1, 2, 4, 8, 16)
+            if tile % theta == 0 and theta <= max(spec.s, 8)]
 
 
 def structural_score(spec: FilterSpec, lay: Layout, op: str) -> float:
-    """Lower is better. Mirrors §4.1: wide loads amortize issue cost; too
-    much Θ under-utilizes lanes for lookups but tightens RMW windows for
-    adds (the paper's Θ̂ rules, encoded as a soft preference)."""
+    """Lower is better. Mirrors §4.1: unrolling Θ keys amortizes the loop
+    trip's scalar overhead; too much Θ under-utilizes lanes for lookups but
+    tightens RMW windows for adds (the paper's Θ̂ rules, encoded as a soft
+    preference). Every probe loads one whole row, so no load count enters."""
     s = spec.s
-    loads = s // lay.phi                      # load instructions per block
-    steps = max(s // (lay.theta * lay.phi), 1)
-    score = loads + 0.5 * steps
+    score = 1.0 / lay.theta                   # trip overhead per key
     if op == "contains":
         target = max(1, spec.block_bits // 256)
         score += 0.25 * abs(lay.theta - target)
     else:                                     # add: fully horizontal wins
-        score += 0.25 * (s - min(lay.theta * lay.phi, s)) / max(s, 1)
-        score += 0.1 * loads
+        score += 0.25 * (s - min(lay.theta, s)) / max(s, 1)
     return score
 
 
@@ -169,8 +159,8 @@ def probe_schedule_steps(spec: FilterSpec, lay: Layout, op: str, tile: int,
                          probe: str, bank: int = 1) -> float:
     """Interpret-mode schedule-step count of one key tile's phase 2.
 
-    loop:   (tile/Θ) trips, each issuing s/Φ loads + 1 fused compare (or
-            s/Φ RMW pairs for add) — the per-key scalar walk.
+    loop:   (tile/Θ) trips, each issuing one row load + one fused compare
+            (or one row store for add) — the per-key scalar walk.
     gather: a constant number of whole-tile vector ops — index build,
             ONE gather, ONE fused compare for contains; sort (log²-depth
             bitonic analogue), segmented scan, gather, scatter for add.
@@ -185,8 +175,7 @@ def probe_schedule_steps(spec: FilterSpec, lay: Layout, op: str, tile: int,
     import math
     lg_b = math.log2(max(bank, 1))
     if probe == "loop":
-        per_trip = spec.s // lay.phi + (1 if op == "contains" else
-                                        spec.s // lay.phi)
+        per_trip = 2
         return (tile // lay.theta) * per_trip * (1.0 + 0.05 * lg_b)
     if op == "contains":
         return 3.0 + 0.25 * lg_b
@@ -260,7 +249,7 @@ def tune_layout(spec: FilterSpec, op: str = "contains",
             cands.append(lay.validate(spec, tile))
         except AssertionError:
             continue
-    cands = sorted(set(cands), key=lambda l: (l.theta, l.phi))
+    cands = sorted(set(cands), key=lambda l: l.theta)
     if not cands:
         return default_layout(spec, op), []
     if mode == "structural":
@@ -278,21 +267,30 @@ def tune_layout(spec: FilterSpec, op: str = "contains",
 # Full-plan sweep: probe strategy x depth x segments (+ the layout grid)
 # ---------------------------------------------------------------------------
 
-def _model_candidates(coop: str, mix: str):
+def _model_candidates(coop: str, mix: str, regime: str = "vmem"):
     """The (probe, coop, mix) candidate grid under optional pinning.
     coop="subtile" supersedes the probe strategy in the kernels, so
     cooperative candidates are canonicalized to probe="gather" — one
     spelling per distinct schedule, no duplicate cache entries. Order
     breaks predicted-cost ties toward the non-coop baseline and the full
-    mix's cheap sibling is ranked by its strictly-lower flop count."""
+    mix's cheap sibling is ranked by its strictly-lower flop count.
+    Off the CPU only schedules the chip's compiler accepts are offered
+    (``sbf.lowers``); a pin that leaves none raises."""
+    from repro.kernels import ops
+    from repro.kernels.sbf import lowers
     coops = ("none", "subtile") if coop == "auto" else (coop,)
     mixes = ("cheap", "full") if mix == "auto" else (mix,)
     out = []
     for c in coops:
         probes = ("gather", "loop") if c == "none" else ("gather",)
         for p in probes:
+            if not (ops._interpret() or lowers(regime, p, c)):
+                continue
             for m in mixes:
                 out.append((p, c, m))
+    if not out:
+        raise ValueError(f"coop={coop!r} has no schedule that lowers for "
+                         f"{jax.default_backend()} in the {regime} regime")
     return out
 
 
@@ -325,7 +323,8 @@ def tune_plan(spec: FilterSpec, op: str = "contains", regime: str = "vmem",
     working set while the gather probe stays whole-tile constant.
     """
     assert op in ("contains", "add") and bank >= 1
-    from repro.kernels.sbf import COOPS, DMA_DEPTHS, MIXES, PROBES
+    from repro.kernels import ops
+    from repro.kernels.sbf import COOPS, DMA_DEPTHS, MIXES, PROBES, lowers
     assert coop == "auto" or coop in COOPS, coop
     assert mix == "auto" or mix in MIXES, mix
     key = _plan_key(spec, op, regime, mode, tile, bank, coop, mix)
@@ -340,7 +339,9 @@ def tune_plan(spec: FilterSpec, op: str = "contains", regime: str = "vmem",
             # the user deletes the cache file by hand.
             if (plan.probe in PROBES and plan.depth in DMA_DEPTHS
                     and plan.n_segments in TUNABLE_SEGMENTS
-                    and plan.coop in COOPS and plan.mix in MIXES):
+                    and plan.coop in COOPS and plan.mix in MIXES
+                    and (ops._interpret()
+                         or lowers(regime, plan.probe, plan.coop))):
                 plan.layout.validate(spec, tile)
                 return plan
         except (KeyError, ValueError, TypeError, AssertionError):
@@ -348,14 +349,14 @@ def tune_plan(spec: FilterSpec, op: str = "contains", regime: str = "vmem",
     layout, _ = tune_layout(spec, op, mode=mode, n_keys=n_keys,
                             repeats=repeats, tile=tile)
     if mode == "measure":
-        if regime == "vmem":
+        if regime == "vmem" and ops._interpret():
             t_loop = _measure(spec, op, n_keys, repeats, layout=layout,
                               tile=tile, probe="loop", regime="vmem")
             t_gather = _measure(spec, op, n_keys, repeats, tile=tile,
                                 probe="gather", regime="vmem")
             probe = "gather" if t_gather <= t_loop else "loop"
         else:
-            probe = "gather"
+            probe = "gather" if regime == "hbm" else "loop"
         if regime == "hbm" and op == "contains":
             timed = {d: _measure(spec, op, n_keys, repeats, regime="hbm",
                                  tile=tile, depth=d) for d in TUNABLE_DEPTHS}
@@ -378,7 +379,7 @@ def tune_plan(spec: FilterSpec, op: str = "contains", regime: str = "vmem",
                                n_keys=tile, bank=bank).flops
             return (t, flops)      # flop tie-break: cheap mix wins ties
 
-        cands = _model_candidates(coop, mix)
+        cands = _model_candidates(coop, mix, regime)
         probe, best_coop, best_mix = min(
             cands, key=lambda pcm: score(*pcm, 2))
         depth = min(TUNABLE_DEPTHS,

@@ -462,21 +462,25 @@ def contains_rows(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray
     h1, h2 = _hashes(keys)
     blk = H.block_index(h2, spec.n_blocks).astype(jnp.int32)
     masks = block_patterns(spec, h1)
-    rows = filt.reshape(spec.n_blocks, spec.s)[blk]          # one gather/key
+    rows = filt[_row_words(spec, blk)]                       # one row/key
     return jnp.all((rows & masks) == masks, axis=-1)
+
+
+def _row_words(spec: FilterSpec, blk: jnp.ndarray) -> jnp.ndarray:
+    """(n, s) flat word indices of each block's row. Indexing the 1-D
+    words with these keeps every array lane-dense; a (n_blocks, s) view
+    would pad s words to 128 lanes on TPU (16x the filter at s=8)."""
+    return blk[:, None] * spec.s + jnp.arange(spec.s, dtype=jnp.int32)
 
 
 def add_rows(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray
              ) -> jnp.ndarray:
-    """Sorted segmented-OR bulk insert (§Perf iteration B2).
+    """Whole-batch bulk insert by row gather + row scatter (§Perf B2,
+    see :func:`or_rows`).
 
-    Hypothesis: per-key RMW loops pay XLA while-loop overhead (~10 us/key)
-    and bit-plane scatters pay 32 full-filter passes. Instead: sort keys by
-    block, OR the masks of same-block keys with a segmented associative
-    scan (no filter traffic), then ONE row gather + ONE row scatter.
-    Duplicate scatter indices carry identical values, so ``set`` is
-    deterministic. This is the ownership/partitioning idea executed at the
-    vector-engine level.
+    Per-key RMW loops pay XLA while-loop overhead (~10 us/key) and
+    bit-plane scatters pay 32 full-filter passes; this touches each key's
+    row a few times at most.
     """
     if spec.variant == "cbf":
         return add_scatter(spec, filt, keys)
@@ -488,25 +492,39 @@ def add_rows(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray
 
 def or_rows(spec: FilterSpec, filt: jnp.ndarray, blk: jnp.ndarray,
             masks: jnp.ndarray, n_rows: Optional[int] = None) -> jnp.ndarray:
-    """Conflict-free whole-batch OR of per-key ``masks`` into their blocks.
+    """Exact whole-batch OR of per-key ``masks`` into their blocks.
 
-    Sort by block, segment-OR the masks of same-block keys, then ONE row
-    gather + ONE row scatter. Duplicate scatter indices carry identical
-    values, so ``set`` is deterministic. Rows with all-zero masks are OR
-    no-ops, which is what makes this the overflow-residual backstop of the
-    jit partition path (`kernels.ops`) as well as the `add_rows` engine.
+    Each round gathers every key's row and scatters ``row | mask`` back,
+    but only for the words still missing some of the key's bits. Where
+    several keys share a word, one write lands per round and it adds that
+    key's missing bits, so every round makes progress and the loop ends
+    after as many rounds as the most keys sharing one word (a handful at
+    realistic loads). The result is ``filt | masks`` per word whatever the
+    scatter's order among duplicates. No sort and no segmented scan:
+    both compile for minutes on TPU at millions of keys. Rows with
+    all-zero masks are OR no-ops, which is what makes this the
+    overflow-residual backstop of the jit partition path (`kernels.ops`)
+    as well as the `add_rows` engine.
 
     ``n_rows`` overrides the row count (default ``spec.n_blocks``) so a
     *bank* of B filters can be treated as one super-filter of B*n_blocks
     rows — ``blk`` then carries member-offset block ids (see ``bank_*``).
     """
-    order = jnp.argsort(blk)
-    sb = blk[order]
-    or_full = segment_totals(sb, masks[order], jnp.bitwise_or)    # (n, s)
-    filt2d = filt.reshape(n_rows or spec.n_blocks, spec.s)
-    rows = filt2d[sb]
-    new = filt2d.at[sb].set(rows | or_full)                   # identical dups
-    return new.reshape(-1)
+    assert filt.shape == ((n_rows or spec.n_blocks) * spec.s,), filt.shape
+    idx = _row_words(spec, blk)
+    drop = jnp.int32(filt.shape[0])                 # out of range: no write
+
+    def missing(f):
+        return (f[idx] & masks) != masks
+
+    def body(carry):
+        f, todo = carry
+        f = f.at[jnp.where(todo, idx, drop)].set(f[idx] | masks, mode="drop")
+        return f, missing(f)
+
+    out, _ = jax.lax.while_loop(lambda c: jnp.any(c[1]), body,
+                                (filt, missing(filt)))
+    return out
 
 
 def add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
@@ -680,49 +698,60 @@ def _counting_layout(spec: FilterSpec, keys: jnp.ndarray):
     return blk, masks
 
 
-def _bit_counts(spec: FilterSpec, blk: jnp.ndarray, masks: jnp.ndarray,
-                valid: Optional[jnp.ndarray],
-                word_offset: Optional[jnp.ndarray] = None,
-                total_words: Optional[int] = None) -> jnp.ndarray:
-    """(total_words, 32) uint32: number of (valid) keys targeting each
-    logical bit. Column order == flat nibble order, so it aligns with
-    :func:`_unpack_nibbles` without any permutation.
+def _nibble_counts(spec: FilterSpec, keys: jnp.ndarray,
+                   valid: Optional[jnp.ndarray],
+                   member: Optional[jnp.ndarray] = None,
+                   total_words: Optional[int] = None):
+    """Yield, for nibble b = 0..7, (b, (4*T,) int32 number of (valid) keys
+    whose pattern sets nibble b of each counter word) (T = logical words;
+    ``spec.n_words`` for one filter, ``B * n_words`` for a bank, where
+    ``member`` offsets each key's words).
 
-    ``word_offset``/``total_words`` extend the index space to a *bank* of
-    filters viewed as one flat word array (offset = member * n_words)."""
-    word_idx = (blk[:, None] * jnp.uint32(spec.s)
-                + jnp.arange(spec.s, dtype=jnp.uint32)[None, :])
-    if word_offset is not None:
-        word_idx = word_idx + word_offset.astype(jnp.uint32)[:, None]
-    word_idx = word_idx.astype(jnp.int32).reshape(-1)
-    vals = masks
-    if valid is not None:
-        vals = vals * valid.astype(jnp.uint32)[:, None]
-    vals = vals.reshape(-1)
-    counts = jnp.zeros((total_words or spec.n_words, WORD_BITS), jnp.uint32)
-    for b in range(WORD_BITS):
-        plane = (vals >> jnp.uint32(b)) & jnp.uint32(1)
-        counts = counts.at[word_idx, b].add(plane)
-    return counts
-
-
-def _unpack_nibbles(spec: FilterSpec, counters: jnp.ndarray) -> jnp.ndarray:
-    """(4*T,) packed -> (T, 32) one uint32 per logical bit (T = any number
-    of logical words — ``spec.n_words`` for one filter, ``B * n_words`` for
-    a flattened bank)."""
-    nib = jnp.stack([(counters >> jnp.uint32(COUNTER_BITS * b))
-                     & jnp.uint32(COUNTER_MAX)
-                     for b in range(NIBBLES_PER_WORD)], axis=-1)
-    return nib.reshape(-1, WORD_BITS)
-
-
-def _pack_nibbles(spec: FilterSpec, nib: jnp.ndarray) -> jnp.ndarray:
-    """(n_words, 32) -> (4*n_words,) packed counter words."""
-    nib = nib.reshape(-1, NIBBLES_PER_WORD)
-    out = jnp.zeros((nib.shape[0],), jnp.uint32)
+    One scatter-add per nibble of the k bit positions per key, duplicates
+    within a key dropped (a pattern sets such a bit once, as
+    ``block_patterns`` ORs it once). Every array keeps keys or counter
+    words on the last axis: an (n, s) or (words, 32) layout pads to 128
+    lanes on a TPU (48 GiB for 128 MiB of counters), and a scatter holds
+    a few copies of its operand, so each holds one nibble plane."""
+    assert spec.variant == "countingbf"
+    h1, h2 = _hashes(keys)
+    word0 = H.block_index(h2, spec.n_blocks).astype(jnp.int32) * spec.s
+    if member is not None:
+        word0 = word0 + member.astype(jnp.int32) * jnp.int32(spec.n_words)
+    n_cw = (total_words or spec.n_words) * COUNTER_WORDS_PER_WORD
+    keep = (jnp.ones(keys.shape[:1], jnp.bool_) if valid is None
+            else valid.astype(jnp.bool_))
+    # bit i of the pattern lands in word i % s (see ``block_patterns``)
+    pos = [H.mulshift(h1, H.SALTS[i], _LOG2_WORD).astype(jnp.int32)
+           for i in range(spec.k)]
+    nib, cw = [], []
+    for i in range(spec.k):
+        first = keep
+        for j in range(i % spec.s, i, spec.s):
+            first = first & (pos[j] != pos[i])
+        word = word0 + i % spec.s
+        nib.append(jnp.where(first, pos[i] % NIBBLES_PER_WORD, -1))
+        cw.append(word * COUNTER_WORDS_PER_WORD + pos[i] // NIBBLES_PER_WORD)
+    nib, cw = jnp.stack(nib), jnp.stack(cw)                   # (k, n)
     for b in range(NIBBLES_PER_WORD):
-        out = out | (nib[:, b].astype(jnp.uint32)
-                     << jnp.uint32(COUNTER_BITS * b))
+        plane = jnp.zeros((n_cw,), jnp.int32)
+        yield b, plane.at[jnp.where(nib == b, cw, n_cw)].add(1, mode="drop")
+
+
+def _apply_counts(counters: jnp.ndarray, counts, op: str) -> jnp.ndarray:
+    """Saturating add (``op="add"``) or guarded subtract of per-counter
+    ``counts`` (the planes of :func:`_nibble_counts`) into packed (W,)
+    counter words."""
+    out = jnp.zeros_like(counters)
+    for b, count in counts:
+        shift = jnp.uint32(COUNTER_BITS * b)
+        nib = ((counters >> shift) & jnp.uint32(COUNTER_MAX)).astype(jnp.int32)
+        if op == "add":
+            new = jnp.minimum(nib + count, COUNTER_MAX)
+        else:
+            new = jnp.where(nib == COUNTER_MAX, COUNTER_MAX,
+                            jnp.maximum(nib - count, 0))
+        out = out | (new.astype(jnp.uint32) << shift)
     return out
 
 
@@ -735,23 +764,15 @@ def counting_add(spec: FilterSpec, counters: jnp.ndarray, keys: jnp.ndarray,
     counting updates are NOT idempotent, so repeat-key padding is forbidden
     here (see kernels/ops.py)."""
     assert spec.is_counting
-    blk, masks = _counting_layout(spec, keys)
-    counts = _bit_counts(spec, blk, masks, valid)
-    nib = _unpack_nibbles(spec, counters)
-    new = jnp.minimum(nib + counts, jnp.uint32(COUNTER_MAX))
-    return _pack_nibbles(spec, new)
+    return _apply_counts(counters, _nibble_counts(spec, keys, valid), "add")
 
 
 def counting_remove(spec: FilterSpec, counters: jnp.ndarray, keys: jnp.ndarray,
                     valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Vectorized bulk decrement (guarded: 0 floors, 15 is sticky)."""
     assert spec.is_counting
-    blk, masks = _counting_layout(spec, keys)
-    counts = _bit_counts(spec, blk, masks, valid)
-    nib = _unpack_nibbles(spec, counters).astype(jnp.int32)
-    dec = jnp.maximum(nib - counts.astype(jnp.int32), 0).astype(jnp.uint32)
-    new = jnp.where(nib == COUNTER_MAX, jnp.uint32(COUNTER_MAX), dec)
-    return _pack_nibbles(spec, new)
+    return _apply_counts(counters, _nibble_counts(spec, keys, valid),
+                         "remove")
 
 
 def counting_contains(spec: FilterSpec, counters: jnp.ndarray,
@@ -838,17 +859,16 @@ def bank_contains_rows(spec: FilterSpec, words: jnp.ndarray,
     """(N,) bool membership of flat routed keys against a (B, n_words)
     bank — one row gather over the B*n_blocks super-filter."""
     assert spec.variant != "cbf" and not spec.is_counting
-    B = words.shape[0]
     blk, masks = bank_block_ids(spec, keys, member)
-    rows = words.reshape(B * spec.n_blocks, spec.s)[blk]
+    rows = words.reshape(-1)[_row_words(spec, blk)]
     return jnp.all((rows & masks) == masks, axis=-1)
 
 
 def bank_add_rows(spec: FilterSpec, words: jnp.ndarray, keys: jnp.ndarray,
                   member: jnp.ndarray,
                   valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Bulk OR of flat routed keys into a (B, n_words) bank: one sorted
-    segmented-OR + one row scatter over the super-filter. ``valid`` zeroes
+    """Bulk OR of flat routed keys into a (B, n_words) bank: ``or_rows``
+    over the super-filter. ``valid`` zeroes
     the masks of padding slots (an OR no-op), so routed batches pad safely."""
     assert spec.variant != "cbf" and not spec.is_counting
     B = words.shape[0]
@@ -867,18 +887,9 @@ def bank_counting_update(spec: FilterSpec, counters: jnp.ndarray,
     into a (B, 4*n_words) counter bank (counting super-filter)."""
     assert spec.is_counting and op in ("add", "remove")
     B = counters.shape[0]
-    blk, masks = _counting_layout(spec, keys)
-    counts = _bit_counts(spec, blk, masks, valid,
-                         word_offset=member * jnp.int32(spec.n_words),
-                         total_words=B * spec.n_words)
-    nib = _unpack_nibbles(spec, counters.reshape(-1))   # (B*n_words, 32)
-    if op == "add":
-        new = jnp.minimum(nib + counts, jnp.uint32(COUNTER_MAX))
-    else:
-        nibi = nib.astype(jnp.int32)
-        dec = jnp.maximum(nibi - counts.astype(jnp.int32), 0).astype(jnp.uint32)
-        new = jnp.where(nib == COUNTER_MAX, jnp.uint32(COUNTER_MAX), dec)
-    return _pack_nibbles(spec, new).reshape(B, -1)
+    counts = _nibble_counts(spec, keys, valid, member=member,
+                            total_words=B * spec.n_words)
+    return _apply_counts(counters.reshape(-1), counts, op).reshape(B, -1)
 
 
 def bank_counting_contains(spec: FilterSpec, counters: jnp.ndarray,

@@ -2,7 +2,7 @@
 
 sbf.py — blocked-variant kernels (BBF/RBBF/SBF/CSBF share the skeleton;
           the variant-specific pattern generation is trace-time dispatched):
-          (Θ, Φ) layouts, VMEM-/HBM-resident regimes, partitioned add.
+          Θ layouts, VMEM-/HBM-resident regimes, partitioned add.
 cbf.py — classical-filter baseline kernels.
 ops.py — jit'd dispatch (regime + layout selection, padding).
 ref.py — pure-jnp oracles; every kernel is verified bit-exactly against them.

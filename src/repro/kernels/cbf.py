@@ -23,7 +23,7 @@ from repro.kernels.sbf import DEFAULT_TILE, _take_scalar
 
 def _positions(spec: FilterSpec, keys: jnp.ndarray):
     h1 = H.xxh32_u64x2(keys, H.SEED_PATTERN)
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     pos = V.cbf_positions(spec, h1, h2)                          # (n, k)
     widx = (pos >> jnp.uint32(5)).astype(jnp.int32)
     bits = jnp.uint32(1) << (pos & jnp.uint32(31))
@@ -36,7 +36,7 @@ def _contains_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec, tile: int
     def body(i, acc):
         ok = jnp.bool_(True)
         for j in range(spec.k):                                  # static unroll
-            w = pl.load(filt_ref, (pl.ds(_take_scalar(widx[:, j], i), 1),))[0]
+            w = filt_ref[pl.ds(_take_scalar(widx[:, j], i), 1)][0]
             ok = jnp.logical_and(ok, (w & _take_scalar(bits[:, j], i)) != 0)
         return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
 
@@ -54,15 +54,16 @@ def _add_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec, tile: int):
     def body(i, carry):
         for j in range(spec.k):                                  # k scattered RMWs
             idx = (pl.ds(_take_scalar(widx[:, j], i), 1),)
-            w = pl.load(out_ref, idx)
-            pl.store(out_ref, idx, w | _take_scalar(bits[:, j], i)[None])
+            w = out_ref[idx]
+            out_ref[idx] = w | _take_scalar(bits[:, j], i)[None]
         return carry
 
     jax.lax.fori_loop(0, tile, body, jnp.int32(0))
 
 
 def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  tile: int = DEFAULT_TILE, interpret: bool = True) -> jnp.ndarray:
+                  *, interpret: bool,
+                  tile: int = DEFAULT_TILE) -> jnp.ndarray:
     n = keys.shape[0]
     assert n % tile == 0
     kern = functools.partial(_contains_kernel, spec=spec, tile=tile)
@@ -78,7 +79,8 @@ def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 
 def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-             tile: int = DEFAULT_TILE, interpret: bool = True) -> jnp.ndarray:
+             *, interpret: bool,
+             tile: int = DEFAULT_TILE) -> jnp.ndarray:
     n = keys.shape[0]
     assert n % tile == 0
     kern = functools.partial(_add_kernel, spec=spec, tile=tile)

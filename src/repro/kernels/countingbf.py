@@ -1,11 +1,10 @@
 """Pallas TPU kernels for the counting Bloom filter (countingbf).
 
-Same (Θ, Φ) layout machinery and residency regimes as ``kernels.sbf``, but
+Same Θ layout machinery and residency regimes as ``kernels.sbf``, but
 every logical bit is a packed 4-bit saturating counter, so three things
 change:
 
-* rows are **4s counter words** per block instead of s bit words — the Φ
-  chunking runs over the expanded row;
+* rows are **4s counter words** per block instead of s bit words;
 * the per-key op is a **read-modify-write with carry-free nibble
   arithmetic** (``sat_inc_word`` / ``guard_dec_word`` from core.variants —
   plain vector ops, so the identical helpers run in the jnp reference);
@@ -34,10 +33,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import hashing as H
 from repro.core import variants as V
 from repro.core.variants import FilterSpec
+from repro.kernels import sbf
 from repro.kernels.sbf import (COOPS, DEFAULT_DMA_DEPTH, DEFAULT_TILE,
                                DMA_DEPTHS, Layout, MIXES, PROBES,
-                               _COMPILER_PARAMS, _hash_streams, _mask_row,
-                               _take_scalar)
+                               _check_lowers, _hash_streams)
 
 
 def _cfingerprints(spec: FilterSpec, keys: jnp.ndarray,
@@ -57,62 +56,21 @@ def _cfingerprints(spec: FilterSpec, keys: jnp.ndarray,
     return cstarts, cmasks
 
 
+def counting_fingerprints(spec: FilterSpec, keys: jnp.ndarray,
+                          mix: str = "full", valid: jnp.ndarray = None):
+    """Phase 1 in XLA for the compiled kernels: (counter-row starts (n,)
+    int32, nibble-increment words (n, 4s)), valid-masked when ``valid`` is
+    given. For a probe the increment words double as the occupancy masks:
+    a key is present where ``nib_nonzero(row)`` covers them."""
+    starts, masks = sbf.fingerprints(spec, keys, mix)
+    cmasks = V.expand_mask_words(masks)
+    if valid is not None:
+        cmasks = cmasks * valid.astype(jnp.uint32)[:, None]
+    return starts * jnp.int32(V.COUNTER_WORDS_PER_WORD), cmasks
+
+
 def _update(op: str):
     return V.sat_inc_word if op == "add" else V.guard_dec_word
-
-
-def counting_layout(spec: FilterSpec, layout: Layout, tile: int) -> Layout:
-    """Validate a (Θ, Φ) layout against the expanded 4s-word counter row."""
-    cs = spec.counter_row_words
-    phi = min(layout.phi, cs)
-    assert cs % phi == 0, f"phi={phi} must divide 4s={cs}"
-    assert tile % layout.theta == 0
-    return Layout(layout.theta, phi)
-
-
-def default_counting_layout(spec: FilterSpec, op: str) -> Layout:
-    """Counting analogue of ``sbf.default_layout``: same Θ̂ rules, Φ scaled
-    to the 4x-wider counter row."""
-    cs = spec.counter_row_words
-    if op == "contains":
-        theta = min(max(1, spec.block_bits // 256), 8)
-        return Layout(theta, max(1, min(8, cs // theta)))
-    theta = min(spec.s, 8)
-    return Layout(theta, max(1, min(cs // theta, 8)))
-
-
-# ---------------------------------------------------------------------------
-# VMEM-resident kernels
-# ---------------------------------------------------------------------------
-
-def _update_vmem_kernel(keys_ref, valid_ref, filt_ref, out_ref, *,
-                        spec: FilterSpec, layout: Layout, tile: int, op: str,
-                        mix: str):
-    cs, theta, phi = spec.counter_row_words, layout.theta, layout.phi
-    n_chunks = cs // phi
-    update = _update(op)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    cstarts, cmasks = _cfingerprints(spec, keys_ref[...], valid_ref[...],
-                                     mix=mix)
-
-    def group_body(g, carry):
-        base = g * theta
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(cstarts, i)
-            mrow = _mask_row(cmasks, i, cs)
-            for c in range(n_chunks):               # static unroll over Φ chunks
-                idx = (pl.ds(st + c * phi, phi),)
-                w = pl.load(out_ref, idx)
-                inc = jax.lax.dynamic_slice(mrow, (c * phi,), (phi,))
-                pl.store(out_ref, idx, update(w, inc))
-        return carry
-
-    jax.lax.fori_loop(0, tile // theta, group_body, jnp.int32(0))
 
 
 # ---------------------------------------------------------------------------
@@ -161,36 +119,6 @@ def _contains_vmem_gather_kernel(keys_ref, filt_ref, out_ref, *,
     rows = jnp.take(filt_ref[...].reshape(-1, cs), blk, axis=0)  # (tile, 4s)
     occ = V.collapse_counter_words(rows)                       # (tile, s)
     out_ref[...] = jnp.all((occ & masks) == masks, axis=-1)
-
-
-def _contains_vmem_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
-                          layout: Layout, tile: int, mix: str):
-    cs, theta, phi = spec.counter_row_words, layout.theta, layout.phi
-    n_chunks = cs // phi
-    h1, h2 = _hash_streams(keys_ref[...], mix)
-    blk = H.block_index(h2, spec.n_blocks)
-    masks = V.block_patterns(spec, h1, batched=False)          # logical (n, s)
-    cstarts = (blk * jnp.uint32(cs)).astype(jnp.int32)
-
-    def group_body(g, acc):
-        base = g * theta
-        lanes = []
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(cstarts, i)
-            chunks = [pl.load(filt_ref, (pl.ds(st + c * phi, phi),))
-                      for c in range(n_chunks)]
-            lanes.append((jnp.concatenate(chunks),            # (4s,)
-                          _mask_row(masks, i, spec.s)))
-        Cm = jnp.stack([c for c, _ in lanes])                 # (theta, 4s)
-        Mm = jnp.stack([m for _, m in lanes])                 # (theta, s)
-        occ = V.collapse_counter_words(Cm)                    # (theta, s)
-        ok = jnp.all((occ & Mm) == Mm, axis=-1)
-        return jax.lax.dynamic_update_slice(acc, ok, (base,))
-
-    out = jax.lax.fori_loop(0, tile // theta, group_body,
-                            jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = out
 
 
 # ---------------------------------------------------------------------------
@@ -256,8 +184,8 @@ def _contains_vmem_coop_kernel(keys_ref, filt_ref, out_ref, *,
 
 
 def update_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                valid: jnp.ndarray, op: str, layout: Layout = None,
-                tile: int = DEFAULT_TILE, interpret: bool = True,
+                valid: jnp.ndarray, op: str, *, interpret: bool,
+                layout: Layout = None, tile: int = DEFAULT_TILE,
                 probe: str = "loop", coop: str = "none",
                 mix: str = "full") -> jnp.ndarray:
     """Bulk increment/decrement, whole counter array pinned in VMEM."""
@@ -268,16 +196,18 @@ def update_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     assert mix in MIXES, mix
     # An explicitly-passed layout is validated regardless of probe — the
     # gather engine ignores it, but never silently accepts an invalid one.
-    layout = counting_layout(
-        spec, layout or default_counting_layout(spec, op), tile)
+    _check_lowers("vmem", probe, coop, interpret)
+    layout = (layout or sbf.default_layout(spec, op)).validate(spec, tile)
+    if coop == "none" and probe == "loop":
+        starts, cmasks = counting_fingerprints(spec, keys, mix, valid)
+        return sbf.update_rows_vmem(filt, starts, cmasks, theta=layout.theta,
+                                    tile=tile, interpret=interpret,
+                                    apply=_update(op))
     if coop == "subtile":      # cooperative schedule supersedes the probe
         kern = functools.partial(_update_vmem_coop_kernel, spec=spec,
                                  tile=tile, op=op, mix=mix)
-    elif probe == "gather":
-        kern = functools.partial(_update_vmem_gather_kernel, spec=spec,
-                                 tile=tile, op=op, mix=mix)
     else:
-        kern = functools.partial(_update_vmem_kernel, spec=spec, layout=layout,
+        kern = functools.partial(_update_vmem_gather_kernel, spec=spec,
                                  tile=tile, op=op, mix=mix)
     return pl.pallas_call(
         kern,
@@ -293,26 +223,29 @@ def update_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     )(keys, valid, filt)
 
 
-def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  layout: Layout = None, tile: int = DEFAULT_TILE,
-                  interpret: bool = True, probe: str = "loop",
+def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
+                  interpret: bool, layout: Layout = None,
+                  tile: int = DEFAULT_TILE, probe: str = "loop",
                   coop: str = "none", mix: str = "full") -> jnp.ndarray:
     n = keys.shape[0]
     assert n % tile == 0
     assert probe in PROBES, probe
     assert coop in COOPS, coop
     assert mix in MIXES, mix
-    layout = counting_layout(
-        spec, layout or default_counting_layout(spec, "contains"), tile)
+    _check_lowers("vmem", probe, coop, interpret)
+    layout = (layout or sbf.default_layout(spec, "contains")).validate(
+        spec, tile)
+    if coop == "none" and probe == "loop":
+        starts, cmasks = counting_fingerprints(spec, keys, mix)
+        return sbf.probe_rows_vmem(filt, starts, cmasks, theta=layout.theta,
+                                   tile=tile, interpret=interpret,
+                                   held=V.nib_nonzero)
     if coop == "subtile":      # cooperative schedule supersedes the probe
         kern = functools.partial(_contains_vmem_coop_kernel, spec=spec,
                                  tile=tile, mix=mix)
-    elif probe == "gather":
+    else:
         kern = functools.partial(_contains_vmem_gather_kernel, spec=spec,
                                  tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_contains_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, mix=mix)
     return pl.pallas_call(
         kern,
         grid=(n // tile,),
@@ -363,71 +296,30 @@ def _bank_update_vmem_gather_kernel(keys_ref, member_ref, valid_ref, filt_ref,
     out_ref[...] = f2d.at[sb].set(apply(rows, totals)).reshape(-1)
 
 
-def _bank_update_vmem_kernel(keys_ref, member_ref, valid_ref, filt_ref,
-                             out_ref, *, spec: FilterSpec, layout: Layout,
-                             tile: int, op: str, mix: str):
-    cs, theta, phi = spec.counter_row_words, layout.theta, layout.phi
-    n_chunks = cs // phi
-    update = _update(op)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    cstarts, cmasks = _bank_cstarts(spec, keys_ref[...], member_ref[...],
-                                    valid_ref[...], mix=mix)
-
-    def group_body(g, carry):
-        base = g * theta
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(cstarts, i)
-            mrow = _mask_row(cmasks, i, cs)
-            for c in range(n_chunks):               # static unroll over Φ
-                idx = (pl.ds(st + c * phi, phi),)
-                w = pl.load(out_ref, idx)
-                inc = jax.lax.dynamic_slice(mrow, (c * phi,), (phi,))
-                pl.store(out_ref, idx, update(w, inc))
-        return carry
-
-    jax.lax.fori_loop(0, tile // theta, group_body, jnp.int32(0))
-
-
-def _bank_contains_vmem_gather_kernel(keys_ref, member_ref, filt_ref, out_ref,
-                                      *, spec: FilterSpec, tile: int,
-                                      bank: int, mix: str):
-    cs = spec.counter_row_words
-    h1, h2 = _hash_streams(keys_ref[...], mix)
-    blk = H.block_index(h2, spec.n_blocks).astype(jnp.int32)
-    blk = member_ref[...] * jnp.int32(spec.n_blocks) + blk
-    masks = V.block_patterns(spec, h1, batched=False)          # logical (n, s)
-    rows = jnp.take(filt_ref[...].reshape(bank * spec.n_blocks, cs), blk,
-                    axis=0)                                    # (tile, 4s)
-    occ = V.collapse_counter_words(rows)                       # (tile, s)
-    out_ref[...] = jnp.all((occ & masks) == masks, axis=-1)
-
-
 def bank_update_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
-                     member: jnp.ndarray, valid: jnp.ndarray, op: str,
-                     layout: Layout = None, tile: int = DEFAULT_TILE,
-                     interpret: bool = True, probe: str = "gather",
+                     member: jnp.ndarray, valid: jnp.ndarray, op: str, *,
+                     interpret: bool, layout: Layout = None,
+                     tile: int = DEFAULT_TILE, probe: str = "gather",
                      mix: str = "full") -> jnp.ndarray:
     """Flat routed counter update of a (B, storage_words) bank — one launch."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,) and valid.shape == (n,)
     assert probe in PROBES, probe
     assert mix in MIXES, mix
+    _check_lowers("vmem", probe, "none", interpret)
     B, flat = bank.shape[0], bank.reshape(-1)
-    layout = counting_layout(
-        spec, layout or default_counting_layout(spec, op), tile)
-    if probe == "gather":
-        kern = functools.partial(_bank_update_vmem_gather_kernel, spec=spec,
-                                 tile=tile, bank=B, op=op, mix=mix)
-    else:
-        kern = functools.partial(_bank_update_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, op=op, mix=mix)
+    layout = (layout or sbf.default_layout(spec, op)).validate(spec, tile)
+    member = member.astype(jnp.int32)
+    if probe == "loop":
+        starts, cmasks = counting_fingerprints(spec, keys, mix, valid)
+        out = sbf.update_rows_vmem(
+            flat, starts + member * jnp.int32(spec.storage_words), cmasks,
+            theta=layout.theta, tile=tile, interpret=interpret,
+            apply=_update(op))
+        return out.reshape(B, spec.storage_words)
     out = pl.pallas_call(
-        kern,
+        functools.partial(_bank_update_vmem_gather_kernel, spec=spec,
+                          tile=tile, bank=B, op=op, mix=mix),
         grid=(n // tile,),
         in_specs=[
             pl.BlockSpec((tile, 2), lambda i: (i, 0)),
@@ -438,191 +330,49 @@ def bank_update_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
         out_specs=pl.BlockSpec((B * spec.storage_words,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((B * spec.storage_words,), jnp.uint32),
         interpret=interpret,
-    )(keys, member.astype(jnp.int32), valid, flat)
+    )(keys, member, valid, flat)
     return out.reshape(B, spec.storage_words)
 
 
 def bank_contains_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
-                       member: jnp.ndarray, tile: int = DEFAULT_TILE,
-                       interpret: bool = True, mix: str = "full"
-                       ) -> jnp.ndarray:
-    """Flat routed occupancy membership against a counter bank — one launch
-    (whole-tile gather probe; the loop probe adds nothing for banks)."""
+                       member: jnp.ndarray, *, interpret: bool,
+                       tile: int = DEFAULT_TILE,
+                       mix: str = "full") -> jnp.ndarray:
+    """Flat routed occupancy membership against a counter bank — one
+    launch of the per-key row probe."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,)
     assert mix in MIXES, mix
-    B, flat = bank.shape[0], bank.reshape(-1)
-    kern = functools.partial(_bank_contains_vmem_gather_kernel, spec=spec,
-                             tile=tile, bank=B, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((B * spec.storage_words,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        interpret=interpret,
-    )(keys, member.astype(jnp.int32), flat)
+    starts, cmasks = counting_fingerprints(spec, keys, mix)
+    starts = starts + member.astype(jnp.int32) * jnp.int32(spec.storage_words)
+    return sbf.probe_rows_vmem(bank.reshape(-1), starts, cmasks,
+                               theta=1, tile=tile, interpret=interpret,
+                               held=V.nib_nonzero)
 
 
 # ---------------------------------------------------------------------------
-# HBM-resident kernels — DMA-streamed counter rows
+# HBM-resident and partitioned paths — the sbf row engine on counter rows
 # ---------------------------------------------------------------------------
-
-def _update_hbm_kernel(keys_ref, valid_ref, filt_hbm, out_hbm, scratch,
-                       sem_r, sem_w, *, spec: FilterSpec, tile: int, op: str,
-                       mix: str):
-    """Block-sorted coalesced DMA RMW: the tile is sorted by counter row
-    and same-row increments collapse to one total via the segmented
-    saturating nibble add, so the DMA loop touches each *unique* row once
-    (vs one serialized RMW per key). Distinct rows are disjoint word
-    ranges — the ownership argument needs no atomics."""
-    cs = spec.counter_row_words
-    apply = _accumulate(op)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        cp = pltpu.make_async_copy(filt_hbm, out_hbm, sem_r.at[0])
-        cp.start()
-        cp.wait()
-
-    cstarts, cmasks = _cfingerprints(spec, keys_ref[...], valid_ref[...],
-                                     mix=mix)
-    order = jnp.argsort(cstarts)
-    sst = cstarts[order]
-    totals = V.segment_totals(sst, cmasks[order], V.nib_sat_add_words)
-    is_end = jnp.concatenate([sst[1:] != sst[:-1], jnp.ones((1,), bool)])
-
-    def body(i, carry):
-        @pl.when(_take_scalar(is_end, i))
-        def _rmw():                        # one RMW per unique counter row
-            st = _take_scalar(sst, i)
-            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(st, cs)],
-                                       scratch.at[0], sem_r.at[0])
-            rd.start()
-            rd.wait()
-            row = pl.load(scratch, (pl.ds(0, 1), slice(None)))[0]
-            new = apply(row, _mask_row(totals, i, cs))
-            pl.store(scratch, (pl.ds(1, 1), slice(None)), new[None])
-            wr = pltpu.make_async_copy(scratch.at[1],
-                                       out_hbm.at[pl.ds(st, cs)], sem_w.at[0])
-            wr.start()
-            wr.wait()
-        return carry
-
-    jax.lax.fori_loop(0, tile, body, jnp.int32(0))
-
-
-def _contains_hbm_kernel(keys_ref, filt_hbm, out_ref, scratch, sem, *,
-                         spec: FilterSpec, tile: int, depth: int, mix: str):
-    """Depth-tunable row-streaming pipeline, counting analogue of sbf
-    contains_hbm: up to depth-1 row DMAs in flight ahead of the test."""
-    cs = spec.counter_row_words
-    h1, h2 = _hash_streams(keys_ref[...], mix)
-    blk = H.block_index(h2, spec.n_blocks)
-    masks = V.block_patterns(spec, h1, batched=False)
-    cstarts = (blk * jnp.uint32(cs)).astype(jnp.int32)
-
-    def dma(i, slot):
-        st = _take_scalar(cstarts, i)
-        return pltpu.make_async_copy(
-            filt_hbm.at[pl.ds(st, cs)], scratch.at[slot], sem.at[slot])
-
-    for d in range(depth - 1):             # static prologue: fill the pipe
-        dma(d, d).start()
-
-    def body(i, acc):
-        slot = jax.lax.rem(i, depth)
-
-        # depth=1: the offset-0 "prefetch" starts the current DMA (serial).
-        @pl.when(i + depth - 1 < tile)
-        def _prefetch():
-            dma(i + depth - 1, jax.lax.rem(i + depth - 1, depth)).start()
-
-        dma(i, slot).wait()
-        row = pl.load(scratch, (pl.ds(slot, 1), slice(None)))[0]   # (4s,)
-        occ = V.collapse_counter_words(row[None])[0]               # (s,)
-        m = _mask_row(masks, i, spec.s)
-        ok = jnp.all((occ & m) == m)
-        return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
-
-    out = jax.lax.fori_loop(0, tile, body, jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = out
-
-
-def _contains_hbm_coop_kernel(keys_ref, filt_hbm, out_ref, scratch, sem, *,
-                              spec: FilterSpec, tile: int, mix: str):
-    """Cooperative HBM contains, counting analogue of sbf: the tile is
-    sorted by counter-row start so same-row sub-tiles share ONE row DMA —
-    each unique row crosses the bus once per tile; results are computed in
-    sorted order and unsorted with one scatter."""
-    cs = spec.counter_row_words
-    h1, h2 = _hash_streams(keys_ref[...], mix)
-    blk = H.block_index(h2, spec.n_blocks)
-    masks = V.block_patterns(spec, h1, batched=False)
-    cstarts = (blk * jnp.uint32(cs)).astype(jnp.int32)
-    order = jnp.argsort(cstarts)
-    sst = cstarts[order]
-    smasks = masks[order]
-    is_head = jnp.concatenate(
-        [jnp.ones((1,), bool), sst[1:] != sst[:-1]])
-
-    def body(i, acc):
-        @pl.when(_take_scalar(is_head, i))
-        def _fetch():                      # one DMA per unique counter row
-            st = _take_scalar(sst, i)
-            cp = pltpu.make_async_copy(
-                filt_hbm.at[pl.ds(st, cs)], scratch.at[0], sem.at[0])
-            cp.start()
-            cp.wait()
-        row = pl.load(scratch, (pl.ds(0, 1), slice(None)))[0]      # (4s,)
-        occ = V.collapse_counter_words(row[None])[0]               # (s,)
-        m = _mask_row(smasks, i, spec.s)
-        ok = jnp.all((occ & m) == m)
-        return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
-
-    sorted_ok = jax.lax.fori_loop(0, tile, body,
-                                  jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = jnp.zeros((tile,), jnp.bool_).at[order].set(sorted_ok)
-
 
 def update_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-               valid: jnp.ndarray, op: str, tile: int = DEFAULT_TILE,
-               interpret: bool = True, coop: str = "none",
+               valid: jnp.ndarray, op: str, *, interpret: bool,
+               tile: int = DEFAULT_TILE, coop: str = "none",
                mix: str = "full") -> jnp.ndarray:
-    # Like sbf.add_hbm, the HBM update is already cooperative (sorted
-    # unique-row DMA RMW); coop is validated and threads through to the
-    # same kernel for either value.
+    """Row-sorted RMW of DMA-streamed counter rows. Like sbf.add_hbm the
+    schedule is already cooperative (one read and write per unique row per
+    tile); coop is validated and both values run the same kernel."""
     n = keys.shape[0]
     assert n % tile == 0
     assert coop in COOPS, coop
     assert mix in MIXES, mix
-    kern = functools.partial(_update_hbm_kernel, spec=spec, tile=tile, op=op,
-                             mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((spec.storage_words,), jnp.uint32),
-        scratch_shapes=[
-            pltpu.VMEM((2, spec.counter_row_words), jnp.uint32),
-            pltpu.SemaphoreType.DMA((1,)),
-            pltpu.SemaphoreType.DMA((1,)),
-        ],
-        interpret=interpret,
-    )(keys, valid, filt)
+    starts, cmasks = counting_fingerprints(spec, keys, mix, valid)
+    return sbf.update_rows_hbm(filt, starts, cmasks, tile=tile,
+                               interpret=interpret, apply=_update(op))
 
 
 def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                 tile: int = DEFAULT_TILE, interpret: bool = True,
+                 *, interpret: bool,
+                 tile: int = DEFAULT_TILE,
                  depth: int = DEFAULT_DMA_DEPTH, coop: str = "none",
                  mix: str = "full") -> jnp.ndarray:
     n = keys.shape[0]
@@ -630,88 +380,28 @@ def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     assert depth in DMA_DEPTHS, f"depth={depth} not in {DMA_DEPTHS}"
     assert coop in COOPS, coop
     assert mix in MIXES, mix
-    depth = min(depth, tile)
-    if coop == "subtile":
-        depth = 1                          # single shared scratch row
-        kern = functools.partial(_contains_hbm_coop_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_contains_hbm_kernel, spec=spec, tile=tile,
-                                 depth=depth, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        scratch_shapes=[
-            pltpu.VMEM((depth, spec.counter_row_words), jnp.uint32),
-            pltpu.SemaphoreType.DMA((depth,)),
-        ],
-        interpret=interpret,
-    )(keys, filt)
-
-
-# ---------------------------------------------------------------------------
-# Partitioned-ownership update — PARALLEL grid, one segment per step
-# ---------------------------------------------------------------------------
-
-def _update_partitioned_kernel(keys_ref, valid_ref, filt_ref, out_ref, *,
-                               spec: FilterSpec, seg_cwords: int,
-                               capacity: int, op: str, mix: str):
-    """One grid step owns one counter segment exclusively (PARALLEL-safe).
-
-    Keys were pre-partitioned by block segment; padded slots have valid=0
-    and therefore all-zero increment rows. This is the path that replaces
-    the GPU's atomicAdd/atomicSub for counter updates."""
-    cs = spec.counter_row_words
-    update = _update(op)
-    out_ref[...] = filt_ref[...]
-    keys = pl.load(keys_ref, (pl.ds(0, 1), slice(None), slice(None)))[0]
-    valid = pl.load(valid_ref, (pl.ds(0, 1), slice(None)))[0]
-    cstarts, cmasks = _cfingerprints(spec, keys, valid, mix=mix)
-    # counter-word offset within this segment
-    cstarts = jax.lax.rem(cstarts, jnp.int32(seg_cwords))
-
-    def body(i, carry):
-        st = _take_scalar(cstarts, i)
-        idx = (pl.ds(st, cs),)
-        w = pl.load(out_ref, idx)
-        pl.store(out_ref, idx, update(w, _mask_row(cmasks, i, cs)))
-        return carry
-
-    jax.lax.fori_loop(0, capacity, body, jnp.int32(0))
+    starts, cmasks = counting_fingerprints(spec, keys, mix)
+    return sbf.probe_rows_hbm(filt, starts, cmasks, tile=tile, depth=depth,
+                              coop=coop, interpret=interpret,
+                              held=V.nib_nonzero)
 
 
 def update_partitioned(spec: FilterSpec, filt: jnp.ndarray,
                        keys_by_seg: jnp.ndarray, valid: jnp.ndarray,
-                       n_segments: int, op: str, interpret: bool = True,
+                       n_segments: int, op: str, *, interpret: bool,
                        mix: str = "full") -> jnp.ndarray:
-    """keys_by_seg: (n_segments, capacity, 2); valid: (n_segments, capacity)."""
+    """keys_by_seg: (n_segments, capacity, 2); valid: (n_segments, capacity).
+
+    One PARALLEL grid step owns one counter segment exclusively: the path
+    that replaces the GPU's atomicAdd/atomicSub for counter updates."""
     assert spec.storage_words % n_segments == 0
     assert mix in MIXES, mix
     seg_cwords = spec.storage_words // n_segments
-    capacity = keys_by_seg.shape[1]
-    kern = functools.partial(_update_partitioned_kernel, spec=spec,
-                             seg_cwords=seg_cwords, capacity=capacity, op=op,
-                             mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n_segments,),
-        in_specs=[
-            pl.BlockSpec((1, capacity, 2), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, capacity), lambda i: (i, 0)),
-            pl.BlockSpec((seg_cwords,), lambda i: (i,)),       # own segment only
-        ],
-        out_specs=pl.BlockSpec((seg_cwords,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((spec.storage_words,), jnp.uint32),
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel",)),                # segments independent
-    )(keys_by_seg, valid, filt)
+    starts, cmasks = counting_fingerprints(spec, keys_by_seg.reshape(-1, 2),
+                                           mix, valid.reshape(-1))
+    return sbf.update_rows_partitioned(
+        filt, jax.lax.rem(starts, jnp.int32(seg_cwords)), cmasks, n_segments,
+        interpret=interpret, apply=_update(op))
 
 
 # ---------------------------------------------------------------------------
@@ -722,8 +412,8 @@ def _decay_kernel(filt_ref, out_ref):
     out_ref[...] = V.decay_word(filt_ref[...])
 
 
-def decay(spec: FilterSpec, filt: jnp.ndarray, tile_words: int = 4096,
-          interpret: bool = True) -> jnp.ndarray:
+def decay(spec: FilterSpec, filt: jnp.ndarray, *, interpret: bool,
+          tile_words: int = 4096) -> jnp.ndarray:
     """One aging step over the whole counter array (PARALLEL word tiles)."""
     nw = spec.storage_words
     tile_words = min(tile_words, nw)
@@ -735,6 +425,6 @@ def decay(spec: FilterSpec, filt: jnp.ndarray, tile_words: int = 4096,
         out_specs=pl.BlockSpec((tile_words,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((nw,), jnp.uint32),
         interpret=interpret,
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(filt)

@@ -45,7 +45,8 @@ def _contains_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
 
 
 def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  tile: int = DEFAULT_TILE, interpret: bool = True,
+                  *, interpret: bool,
+                  tile: int = DEFAULT_TILE,
                   coop: str = "none") -> jnp.ndarray:
     """Bulk membership, table pinned in VMEM — one launch, gather probe.
     ``coop="subtile"`` swaps in the early-exit two-phase bucket probe
@@ -109,16 +110,16 @@ def _update_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 
 def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-             valid: jnp.ndarray, tile: int = F.CUCKOO_ADD_TILE,
-             interpret: bool = True):
+             valid: jnp.ndarray, *, interpret: bool,
+             tile: int = F.CUCKOO_ADD_TILE):
     """Bulk block-sorted insert. Returns (table, ok) — ``ok[i]=False`` is
     the explicit kick-overflow failure signal for key i."""
     return _update_vmem(spec, filt, keys, valid, "add", tile, interpret)
 
 
 def remove_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                valid: jnp.ndarray, tile: int = F.CUCKOO_ADD_TILE,
-                interpret: bool = True):
+                valid: jnp.ndarray, *, interpret: bool,
+                tile: int = F.CUCKOO_ADD_TILE):
     """Bulk delete. Returns (table, found) — found=False means the key's
     fingerprint was absent (nothing cleared)."""
     return _update_vmem(spec, filt, keys, valid, "remove", tile, interpret)

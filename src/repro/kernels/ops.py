@@ -7,7 +7,8 @@
 * regime: filter words <= VMEM budget -> ``*_vmem`` (cache-resident
   analogue), else ``*_hbm`` (DMA streaming) — mirroring the paper's §5.3/§5.2
   split;
-* probe strategy (vmem regime): ``probe="loop"`` is the (Θ, Φ) per-key walk,
+* probe strategy (vmem regime): ``probe="loop"`` is the per-key row walk
+  (the only VMEM schedule that compiles for TPU, ``sbf.lowers``),
   ``probe="gather"`` the whole-tile vectorized engine (one gather + one
   fused compare / conflict-free segment-reduced scatter); ``"auto"``
   resolves through ``core.tuning.tune_plan``. The HBM regime instead
@@ -37,12 +38,19 @@
   **valid-masking** (``_pad_keys_valid``): padded slots carry valid=0 and
   contribute an all-zero increment row.
 
-On non-TPU backends the kernels run in interpret mode (kernel body executed
-with jnp semantics) — bit-exact, which is what the test sweeps rely on.
+On the CPU the kernels run in interpret mode (kernel body executed with
+jnp semantics) — bit-exact, which is what the test sweeps rely on.
+Everywhere else they are compiled. Families whose kernels the chip's
+compiler refuses (cuckoo, quotient, ring, classical) run XLA on TPU
+through their engines; calling their kernels here on a TPU raises
+instead (:func:`_interpret_only`). Dispatches that choose between a
+kernel and the XLA reference by size record which one ran
+(:func:`dispatched`).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import collections
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
@@ -66,7 +74,34 @@ from repro.kernels.sbf import (DEFAULT_DMA_DEPTH, DEFAULT_TILE, DMA_DEPTHS,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU only; every other backend compiles."""
+    return jax.default_backend() == "cpu"
+
+
+def _interpret_only(family: str) -> bool:
+    """``interpret`` for a family whose kernels do not lower for the TPU:
+    its engine runs the XLA implementation there, and a direct call on a
+    TPU is refused here rather than deep in the compiler."""
+    if not _interpret():
+        raise NotImplementedError(
+            f"the {family} Pallas kernels do not lower for "
+            f"{jax.default_backend()}; use the engine's XLA implementation")
+    return True
+
+
+# (op, impl) -> traces that dispatched it. ``impl`` is the kernel regime
+# ("pallas-vmem", "pallas-hbm") or "xla" where a size check swapped the
+# kernel for the jnp reference.
+_DISPATCHED: "collections.Counter[Tuple[str, str]]" = collections.Counter()
+
+
+def dispatched() -> Dict[Tuple[str, str], int]:
+    """Snapshot of which implementation each dispatch traced to."""
+    return dict(_DISPATCHED)
+
+
+def _ran(op: str, impl: str) -> None:
+    _DISPATCHED[(op, impl)] += 1
 
 
 def kernel_supported(spec: FilterSpec) -> bool:
@@ -180,14 +215,17 @@ def bloom_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     padded = _pad_keys(keys, tile)
     interp = _interpret()
     if spec.variant == "cbf":
-        out = cbf_k.contains_vmem(spec, filt, padded, tile=tile, interpret=interp)
+        out = cbf_k.contains_vmem(spec, filt, padded, tile=tile,
+                                  interpret=_interpret_only("cbf"))
     elif _regime(spec, regime) == "vmem":
+        _ran("bloom_contains", "pallas-vmem")
         p, c, m = _resolve_pcm(spec, "contains", "vmem", tile, probe, coop,
                                mix)
         out = sbf_k.contains_vmem(
             spec, filt, padded, layout or default_layout(spec, "contains"),
             tile=tile, interpret=interp, probe=p, coop=c, mix=m)
     else:
+        _ran("bloom_contains", "pallas-hbm")
         _, c, m = _resolve_pcm(spec, "contains", "hbm", tile, "gather",
                                coop, mix)
         out = sbf_k.contains_hbm(
@@ -209,12 +247,15 @@ def bloom_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     padded = _pad_keys(keys, tile)
     interp = _interpret()
     if spec.variant == "cbf":
-        return cbf_k.add_vmem(spec, filt, padded, tile=tile, interpret=interp)
+        return cbf_k.add_vmem(spec, filt, padded, tile=tile,
+                              interpret=_interpret_only("cbf"))
     if _regime(spec, regime) == "vmem":
+        _ran("bloom_add", "pallas-vmem")
         p, c, m = _resolve_pcm(spec, "add", "vmem", tile, probe, coop, mix)
         return sbf_k.add_vmem(
             spec, filt, padded, layout or default_layout(spec, "add"),
             tile=tile, interpret=interp, probe=p, coop=c, mix=m)
+    _ran("bloom_add", "pallas-hbm")
     _, c, m = _resolve_pcm(spec, "add", "hbm", tile, "gather", coop, mix)
     return sbf_k.add_hbm(spec, filt, padded, tile=tile, interpret=interp,
                          coop=c, mix=m)
@@ -272,8 +313,10 @@ def bloom_bank_contains(spec: FilterSpec, bank: jnp.ndarray,
         return jnp.zeros((0,), jnp.bool_)
     B = bank.shape[0]
     if spec.variant == "cbf" or not bank_vmem_resident(spec, B):
+        _ran("bank_contains", "xla")
         return V.bank_contains_rows(spec, bank, keys,
                                     jnp.asarray(member, jnp.int32))
+    _ran("bank_contains", "pallas-vmem")
     tile = _clamp_tile(n, tile)
     pk, pm = _pad_flat(keys, jnp.asarray(member, jnp.int32), tile)
     out = sbf_k.bank_contains_vmem(
@@ -296,7 +339,9 @@ def bloom_bank_add(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
     B = bank.shape[0]
     member = jnp.asarray(member, jnp.int32)
     if spec.variant == "cbf" or not bank_vmem_resident(spec, B):
+        _ran("bank_add", "xla")
         return V.bank_add_rows(spec, bank, keys, member, valid=valid)
+    _ran("bank_add", "pallas-vmem")
     tile = _clamp_tile(n, tile)
     pk, pm, pv = _pad_flat_valid(keys, member, valid, tile)
     return sbf_k.bank_add_vmem(
@@ -321,7 +366,9 @@ def counting_bank_update(spec: FilterSpec, bank: jnp.ndarray,
     B = bank.shape[0]
     member = jnp.asarray(member, jnp.int32)
     if not bank_vmem_resident(spec, B):
+        _ran("counting_bank_update", "xla")
         return V.bank_counting_update(spec, bank, keys, member, valid, op)
+    _ran("counting_bank_update", "pallas-vmem")
     tile = _clamp_tile(n, tile)
     pk, pm, pv = _pad_flat_valid(keys, member, valid, tile)
     return cnt_k.bank_update_vmem(
@@ -342,7 +389,9 @@ def counting_bank_contains(spec: FilterSpec, bank: jnp.ndarray,
     B = bank.shape[0]
     member = jnp.asarray(member, jnp.int32)
     if not bank_vmem_resident(spec, B):
+        _ran("counting_bank_contains", "xla")
         return V.bank_counting_contains(spec, bank, keys, member)
+    _ran("counting_bank_contains", "pallas-vmem")
     tile = _clamp_tile(n, tile)
     pk, pm = _pad_flat(keys, member, tile)
     out = cnt_k.bank_contains_vmem(spec, bank, pk, pm, tile=tile,
@@ -548,10 +597,12 @@ def _counting_update(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     padded, pvalid = _pad_keys_valid(keys, tile, valid)
     interp = _interpret()
     if _regime(spec, regime) == "vmem":
+        _ran(f"counting_{op}", "pallas-vmem")
         p, c, m = _resolve_pcm(spec, "add", "vmem", tile, probe, coop, mix)
         return cnt_k.update_vmem(
             spec, filt, padded, pvalid, op, layout=layout, tile=tile,
             interpret=interp, probe=p, coop=c, mix=m)
+    _ran(f"counting_{op}", "pallas-hbm")
     _, c, m = _resolve_pcm(spec, "add", "hbm", tile, "gather", coop, mix)
     return cnt_k.update_hbm(spec, filt, padded, pvalid, op, tile=tile,
                             interpret=interp, coop=c, mix=m)
@@ -594,12 +645,14 @@ def counting_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     padded = _pad_keys(keys, tile)
     interp = _interpret()
     if _regime(spec, regime) == "vmem":
+        _ran("counting_contains", "pallas-vmem")
         p, c, m = _resolve_pcm(spec, "contains", "vmem", tile, probe, coop,
                                mix)
         out = cnt_k.contains_vmem(
             spec, filt, padded, layout=layout, tile=tile, interpret=interp,
             probe=p, coop=c, mix=m)
     else:
+        _ran("counting_contains", "pallas-hbm")
         _, c, m = _resolve_pcm(spec, "contains", "hbm", tile, "gather",
                                coop, mix)
         out = cnt_k.contains_hbm(
@@ -646,7 +699,8 @@ def counting_update_partitioned(spec: FilterSpec, filt: jnp.ndarray, keys,
     keys = jnp.asarray(keys)
     part = _partition_device(spec, keys, n_segments, capacity)
     out = cnt_k.update_partitioned(spec, filt, part.keys_by_seg, part.valid,
-                                   n_segments, op, interpret=_interpret())
+                                   n_segments, op,
+                                   interpret=_interpret())
     if not _is_traced(part.overflow):
         if int(part.overflow) == 0:
             return out
@@ -691,11 +745,13 @@ def cuckoo_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     if n == 0:
         return jnp.zeros((0,), jnp.bool_)
     if not cuckoo_vmem_resident(spec):
+        _ran("cuckoo_contains", "xla")
         return F.cuckoo_contains(spec, filt, keys)
+    _ran("cuckoo_contains", "pallas-vmem")
     tile = _clamp_tile(n, tile or DEFAULT_TILE)
     padded = _pad_keys(keys, tile)              # reads: repeat-last is safe
     out = ckoo_k.contains_vmem(spec, filt, padded, tile=tile,
-                               interpret=_interpret(),
+                               interpret=_interpret_only("cuckoo"),
                                coop=_resolve_coop_fp(spec, coop, tile))
     return out[:n]
 
@@ -721,12 +777,15 @@ def _cuckoo_update(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
         return filt, jnp.zeros((0,), jnp.bool_)
     T = tile or F.CUCKOO_ADD_TILE
     if not cuckoo_vmem_resident(spec):
+        _ran(f"cuckoo_{op}", "xla")
         fn = F.cuckoo_add if op == "add" else F.cuckoo_remove
         return fn(spec, filt, keys, valid=valid, tile=T)
+    _ran(f"cuckoo_{op}", "pallas-vmem")
     eff = _cuckoo_tile(n, tile)
     pk, pv = _pad_keys_valid(keys, eff, valid)
     fn = ckoo_k.add_vmem if op == "add" else ckoo_k.remove_vmem
-    out, flags = fn(spec, filt, pk, pv, tile=eff, interpret=_interpret())
+    out, flags = fn(spec, filt, pk, pv, tile=eff,
+                    interpret=_interpret_only("cuckoo"))
     return out, flags[:n]
 
 
@@ -769,11 +828,13 @@ def quotient_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     if n == 0:
         return jnp.zeros((0,), jnp.bool_)
     if not quotient_vmem_resident(spec):
+        _ran("quotient_contains", "xla")
         return Q.quotient_contains(spec, filt, keys)
+    _ran("quotient_contains", "pallas-vmem")
     tile = _clamp_tile(n, tile or DEFAULT_TILE)
     padded = _pad_keys(keys, tile)              # reads: repeat-last is safe
     out = qf_k.contains_vmem(spec, filt, padded, tile=tile,
-                             interpret=_interpret(),
+                             interpret=_interpret_only("quotient"),
                              coop=_resolve_coop_fp(spec, coop, tile))
     return out[:n]
 
@@ -798,12 +859,15 @@ def _quotient_update(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
         return filt, jnp.zeros((0,), jnp.bool_)
     T = tile or Q.QUOTIENT_ADD_TILE
     if not quotient_vmem_resident(spec):
+        _ran(f"quotient_{op}", "xla")
         fn = Q.quotient_add if op == "add" else Q.quotient_remove
         return fn(spec, filt, keys, valid=valid, tile=T)
+    _ran(f"quotient_{op}", "pallas-vmem")
     eff = _quotient_tile(n, tile)
     pk, pv = _pad_keys_valid(keys, eff, valid)
     fn = qf_k.add_vmem if op == "add" else qf_k.remove_vmem
-    out, flags = fn(spec, filt, pk, pv, tile=eff, interpret=_interpret())
+    out, flags = fn(spec, filt, pk, pv, tile=eff,
+                    interpret=_interpret_only("quotient"))
     return out, flags[:n]
 
 
@@ -837,7 +901,7 @@ def ring_contains(spec: FilterSpec, rings: jnp.ndarray, keys: jnp.ndarray,
         return jnp.zeros((0,), jnp.bool_)
     tile = _clamp_tile(n, tile)
     padded = _pad_keys(keys, tile)
-    interp = _interpret()
+    interp = _interpret_only("ring")
     n_gen = rings.shape[0]
     if regime == "auto":
         regime = ("vmem" if n_gen * spec.n_words * 4 <= VMEM_FILTER_BYTES

@@ -49,7 +49,8 @@ def _contains_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
 
 
 def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  tile: int = DEFAULT_TILE, interpret: bool = True,
+                  *, interpret: bool,
+                  tile: int = DEFAULT_TILE,
                   coop: str = "none") -> jnp.ndarray:
     """Bulk membership, table pinned in VMEM — one launch, fused run scan.
     ``coop="subtile"`` predicates the run scan on the tile-wide home-slot
@@ -112,16 +113,16 @@ def _update_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 
 def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-             valid: jnp.ndarray, tile: int = Q.QUOTIENT_ADD_TILE,
-             interpret: bool = True):
+             valid: jnp.ndarray, *, interpret: bool,
+             tile: int = Q.QUOTIENT_ADD_TILE):
     """Bulk decode+rebuild insert. Returns (table, ok) — ``ok[i]=False``
     is the explicit table-full failure signal for key i."""
     return _update_vmem(spec, filt, keys, valid, "add", tile, interpret)
 
 
 def remove_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                valid: jnp.ndarray, tile: int = Q.QUOTIENT_ADD_TILE,
-                interpret: bool = True):
+                valid: jnp.ndarray, *, interpret: bool,
+                tile: int = Q.QUOTIENT_ADD_TILE):
     """Bulk delete. Returns (table, found) — found=False means no stored
     copy of the key's fingerprint was left to clear."""
     return _update_vmem(spec, filt, keys, valid, "remove", tile, interpret)

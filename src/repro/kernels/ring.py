@@ -43,7 +43,7 @@ def ring_contains_ref(spec: FilterSpec, rings: jnp.ndarray,
 
 def _fingerprints(spec: FilterSpec, keys: jnp.ndarray):
     h1 = H.xxh32_u64x2(keys, H.SEED_PATTERN)
-    h2 = H.xxh32_u64x2(keys, H.SEED_BLOCK)
+    h2 = H.block_hash(keys)
     blk = H.block_index(h2, spec.n_blocks)
     masks = V.block_patterns(spec, h1, batched=False)
     starts = (blk * jnp.uint32(spec.s)).astype(jnp.int32)
@@ -57,9 +57,9 @@ def _ring_vmem_kernel(keys_ref, rings_ref, out_ref, *, spec: FilterSpec,
 
     def body(i, acc):
         st = _take_scalar(starts, i)
-        row = pl.load(rings_ref, (pl.ds(0, 1), pl.ds(st, s)))[0]
+        row = rings_ref[0, pl.ds(st, s)]
         for g in range(1, n_gen):               # static unroll over the ring
-            row = row | pl.load(rings_ref, (pl.ds(g, 1), pl.ds(st, s)))[0]
+            row = row | rings_ref[g, pl.ds(st, s)]
         m = _mask_row(masks, i, s)
         ok = jnp.all((row & m) == m)
         return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
@@ -87,7 +87,7 @@ def _ring_hbm_kernel(keys_ref, rings_hbm, out_ref, scratch, sem, *,
             if g + 1 < n_gen:
                 dma(i, g + 1, (g + 1) % 2).start()   # prefetch next gen
             dma(i, g, slot).wait()
-            row = row | pl.load(scratch, (pl.ds(slot, 1), slice(None)))[0]
+            row = row | scratch[slot]
         m = _mask_row(masks, i, s)
         ok = jnp.all((row & m) == m)
         return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
@@ -96,9 +96,9 @@ def _ring_hbm_kernel(keys_ref, rings_hbm, out_ref, scratch, sem, *,
     out_ref[...] = out
 
 
-def ring_contains_vmem(spec: FilterSpec, rings: jnp.ndarray,
-                       keys: jnp.ndarray, tile: int = DEFAULT_TILE,
-                       interpret: bool = True) -> jnp.ndarray:
+def ring_contains_vmem(spec: FilterSpec, rings: jnp.ndarray, keys: jnp.ndarray,
+                       *, interpret: bool,
+                       tile: int = DEFAULT_TILE) -> jnp.ndarray:
     n = keys.shape[0]
     n_gen = rings.shape[0]
     assert n % tile == 0
@@ -117,9 +117,9 @@ def ring_contains_vmem(spec: FilterSpec, rings: jnp.ndarray,
     )(keys, rings)
 
 
-def ring_contains_hbm(spec: FilterSpec, rings: jnp.ndarray,
-                      keys: jnp.ndarray, tile: int = DEFAULT_TILE,
-                      interpret: bool = True) -> jnp.ndarray:
+def ring_contains_hbm(spec: FilterSpec, rings: jnp.ndarray, keys: jnp.ndarray,
+                      *, interpret: bool,
+                      tile: int = DEFAULT_TILE) -> jnp.ndarray:
     n = keys.shape[0]
     n_gen = rings.shape[0]
     assert n % tile == 0

@@ -2,39 +2,43 @@
 
 This is the TPU-native realization of the paper's §4 design space:
 
-* **(Θ, Φ) vectorization layout** (`Layout`): the inner loop over a tile of
-  keys processes Θ keys per step ("horizontal" — the cooperative-group
-  analogue: Θ separate address streams, one fused vector compare), fetching
-  Φ contiguous words per load ("vertical" — the wide-load analogue:
-  ``pl.load`` of Φ·32 contiguous bits). Both loops are unrolled at trace
-  time, so salts, word offsets and chunk indices are inlined constants —
-  the analogue of the paper's template-metaprogramming inlining.
-* **Adaptive cooperation** (§4.3): phase 1 hashes the whole key tile on the
-  8×128 VPU in lockstep (hash work is *never* replicated across the Θ
-  dimension); phase 2 switches granularity to per-block probes that read
-  the precomputed hash/mask vectors.
+* **Two phases.** Phase 1 hashes the whole key batch in XLA, before the
+  kernel: every key gets its block start and its (s,) word masks
+  (:func:`fingerprints`). Phase 2 is the kernel: a per-key walk that
+  reads the key's block start from SMEM as a scalar and tests or ORs its
+  masks against the filter. Keeping block starts scalar is what the
+  chip's compiler needs: a vector value cannot be indexed dynamically
+  inside a kernel, a ref can.
+* **Row layout.** The filter words are viewed as ``(n_words / L, L)`` rows
+  of ``L = min(128, n_words)`` lanes (:func:`row_view`, a free reshape).
+  A block of s words never straddles a row, so a probe is one aligned
+  row load at a dynamic sublane offset plus a lane rotate of the key's
+  masks into place. Masks arrive lane-dense (``128 / s`` keys per row)
+  so the mask stream costs s words per key, not a whole row.
+* **Θ layout** (`Layout`): Θ keys are unrolled per loop trip. The paper's
+  second knob, Φ words per load, has no counterpart: a probe always loads
+  the whole row holding the block.
 * **Residency regimes** (§5.2/§5.3): ``*_vmem`` kernels pin the whole filter
   in VMEM via its BlockSpec (the L2-cache-resident analogue); ``*_hbm``
-  kernels leave the filter in HBM (``pl.ANY``) and stream blocks through a
-  double-buffered DMA scratch (the DRAM-resident analogue — the explicit
-  version of the GPU's sector fetches, with the paper's "prefetch next
-  chunk while processing" pipelining).
+  kernels leave the filter in HBM (``pl.ANY``) and stream rows through a
+  DMA scratch (the DRAM-resident analogue). Sorting keys by block for the
+  HBM schedules runs in XLA before the kernel.
 * **Ownership instead of atomics**: TPU Pallas grids execute sequentially on
   a core, and the partitioned bulk path gives each grid step an exclusive
   filter segment, so read-modify-write needs no atomics (DESIGN.md §2).
 
-All kernels are validated bit-exactly against ``repro.kernels.ref`` in
-interpret mode (this container is CPU-only; TPU is the target).
+The whole-tile gather probe and the VMEM cooperative kernels use an
+in-kernel gather or sort, which the chip's compiler refuses; they run only
+in interpret mode (:func:`lowers`). All kernels are validated bit-exactly
+against ``repro.core.variants`` in interpret mode.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,13 +46,12 @@ from repro.core import hashing as H
 from repro.core import variants as V
 from repro.core.variants import FilterSpec
 
-# renamed CompilerParams <-> TPUCompilerParams across jax releases
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 DEFAULT_TILE = 256
-# VMEM-regime budget for the filter words (bytes). Half of a ~16 MiB VMEM,
-# leaving room for key tiles, masks and scratch.
+# VMEM-regime budget for the filter words (bytes). The add kernels hold the
+# filter twice (input and output blocks); both compile for v5e at this edge
+# (tests/test_tpu_compile.py).
 VMEM_FILTER_BYTES = 4 * 1024 * 1024
+LANES = 128
 
 
 def _is_pow2(x: int) -> bool:
@@ -57,26 +60,19 @@ def _is_pow2(x: int) -> bool:
 
 @dataclasses.dataclass(frozen=True)
 class Layout:
-    """(Θ, Φ) vectorization layout — the paper's two degrees of freedom.
-
-    theta: keys processed per inner step (horizontal; Θ address streams are
-           issued back-to-back and their word tests fuse into one vector op).
-    phi:   contiguous words fetched per load (vertical; one pl.load of Φ
-           words ≙ ld.global.v{Φ}.u32).
+    """Θ, the keys processed per inner step (horizontal; Θ probes are
+    unrolled into one loop trip). The paper's vertical Φ (words per load)
+    does not exist here: every probe loads the whole row holding its block.
     """
     theta: int = 1
-    phi: int = 8
 
     def validate(self, spec: FilterSpec, tile: int) -> "Layout":
-        s = spec.s
-        phi = min(self.phi, s)
-        assert _is_pow2(self.theta) and _is_pow2(phi), (self.theta, phi)
-        assert s % phi == 0, f"phi={phi} must divide s={s}"
+        assert _is_pow2(self.theta), self.theta
         assert tile % self.theta == 0, f"theta={self.theta} must divide tile={tile}"
-        return Layout(self.theta, phi)
+        return self
 
     def __str__(self):
-        return f"Θ{self.theta}Φ{self.phi}"
+        return f"Θ{self.theta}"
 
 
 def default_layout(spec: FilterSpec, op: str) -> Layout:
@@ -86,15 +82,9 @@ def default_layout(spec: FilterSpec, op: str) -> Layout:
     add:      Θ̂ = s — fully horizontal maximizes temporal locality of the
               word updates (our analogue: tightest RMW grouping per block).
     """
-    s = spec.s
     if op == "contains":
-        theta = max(1, (spec.block_bits) // 256)
-        theta = min(theta, 8)
-        phi = max(1, min(8, s // theta))
-        return Layout(theta, phi)
-    theta = min(s, 8)
-    phi = max(1, s // theta)
-    return Layout(theta, phi)
+        return Layout(min(max(1, spec.block_bits // 256), 8))
+    return Layout(min(spec.s, 8))
 
 
 # ---------------------------------------------------------------------------
@@ -112,20 +102,29 @@ def _hash_streams(keys: jnp.ndarray, mix: str):
     if mix == "cheap":
         return H.xxh32_u64x2_pair(keys)
     return (H.xxh32_u64x2(keys, H.SEED_PATTERN),
-            H.xxh32_u64x2(keys, H.SEED_BLOCK))
+            H.block_hash(keys))
 
 
 def _fingerprints(spec: FilterSpec, keys: jnp.ndarray, mix: str = "full"):
-    """Vectorized hash + pattern phase: (starts[int32], masks[uint32 (n,s)]).
+    """In-kernel hash + pattern phase: (starts[int32], masks[uint32 (n,s)]).
 
     batched=False: inside a pallas_call the salts must stay scalar literals
-    (kernel bodies may not capture array constants) — this is also exactly
-    the paper's inlined-multiplier regime."""
+    (kernel bodies may not capture array constants). Used by the
+    interpret-only kernels; the row-layout kernels get both from
+    :func:`fingerprints` in XLA."""
     h1, h2 = _hash_streams(keys, mix)
     blk = H.block_index(h2, spec.n_blocks)
     masks = V.block_patterns(spec, h1, batched=False)
     starts = (blk * jnp.uint32(spec.s)).astype(jnp.int32)
     return starts, masks
+
+
+def fingerprints(spec: FilterSpec, keys: jnp.ndarray, mix: str = "full"):
+    """Phase 1 in XLA: (starts (n,) int32, masks (n, s) uint32)."""
+    h1, h2 = _hash_streams(keys, mix)
+    blk = H.block_index(h2, spec.n_blocks)
+    starts = (blk * jnp.uint32(spec.s)).astype(jnp.int32)
+    return starts, V.block_patterns(spec, h1)
 
 
 def _take_scalar(vec: jnp.ndarray, i) -> jnp.ndarray:
@@ -140,7 +139,8 @@ PROBES = ("loop", "gather")
 # Cooperation axis (paper §4.3 / McCoy et al.): "none" keeps the per-key
 # probe schedules; "subtile" shares one key's probe row across a lane
 # sub-tile — column-major early-exit contains, word-granular flat-lane
-# segmented adds. Every coop path is bit-exact with its "none" baseline.
+# segmented adds, sorted row sharing in HBM. Every coop path is bit-exact
+# with its "none" baseline.
 COOPS = ("none", "subtile")
 # Hash mixing schedule: "full" = two independent seeded xxh32 streams;
 # "cheap" = one fused wide mix feeding both streams (bit-identical).
@@ -149,49 +149,118 @@ DMA_DEPTHS = (1, 2, 4, 8)
 DEFAULT_DMA_DEPTH = 2
 
 
+def lowers(regime: str, probe: str, coop: str) -> bool:
+    """Whether the chip's compiler accepts this schedule. It has no
+    in-kernel sort and no general gather, so in the VMEM regime only the
+    per-key loop without cooperation lowers. Every HBM schedule does:
+    their sort runs in XLA before the kernel."""
+    return regime == "hbm" or (probe == "loop" and coop == "none")
+
+
+def _check_lowers(regime: str, probe: str, coop: str, interpret: bool):
+    if not (interpret or lowers(regime, probe, coop)):
+        raise ValueError(
+            f"probe={probe!r} coop={coop!r} does not lower for the TPU in "
+            f"the {regime} regime (in-kernel gather/sort); use "
+            f"probe='loop', coop='none'")
+
+
 # ---------------------------------------------------------------------------
-# VMEM-resident kernels (cache-resident regime analogue)
+# Row layout shared by the compiled kernels
 # ---------------------------------------------------------------------------
 
-def _contains_vmem_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
-                          layout: Layout, tile: int, mix: str):
-    s, theta, phi = spec.s, layout.theta, layout.phi
-    n_chunks = s // phi
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
+def row_lanes(n_words: int) -> int:
+    return min(LANES, n_words)
+
+
+def row_view(words: jnp.ndarray, lanes: int) -> jnp.ndarray:
+    """(n_words,) -> (n_words / lanes, lanes); a bitcast on TPU."""
+    return words.reshape(-1, lanes)
+
+
+def _tiles(x: jnp.ndarray, tile: int) -> jnp.ndarray:
+    """(n,) -> (n / tile, 1, tile): one SMEM block per grid step."""
+    return x.reshape(-1, 1, tile)
+
+
+def _mask_tiles(masks: jnp.ndarray, tile: int, lanes: int) -> jnp.ndarray:
+    """(n, s) masks -> (n / tile, rows, ml) lane-dense VMEM blocks: key i of
+    a tile holds flat words [i*s, i*s + s) of its block, ``ml >= lanes``
+    so one rotate can place them anywhere in a filter row."""
+    n, s = masks.shape
+    w = tile * s
+    ml = max(min(LANES, w), lanes)
+    m = masks.reshape(n // tile, w)
+    pad = (-w) % ml
+    if pad:
+        m = jnp.pad(m, ((0, 0), (0, pad)))
+    return m.reshape(n // tile, -1, ml)
+
+
+def _key_mask(m_ref, i, col, s: int, lanes: int) -> jnp.ndarray:
+    """(1, lanes) row holding key ``i``'s s mask words at lanes
+    [col, col + s) and zeros elsewhere."""
+    ml = m_ref.shape[-1]
+    flat = i * s
+    row = m_ref[0, pl.ds(flat // ml, 1), :]                      # (1, ml)
+    shift = jax.lax.rem(col + ml - jax.lax.rem(flat, ml), ml)
+    row = pltpu.roll(row, shift, 1)[:, :lanes]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+    return jnp.where((lane >= col) & (lane < col + s), row, jnp.uint32(0))
+
+
+def _locate(st_ref, i, lanes: int):
+    """(row, lane) of the block starting at word ``st_ref[0, 0, i]``."""
+    st = st_ref[0, 0, i]
+    return st // lanes, jax.lax.rem(st, lanes)
+
+
+def _held(row):
+    """The bits a probe tests: the words themselves for bit filters
+    (counting filters pass their nibble-occupancy view instead)."""
+    return row
+
+
+def _probe_loop_kernel(st_ref, m_ref, filt_ref, out_ref, *, s: int,
+                       theta: int, tile: int, held):
+    """Per-key membership against a VMEM-resident row view."""
+    lanes = filt_ref.shape[-1]
+    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
     def group_body(g, acc):
-        base = g * theta
-        # Θ address streams: one dynamic-slice load per cooperating "lane",
-        # Φ words each; chunk loop statically unrolled (trace-time).
-        ok_lanes = []
         for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(starts, i)
-            mrow = _mask_row(masks, i, s)
-            chunk_ok = jnp.bool_(True)
-            words_t, masks_t = [], []
-            for c in range(n_chunks):               # static unroll over Φ chunks
-                words_t.append(pl.load(filt_ref, (pl.ds(st + c * phi, phi),)))
-                masks_t.append(jax.lax.dynamic_slice(mrow, (c * phi,), (phi,)))
-            w = jnp.concatenate(words_t)            # (s,)
-            m = jnp.concatenate(masks_t)
-            ok_lanes.append((w, m))
-        # fused vector test across the Θ×s tile — the "lockstep compare"
-        Wm = jnp.stack([w for w, _ in ok_lanes])    # (theta, s)
-        Mm = jnp.stack([m for _, m in ok_lanes])
-        ok = jnp.all((Wm & Mm) == Mm, axis=-1)      # (theta,)
-        return jax.lax.dynamic_update_slice(acc, ok, (base,))
+            i = g * theta + t
+            r, c = _locate(st_ref, i, lanes)
+            row = held(filt_ref[pl.ds(r, 1), :])
+            m = _key_mask(m_ref, i, c, s, lanes)
+            ok = jnp.all((row & m) == m).astype(jnp.int32)
+            acc = jnp.where(key_lane == i, ok, acc)
+        return acc
 
-    out = jax.lax.fori_loop(0, tile // theta, group_body,
-                            jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = out
+    out_ref[0] = jax.lax.fori_loop(0, tile // theta, group_body,
+                                   jnp.zeros((1, tile), jnp.int32))
 
 
-def _add_vmem_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
-                     layout: Layout, tile: int, mix: str):
-    s, theta, phi = spec.s, layout.theta, layout.phi
-    n_chunks = s // phi
+def _update_loop(st_ref, m_ref, out_ref, *, s: int, theta: int, n_keys: int,
+                 apply):
+    """Per-key read-modify-write ``row = apply(row, mask)`` of ``out_ref``
+    rows. ``apply(row, 0)`` must leave the row unchanged: that is how the
+    lanes outside a key's block, and padded or invalid keys, are masked."""
+    lanes = out_ref.shape[-1]
 
+    def group_body(g, carry):
+        for t in range(theta):                      # static unroll over Θ
+            i = g * theta + t
+            r, c = _locate(st_ref, i, lanes)
+            out_ref[pl.ds(r, 1), :] = apply(out_ref[pl.ds(r, 1), :],
+                                            _key_mask(m_ref, i, c, s, lanes))
+        return carry
+
+    jax.lax.fori_loop(0, n_keys // theta, group_body, jnp.int32(0))
+
+
+def _update_loop_kernel(st_ref, m_ref, filt_ref, out_ref, *, s: int,
+                        theta: int, tile: int, apply):
     # Grid steps execute sequentially on a TPU core; the first step seeds the
     # output with the input filter, later steps accumulate into it (RMW —
     # ownership replaces the GPU's atomicOr, see DESIGN.md §2).
@@ -199,33 +268,151 @@ def _add_vmem_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
     def _seed():
         out_ref[...] = filt_ref[...]
 
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
+    _update_loop(st_ref, m_ref, out_ref, s=s, theta=theta, n_keys=tile,
+                 apply=apply)
 
-    def group_body(g, carry):
-        base = g * theta
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(starts, i)
-            mrow = _mask_row(masks, i, s)
-            for c in range(n_chunks):               # static unroll over Φ chunks
-                idx = (pl.ds(st + c * phi, phi),)
-                w = pl.load(out_ref, idx)
-                m = jax.lax.dynamic_slice(mrow, (c * phi,), (phi,))
-                pl.store(out_ref, idx, w | m)
-        return carry
 
-    jax.lax.fori_loop(0, tile // theta, group_body, jnp.int32(0))
+def _key_specs(tile: int, mask_tiles: jnp.ndarray):
+    return [pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),            # block starts
+            pl.BlockSpec((1,) + mask_tiles.shape[1:],
+                         lambda i: (i, 0, 0))]                # lane-dense masks
+
+
+# The row engine. Every compiled kernel of this module and of countingbf.py
+# is one of these five calls: ``words`` is the flat word array (a filter,
+# a counter array, or a bank flattened to one), ``starts`` (n,) the first
+# word of each key's row, ``masks`` (n, w) the w words to test or apply.
+
+def probe_rows_vmem(words: jnp.ndarray, starts: jnp.ndarray,
+                    masks: jnp.ndarray, *, theta: int, tile: int,
+                    interpret: bool, held=_held) -> jnp.ndarray:
+    """(n,) bool: per-key probes of a VMEM-pinned row view of ``words``."""
+    n = starts.shape[0]
+    lanes = row_lanes(words.shape[0])
+    rows = row_view(words, lanes)
+    mt = _mask_tiles(masks, tile, lanes)
+    out = pl.pallas_call(
+        functools.partial(_probe_loop_kernel, s=masks.shape[1], theta=theta,
+                          tile=tile, held=held),
+        grid=(n // tile,),
+        in_specs=_key_specs(tile, mt) + [
+            pl.BlockSpec(rows.shape, lambda i: (0, 0))],      # whole filter
+        out_specs=pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32),
+        interpret=interpret,
+    )(_tiles(starts, tile), mt, rows)
+    return out.reshape(n) != 0
+
+
+def update_rows_vmem(words: jnp.ndarray, starts: jnp.ndarray,
+                     masks: jnp.ndarray, *, theta: int, tile: int,
+                     interpret: bool, apply=jnp.bitwise_or) -> jnp.ndarray:
+    """Sequential-grid RMW of a VMEM-pinned row view; returns the updated
+    flat words."""
+    n = starts.shape[0]
+    lanes = row_lanes(words.shape[0])
+    rows = row_view(words, lanes)
+    mt = _mask_tiles(masks, tile, lanes)
+    out = pl.pallas_call(
+        functools.partial(_update_loop_kernel, s=masks.shape[1], theta=theta,
+                          tile=tile, apply=apply),
+        grid=(n // tile,),
+        in_specs=_key_specs(tile, mt) + [
+            pl.BlockSpec(rows.shape, lambda i: (0, 0))],
+        out_specs=pl.BlockSpec(rows.shape, lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
+        interpret=interpret,
+    )(_tiles(starts, tile), mt, rows)
+    return out.reshape(-1)
+
+
+# ---------------------------------------------------------------------------
+# VMEM-resident kernels (cache-resident regime analogue)
+# ---------------------------------------------------------------------------
+
+def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
+                  layout: Layout, *, interpret: bool, tile: int = DEFAULT_TILE,
+                  probe: str = "loop", coop: str = "none",
+                  mix: str = "full") -> jnp.ndarray:
+    """Bulk membership test, whole filter pinned in VMEM via BlockSpec."""
+    n = keys.shape[0]
+    assert n % tile == 0
+    assert probe in PROBES, probe
+    assert coop in COOPS, coop
+    assert mix in MIXES, mix
+    _check_lowers("vmem", probe, coop, interpret)
+    # An explicit layout is ALWAYS validated, even though the gather engine
+    # ignores it — probe is a schedule choice and must never change which
+    # (layout, tile) combinations are accepted.
+    layout = layout.validate(spec, tile)
+    if coop == "none" and probe == "loop":
+        starts, masks = fingerprints(spec, keys, mix)
+        return probe_rows_vmem(filt, starts, masks, theta=layout.theta,
+                               tile=tile, interpret=interpret)
+    if coop == "subtile":      # cooperative schedule supersedes the probe
+        kern = functools.partial(_contains_vmem_coop_kernel, spec=spec,
+                                 tile=tile, mix=mix)
+    else:
+        kern = functools.partial(_contains_vmem_gather_kernel, spec=spec,
+                                 tile=tile, mix=mix)
+    return pl.pallas_call(
+        kern,
+        grid=(n // tile,),
+        in_specs=[
+            pl.BlockSpec((tile, 2), lambda i: (i, 0)),          # key tile
+            pl.BlockSpec((spec.n_words,), lambda i: (0,)),      # whole filter
+        ],
+        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
+        interpret=interpret,
+    )(keys, filt)
+
+
+def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
+             layout: Layout, *, interpret: bool, tile: int = DEFAULT_TILE,
+             probe: str = "loop", coop: str = "none",
+             mix: str = "full") -> jnp.ndarray:
+    """Bulk insert, whole filter pinned in VMEM; sequential-grid RMW."""
+    n = keys.shape[0]
+    assert n % tile == 0
+    assert probe in PROBES, probe
+    assert coop in COOPS, coop
+    assert mix in MIXES, mix
+    _check_lowers("vmem", probe, coop, interpret)
+    layout = layout.validate(spec, tile)     # validated even on gather
+    if coop == "none" and probe == "loop":
+        starts, masks = fingerprints(spec, keys, mix)
+        return update_rows_vmem(filt, starts, masks, theta=layout.theta,
+                                tile=tile, interpret=interpret)
+    if coop == "subtile":      # cooperative schedule supersedes the probe
+        kern = functools.partial(_add_vmem_coop_kernel, spec=spec, tile=tile,
+                                 mix=mix)
+    else:
+        kern = functools.partial(_add_vmem_gather_kernel, spec=spec,
+                                 tile=tile, mix=mix)
+    return pl.pallas_call(
+        kern,
+        grid=(n // tile,),
+        in_specs=[
+            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
+            pl.BlockSpec((spec.n_words,), lambda i: (0,)),
+        ],
+        out_specs=pl.BlockSpec((spec.n_words,), lambda i: (0,)),
+        out_shape=jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32),
+        interpret=interpret,
+    )(keys, filt)
 
 
 # ---------------------------------------------------------------------------
 # Whole-tile gather-probe kernels (probe="gather")
 # ---------------------------------------------------------------------------
-# Phase 1 already hashes the whole tile in lockstep; these kernels keep
+# Interpret-only (see ``lowers``): these kernels hash in-kernel and keep
 # phase 2 on the vector unit too. contains: build the full (tile, s)
 # word-index matrix, ONE gather, ONE fused compare — no per-key loop at
 # all. add: sort the tile by block, segment-OR the masks of same-block
 # keys, then one row gather + one conflict-free row scatter (duplicate
-# indices carry identical rows). The (Θ, Φ) layout is irrelevant here —
+# indices carry identical rows). The Θ layout is irrelevant here —
 # the whole tile IS the vector.
 
 def _contains_vmem_gather_kernel(keys_ref, filt_ref, out_ref, *,
@@ -251,7 +438,7 @@ def _add_vmem_gather_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
 
 
 # ---------------------------------------------------------------------------
-# Cooperative sub-tile kernels (coop="subtile")
+# Cooperative sub-tile kernels (coop="subtile"; VMEM forms interpret-only)
 # ---------------------------------------------------------------------------
 # The cooperation axis re-slices phase 2 at WORD granularity instead of KEY
 # granularity — the TPU analogue of a lane group sharing one key's k probes:
@@ -308,94 +495,26 @@ def _add_vmem_coop_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
     out_ref[...] = f.at[si].set(words | or_w)
 
 
-def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  layout: Layout, tile: int = DEFAULT_TILE,
-                  interpret: bool = True, probe: str = "loop",
-                  coop: str = "none", mix: str = "full") -> jnp.ndarray:
-    """Bulk membership test, whole filter pinned in VMEM via BlockSpec."""
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert probe in PROBES, probe
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    grid = (n // tile,)
-    # An explicit layout is ALWAYS validated, even though the gather engine
-    # ignores it — probe is a schedule choice and must never change which
-    # (layout, tile) combinations are accepted.
-    layout = layout.validate(spec, tile)
-    if coop == "subtile":      # cooperative schedule supersedes the probe
-        kern = functools.partial(_contains_vmem_coop_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    elif probe == "gather":
-        kern = functools.partial(_contains_vmem_gather_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_contains_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),          # key tile
-            pl.BlockSpec((spec.n_words,), lambda i: (0,)),      # whole filter in VMEM
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        interpret=interpret,
-    )(keys, filt)
-
-
-def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-             layout: Layout, tile: int = DEFAULT_TILE,
-             interpret: bool = True, probe: str = "loop",
-             coop: str = "none", mix: str = "full") -> jnp.ndarray:
-    """Bulk insert, whole filter pinned in VMEM; sequential-grid RMW."""
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert probe in PROBES, probe
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    grid = (n // tile,)
-    layout = layout.validate(spec, tile)     # validated even on gather
-    if coop == "subtile":      # cooperative schedule supersedes the probe
-        kern = functools.partial(_add_vmem_coop_kernel, spec=spec, tile=tile,
-                                 mix=mix)
-    elif probe == "gather":
-        kern = functools.partial(_add_vmem_gather_kernel, spec=spec, tile=tile,
-                                 mix=mix)
-    else:
-        kern = functools.partial(_add_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((spec.n_words,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((spec.n_words,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32),
-        interpret=interpret,
-    )(keys, filt)
 
 
 # ---------------------------------------------------------------------------
 # HBM-resident kernels (DRAM-resident regime analogue) — explicit DMA
 # ---------------------------------------------------------------------------
 
-def _contains_hbm_kernel(keys_ref, filt_hbm, out_ref, scratch, sem, *,
-                         spec: FilterSpec, tile: int, depth: int, mix: str):
-    """Depth-``depth`` block-streaming pipeline: keep up to ``depth - 1``
-    block DMAs in flight ahead of the one being tested — the TPU-explicit
+def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem, *,
+                         s: int, tile: int, depth: int, held):
+    """Depth-``depth`` row-streaming pipeline: keep up to ``depth - 1``
+    row DMAs in flight ahead of the one being tested — the TPU-explicit
     version of the paper's load pipelining, with the pipeline depth a
     tunable instead of hardcoded double-buffering (depth=2)."""
-    s = spec.s
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
+    lanes = filt_hbm.shape[-1]
+    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
     def dma(i, slot):
-        st = _take_scalar(starts, i)
-        return pltpu.make_async_copy(
-            filt_hbm.at[pl.ds(st, s)], scratch.at[slot], sem.at[slot])
+        r, _ = _locate(st_ref, i, lanes)
+        return pltpu.make_async_copy(filt_hbm.at[pl.ds(r, 1)],
+                                     scratch.at[pl.ds(slot, 1)],
+                                     sem.at[slot])
 
     for d in range(depth - 1):             # static prologue: fill the pipe
         dma(d, d).start()
@@ -410,98 +529,158 @@ def _contains_hbm_kernel(keys_ref, filt_hbm, out_ref, scratch, sem, *,
             dma(i + depth - 1, jax.lax.rem(i + depth - 1, depth)).start()
 
         dma(i, slot).wait()
-        words = pl.load(scratch, (pl.ds(slot, 1), slice(None)))[0]   # (s,)
-        m = _mask_row(masks, i, s)
-        ok = jnp.all((words & m) == m)
-        return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
+        _, c = _locate(st_ref, i, lanes)
+        row = held(scratch[pl.ds(slot, 1), :])
+        m = _key_mask(m_ref, i, c, s, lanes)
+        ok = jnp.all((row & m) == m).astype(jnp.int32)
+        return jnp.where(key_lane == i, ok, acc)
 
-    out = jax.lax.fori_loop(0, tile, body, jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = out
+    out_ref[0] = jax.lax.fori_loop(0, tile, body,
+                                   jnp.zeros((1, tile), jnp.int32))
 
 
-def _add_hbm_kernel(keys_ref, filt_hbm, out_hbm, scratch, sem_r, sem_w, *,
-                    spec: FilterSpec, tile: int, mix: str):
-    """HBM insert: block-sorted coalesced DMA read-modify-write.
+def _run_bounds(st_ref, i, tile: int, lanes: int):
+    """(first, last): is key ``i`` of a row-sorted tile the first / last of
+    its row's run? Tile edges always open and close a run."""
+    r = st_ref[0, 0, i] // lanes
+    prev = st_ref[0, 0, jnp.maximum(i - 1, 0)] // lanes
+    nxt = st_ref[0, 0, jnp.minimum(i + 1, tile - 1)] // lanes
+    return (i == 0) | (r != prev), (i == tile - 1) | (r != nxt)
 
-    The tile is sorted by target block and same-block masks are OR-reduced
-    with one segmented scan (vector work, no filter traffic); the DMA loop
-    then touches each *unique* block exactly once — a single read + write
-    per block instead of one serialized RMW per key. RMW windows of
-    distinct blocks never overlap (blocks are disjoint word ranges), so the
-    ownership argument still holds with no atomics. The partitioned bulk
-    path in ops.py parallelizes this across grid steps as well.
-    """
-    s = spec.s
+
+def _contains_hbm_coop_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
+                              *, s: int, tile: int, held):
+    """Cooperative HBM contains over a tile sorted by row start (in XLA):
+    the first key of each row run fetches the row, followers test against
+    the scratch row already resident — each unique row moves over the HBM
+    bus once per tile. Results come out in sorted order."""
+    lanes = filt_hbm.shape[-1]
+    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+
+    def body(i, acc):
+        r, c = _locate(st_ref, i, lanes)
+        first, _ = _run_bounds(st_ref, i, tile, lanes)
+
+        @pl.when(first)
+        def _fetch():                      # one DMA per unique row
+            cp = pltpu.make_async_copy(filt_hbm.at[pl.ds(r, 1)], scratch,
+                                       sem.at[0])
+            cp.start()
+            cp.wait()
+
+        m = _key_mask(m_ref, i, c, s, lanes)
+        ok = jnp.all((held(scratch[...]) & m) == m).astype(jnp.int32)
+        return jnp.where(key_lane == i, ok, acc)
+
+    out_ref[0] = jax.lax.fori_loop(0, tile, body,
+                                   jnp.zeros((1, tile), jnp.int32))
+
+
+def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
+                       s: int, tile: int, apply):
+    """HBM update over a tile sorted by row start (in XLA): one
+    read-modify-write per unique row. The first key of a row run reads the
+    row, every key of the run applies its masks to the scratch row in
+    order, the last writes it back. Runs of distinct rows never overlap,
+    so the ownership argument holds with no atomics."""
+    lanes = filt_hbm.shape[-1]
 
     # Seed the output filter once (full-array DMA HBM->HBM).
     @pl.when(pl.program_id(0) == 0)
     def _seed():
-        cp = pltpu.make_async_copy(filt_hbm, out_hbm, sem_r.at[0])
+        cp = pltpu.make_async_copy(filt_hbm, out_hbm, sem.at[0])
         cp.start()
         cp.wait()
 
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
-    order = jnp.argsort(starts)
-    sst = starts[order]                                       # sorted starts
-    or_full = V.segment_totals(sst, masks[order], jnp.bitwise_or)
-    is_end = jnp.concatenate([sst[1:] != sst[:-1], jnp.ones((1,), bool)])
-
     def body(i, carry):
-        @pl.when(_take_scalar(is_end, i))
-        def _rmw():                        # one RMW per unique block
-            st = _take_scalar(sst, i)
-            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(st, s)],
-                                       scratch.at[0], sem_r.at[0])
+        r, c = _locate(st_ref, i, lanes)
+        first, last = _run_bounds(st_ref, i, tile, lanes)
+
+        @pl.when(first)
+        def _read():
+            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(r, 1)], scratch,
+                                       sem.at[0])
             rd.start()
             rd.wait()
-            row = pl.load(scratch, (pl.ds(0, 1), slice(None)))[0]
-            new = row | _mask_row(or_full, i, s)
-            pl.store(scratch, (pl.ds(1, 1), slice(None)), new[None])
-            wr = pltpu.make_async_copy(scratch.at[1],
-                                       out_hbm.at[pl.ds(st, s)], sem_w.at[0])
+
+        scratch[...] = apply(scratch[...], _key_mask(m_ref, i, c, s, lanes))
+
+        @pl.when(last)
+        def _write():
+            wr = pltpu.make_async_copy(scratch, out_hbm.at[pl.ds(r, 1)],
+                                       sem.at[0])
             wr.start()
             wr.wait()
+
         return carry
 
     jax.lax.fori_loop(0, tile, body, jnp.int32(0))
 
 
-def _contains_hbm_coop_kernel(keys_ref, filt_hbm, out_ref, scratch, sem, *,
-                              spec: FilterSpec, tile: int, mix: str):
-    """Cooperative HBM contains: the tile is sorted by block start so every
-    sub-tile of same-block keys shares ONE DMA — the sub-tile "head" (first
-    key of each block run) fetches the row, followers test against the
-    scratch row already resident. Each unique block moves over the HBM bus
-    exactly once per tile (vs once per key in the depth-ring engine);
-    results are computed in sorted order and unsorted with one scatter."""
-    s = spec.s
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
+def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
+                   masks: jnp.ndarray, *, tile: int, depth: int, coop: str,
+                   interpret: bool, held=_held) -> jnp.ndarray:
+    """(n,) bool: per-key probes streaming rows of HBM-resident ``words``.
+    ``coop="subtile"`` sorts the keys by row in XLA so each row is fetched
+    once per tile; otherwise a depth-``depth`` DMA pipeline."""
+    n = starts.shape[0]
+    lanes = row_lanes(words.shape[0])
+    if coop == "subtile":
+        order = jnp.argsort(starts)
+        starts, masks = starts[order], masks[order]
+        kern = functools.partial(_contains_hbm_coop_kernel, s=masks.shape[1],
+                                 tile=tile, held=held)
+        scratch = [pltpu.VMEM((1, lanes), jnp.uint32),      # shared row
+                   pltpu.SemaphoreType.DMA((1,))]
+    else:
+        depth = min(depth, tile)
+        kern = functools.partial(_contains_hbm_kernel, s=masks.shape[1],
+                                 tile=tile, depth=depth, held=held)
+        scratch = [pltpu.VMEM((depth, lanes), jnp.uint32),  # depth-slot ring
+                   pltpu.SemaphoreType.DMA((depth,))]
+    mt = _mask_tiles(masks, tile, lanes)
+    out = pl.pallas_call(
+        kern,
+        grid=(n // tile,),
+        in_specs=_key_specs(tile, mt) + [
+            pl.BlockSpec(memory_space=pl.ANY)],               # filter in HBM
+        out_specs=pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32),
+        scratch_shapes=scratch,
+        interpret=interpret,
+    )(_tiles(starts, tile), mt, row_view(words, lanes))
+    ok = out.reshape(n) != 0
+    if coop == "subtile":                  # back to the caller's key order
+        ok = jnp.zeros((n,), jnp.bool_).at[order].set(ok)
+    return ok
+
+
+def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
+                    masks: jnp.ndarray, *, tile: int, interpret: bool,
+                    apply=jnp.bitwise_or) -> jnp.ndarray:
+    """Row-sorted RMW of HBM-resident ``words`` (the sort runs in XLA);
+    returns the updated flat words."""
+    n = starts.shape[0]
+    lanes = row_lanes(words.shape[0])
+    rows = row_view(words, lanes)
     order = jnp.argsort(starts)
-    sst = starts[order]
-    smasks = masks[order]
-    is_head = jnp.concatenate(
-        [jnp.ones((1,), bool), sst[1:] != sst[:-1]])
-
-    def body(i, acc):
-        @pl.when(_take_scalar(is_head, i))
-        def _fetch():                      # one DMA per unique block
-            st = _take_scalar(sst, i)
-            cp = pltpu.make_async_copy(
-                filt_hbm.at[pl.ds(st, s)], scratch.at[0], sem.at[0])
-            cp.start()
-            cp.wait()
-        row = pl.load(scratch, (pl.ds(0, 1), slice(None)))[0]    # (s,)
-        m = _mask_row(smasks, i, s)
-        ok = jnp.all((row & m) == m)
-        return jax.lax.dynamic_update_slice(acc, ok[None], (i,))
-
-    sorted_ok = jax.lax.fori_loop(0, tile, body,
-                                  jnp.zeros((tile,), jnp.bool_))
-    out_ref[...] = jnp.zeros((tile,), jnp.bool_).at[order].set(sorted_ok)
+    mt = _mask_tiles(masks[order], tile, lanes)
+    out = pl.pallas_call(
+        functools.partial(_update_hbm_kernel, s=masks.shape[1], tile=tile,
+                          apply=apply),
+        grid=(n // tile,),
+        in_specs=_key_specs(tile, mt) + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((1, lanes), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((1,))],
+        interpret=interpret,
+    )(_tiles(starts[order], tile), mt, rows)
+    return out.reshape(-1)
 
 
-def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                 tile: int = DEFAULT_TILE, interpret: bool = True,
+def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
+                 interpret: bool, tile: int = DEFAULT_TILE,
                  depth: int = DEFAULT_DMA_DEPTH, coop: str = "none",
                  mix: str = "full") -> jnp.ndarray:
     n = keys.shape[0]
@@ -509,60 +688,26 @@ def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     assert depth in DMA_DEPTHS, f"depth={depth} not in {DMA_DEPTHS}"
     assert coop in COOPS, coop
     assert mix in MIXES, mix
-    depth = min(depth, tile)
-    if coop == "subtile":
-        depth = 1                          # single shared scratch row
-        kern = functools.partial(_contains_hbm_coop_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_contains_hbm_kernel, spec=spec, tile=tile,
-                                 depth=depth, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),                  # filter stays in HBM
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        scratch_shapes=[
-            pltpu.VMEM((depth, spec.s), jnp.uint32),            # depth-slot ring
-            pltpu.SemaphoreType.DMA((depth,)),
-        ],
-        interpret=interpret,
-    )(keys, filt)
+    starts, masks = fingerprints(spec, keys, mix)
+    return probe_rows_hbm(filt, starts, masks, tile=tile, depth=depth,
+                          coop=coop, interpret=interpret)
 
 
-def add_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-            tile: int = DEFAULT_TILE, interpret: bool = True,
-            coop: str = "none", mix: str = "full") -> jnp.ndarray:
-    # The HBM add is already fully cooperative: the block-sorted
-    # segment-OR schedule touches each unique block once per tile, which
-    # is exactly the coop="subtile" memory schedule. The axis is accepted
-    # (and validated) so dispatch can thread a uniform plan; both values
-    # run the same kernel.
+def add_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
+            interpret: bool, tile: int = DEFAULT_TILE, coop: str = "none",
+            mix: str = "full") -> jnp.ndarray:
+    # The HBM add is already fully cooperative: the row-sorted schedule
+    # touches each unique row once per tile, which is exactly the
+    # coop="subtile" memory schedule. The axis is accepted (and validated)
+    # so dispatch can thread a uniform plan; both values run the same
+    # kernel.
     n = keys.shape[0]
     assert n % tile == 0
     assert coop in COOPS, coop
     assert mix in MIXES, mix
-    kern = functools.partial(_add_hbm_kernel, spec=spec, tile=tile, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32),
-        scratch_shapes=[
-            pltpu.VMEM((2, spec.s), jnp.uint32),
-            pltpu.SemaphoreType.DMA((1,)),
-            pltpu.SemaphoreType.DMA((1,)),
-        ],
-        interpret=interpret,
-    )(keys, filt)
+    starts, masks = fingerprints(spec, keys, mix)
+    return update_rows_hbm(filt, starts, masks, tile=tile,
+                           interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -581,32 +726,6 @@ def _bank_starts(spec: FilterSpec, keys, member, mix: str = "full"):
     return starts + member * jnp.int32(spec.n_words), masks
 
 
-def _bank_contains_vmem_kernel(keys_ref, member_ref, filt_ref, out_ref, *,
-                               spec: FilterSpec, layout: Layout, tile: int,
-                               mix: str):
-    s, theta, phi = spec.s, layout.theta, layout.phi
-    n_chunks = s // phi
-    starts, masks = _bank_starts(spec, keys_ref[...], member_ref[...], mix)
-
-    def group_body(g, acc):
-        base = g * theta
-        lanes = []
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(starts, i)
-            mrow = _mask_row(masks, i, s)
-            words_t = [pl.load(filt_ref, (pl.ds(st + c * phi, phi),))
-                       for c in range(n_chunks)]    # static unroll over Φ
-            lanes.append((jnp.concatenate(words_t), mrow))
-        Wm = jnp.stack([w for w, _ in lanes])
-        Mm = jnp.stack([m for _, m in lanes])
-        ok = jnp.all((Wm & Mm) == Mm, axis=-1)
-        return jax.lax.dynamic_update_slice(acc, ok, (base,))
-
-    out_ref[...] = jax.lax.fori_loop(0, tile // theta, group_body,
-                                     jnp.zeros((tile,), jnp.bool_))
-
-
 def _bank_contains_vmem_gather_kernel(keys_ref, member_ref, filt_ref, out_ref,
                                       *, spec: FilterSpec, tile: int,
                                       mix: str):
@@ -615,35 +734,6 @@ def _bank_contains_vmem_gather_kernel(keys_ref, member_ref, filt_ref, out_ref,
     idx = starts[:, None] + jax.lax.broadcasted_iota(jnp.int32, (tile, s), 1)
     words = jnp.take(filt_ref[...], idx, axis=0)         # (tile, s) gather
     out_ref[...] = jnp.all((words & masks) == masks, axis=-1)
-
-
-def _bank_add_vmem_kernel(keys_ref, member_ref, valid_ref, filt_ref, out_ref,
-                          *, spec: FilterSpec, layout: Layout, tile: int,
-                          mix: str):
-    s, theta, phi = spec.s, layout.theta, layout.phi
-    n_chunks = s // phi
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    starts, masks = _bank_starts(spec, keys_ref[...], member_ref[...], mix)
-    masks = masks * valid_ref[...][:, None].astype(jnp.uint32)
-
-    def group_body(g, carry):
-        base = g * theta
-        for t in range(theta):                      # static unroll over Θ
-            i = base + t
-            st = _take_scalar(starts, i)
-            mrow = _mask_row(masks, i, s)
-            for c in range(n_chunks):               # static unroll over Φ
-                idx = (pl.ds(st + c * phi, phi),)
-                w = pl.load(out_ref, idx)
-                m = jax.lax.dynamic_slice(mrow, (c * phi,), (phi,))
-                pl.store(out_ref, idx, w | m)
-        return carry
-
-    jax.lax.fori_loop(0, tile // theta, group_body, jnp.int32(0))
 
 
 def _bank_add_vmem_gather_kernel(keys_ref, member_ref, valid_ref, filt_ref,
@@ -661,24 +751,26 @@ def _bank_add_vmem_gather_kernel(keys_ref, member_ref, valid_ref, filt_ref,
 
 
 def bank_contains_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
-                       member: jnp.ndarray, layout: Layout,
-                       tile: int = DEFAULT_TILE, interpret: bool = True,
-                       probe: str = "gather", mix: str = "full") -> jnp.ndarray:
+                       member: jnp.ndarray, layout: Layout, *,
+                       interpret: bool, tile: int = DEFAULT_TILE,
+                       probe: str = "loop", mix: str = "full") -> jnp.ndarray:
     """Flat routed membership against a (B, n_words) bank — one launch."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,)
     assert probe in PROBES, probe
     assert mix in MIXES, mix
+    _check_lowers("vmem", probe, "none", interpret)
     B, flat = bank.shape[0], bank.reshape(-1)
     layout = layout.validate(spec, tile)
-    if probe == "gather":
-        kern = functools.partial(_bank_contains_vmem_gather_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_bank_contains_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, mix=mix)
+    member = member.astype(jnp.int32)
+    if probe == "loop":
+        starts, masks = fingerprints(spec, keys, mix)
+        return probe_rows_vmem(
+            flat, starts + member * jnp.int32(spec.n_words), masks,
+            theta=layout.theta, tile=tile, interpret=interpret)
     return pl.pallas_call(
-        kern,
+        functools.partial(_bank_contains_vmem_gather_kernel, spec=spec,
+                          tile=tile, mix=mix),
         grid=(n // tile,),
         in_specs=[
             pl.BlockSpec((tile, 2), lambda i: (i, 0)),
@@ -688,29 +780,33 @@ def bank_contains_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
         out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
         interpret=interpret,
-    )(keys, member.astype(jnp.int32), flat)
+    )(keys, member, flat)
 
 
 def bank_add_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
-                  member: jnp.ndarray, valid: jnp.ndarray, layout: Layout,
-                  tile: int = DEFAULT_TILE, interpret: bool = True,
-                  probe: str = "gather", mix: str = "full") -> jnp.ndarray:
+                  member: jnp.ndarray, valid: jnp.ndarray, layout: Layout, *,
+                  interpret: bool, tile: int = DEFAULT_TILE,
+                  probe: str = "loop", mix: str = "full") -> jnp.ndarray:
     """Flat routed valid-masked insert into a (B, n_words) bank — one
     launch, sequential-grid RMW over the whole VMEM-resident bank."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,) and valid.shape == (n,)
     assert probe in PROBES, probe
     assert mix in MIXES, mix
+    _check_lowers("vmem", probe, "none", interpret)
     B, flat = bank.shape[0], bank.reshape(-1)
     layout = layout.validate(spec, tile)
-    if probe == "gather":
-        kern = functools.partial(_bank_add_vmem_gather_kernel, spec=spec,
-                                 tile=tile, bank=B, mix=mix)
-    else:
-        kern = functools.partial(_bank_add_vmem_kernel, spec=spec,
-                                 layout=layout, tile=tile, mix=mix)
+    member = member.astype(jnp.int32)
+    if probe == "loop":
+        starts, masks = fingerprints(spec, keys, mix)
+        masks = masks * valid[:, None].astype(jnp.uint32)
+        out = update_rows_vmem(
+            flat, starts + member * jnp.int32(spec.n_words), masks,
+            theta=layout.theta, tile=tile, interpret=interpret)
+        return out.reshape(B, spec.n_words)
     out = pl.pallas_call(
-        kern,
+        functools.partial(_bank_add_vmem_gather_kernel, spec=spec,
+                          tile=tile, bank=B, mix=mix),
         grid=(n // tile,),
         in_specs=[
             pl.BlockSpec((tile, 2), lambda i: (i, 0)),
@@ -721,7 +817,7 @@ def bank_add_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
         out_specs=pl.BlockSpec((B * spec.n_words,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((B * spec.n_words,), jnp.uint32),
         interpret=interpret,
-    )(keys, member.astype(jnp.int32), valid, flat)
+    )(keys, member, valid, flat)
     return out.reshape(B, spec.n_words)
 
 
@@ -729,55 +825,56 @@ def bank_add_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
 # Partitioned bulk add — the beyond-paper TPU-native path
 # ---------------------------------------------------------------------------
 
-def _add_partitioned_kernel(keys_ref, valid_ref, filt_ref, out_ref, *,
-                            spec: FilterSpec, seg_words: int, capacity: int,
-                            mix: str):
+def _update_partitioned_kernel(st_ref, m_ref, filt_ref, out_ref, *, s: int,
+                               capacity: int, apply):
     """One grid step owns one filter segment exclusively (PARALLEL-safe).
 
     Keys were pre-partitioned so every key in this step's tile lands in this
-    segment; invalid (padding) slots carry zero masks (OR no-op).
+    segment; invalid (padding) slots carry zero masks (no-op updates).
     """
-    s = spec.s
     out_ref[...] = filt_ref[...]
-    keys = pl.load(keys_ref, (pl.ds(0, 1), slice(None), slice(None)))[0]
-    valid = pl.load(valid_ref, (pl.ds(0, 1), slice(None)))[0]    # (capacity,)
-    starts, masks = _fingerprints(spec, keys, mix=mix)
-    masks = masks * valid[:, None].astype(jnp.uint32)
-    # local word offset within this segment
-    starts = jax.lax.rem(starts, jnp.int32(seg_words))
+    _update_loop(st_ref, m_ref, out_ref, s=s, theta=1, n_keys=capacity,
+                 apply=apply)
 
-    def body(i, carry):
-        st = _take_scalar(starts, i)
-        idx = (pl.ds(st, s),)
-        w = pl.load(out_ref, idx)
-        pl.store(out_ref, idx, w | _mask_row(masks, i, s))
-        return carry
 
-    jax.lax.fori_loop(0, capacity, body, jnp.int32(0))
+def update_rows_partitioned(words: jnp.ndarray, starts: jnp.ndarray,
+                            masks: jnp.ndarray, n_segments: int, *,
+                            interpret: bool,
+                            apply=jnp.bitwise_or) -> jnp.ndarray:
+    """``starts``/``masks`` hold ``n_segments`` equal groups of keys, group
+    g owning segment g of ``words``; starts are segment-local. One PARALLEL
+    grid step per segment."""
+    seg_words = words.shape[0] // n_segments
+    capacity = starts.shape[0] // n_segments
+    lanes = row_lanes(seg_words)
+    mt = _mask_tiles(masks, capacity, lanes)
+    seg = (seg_words // lanes, lanes)
+    out = pl.pallas_call(
+        functools.partial(_update_partitioned_kernel, s=masks.shape[1],
+                          capacity=capacity, apply=apply),
+        grid=(n_segments,),
+        in_specs=_key_specs(capacity, mt) + [
+            pl.BlockSpec(seg, lambda i: (i, 0))],             # own segment only
+        out_specs=pl.BlockSpec(seg, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((words.shape[0] // lanes, lanes),
+                                       jnp.uint32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),                # segments are independent
+    )(_tiles(starts, capacity), mt, row_view(words, lanes))
+    return out.reshape(-1)
 
 
 def add_partitioned(spec: FilterSpec, filt: jnp.ndarray,
                     keys_by_seg: jnp.ndarray, valid: jnp.ndarray,
-                    n_segments: int, interpret: bool = True,
+                    n_segments: int, *, interpret: bool,
                     mix: str = "full") -> jnp.ndarray:
     """keys_by_seg: (n_segments, capacity, 2); valid: (n_segments, capacity)."""
     assert spec.n_words % n_segments == 0
     assert mix in MIXES, mix
     seg_words = spec.n_words // n_segments
-    capacity = keys_by_seg.shape[1]
-    kern = functools.partial(_add_partitioned_kernel, spec=spec,
-                             seg_words=seg_words, capacity=capacity, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n_segments,),
-        in_specs=[
-            pl.BlockSpec((1, capacity, 2), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, capacity), lambda i: (i, 0)),
-            pl.BlockSpec((seg_words,), lambda i: (i,)),          # own segment only
-        ],
-        out_specs=pl.BlockSpec((seg_words,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32),
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel",)),                  # segments are independent
-    )(keys_by_seg, valid, filt)
+    starts, masks = fingerprints(spec, keys_by_seg.reshape(-1, 2), mix)
+    masks = masks * valid.reshape(-1, 1).astype(jnp.uint32)
+    return update_rows_partitioned(
+        filt, jax.lax.rem(starts, jnp.int32(seg_words)), masks, n_segments,
+        interpret=interpret)

@@ -18,6 +18,7 @@ from repro.configs import get_config, smoke_config
 from repro.models.model import build_model
 from repro.serving.engine import Engine, Request
 from repro.serving.ngram_guard import NGramGuard
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -36,6 +37,7 @@ def main(argv=None):
                     help="time-decayed guard: counting filter + one decay "
                          "per N observed steps (long-running serve loops)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -31,6 +31,7 @@ from repro.models.dist import DistContext
 from repro.models.model import build_model
 from repro.runtime.fault_tolerance import DriverConfig, TrainingDriver
 from repro.training.train_step import make_train_step, train_state_init
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -51,6 +52,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -28,7 +28,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 import numpy as np
 
 from repro.configs.base import ArchConfig
@@ -150,10 +149,10 @@ def _ffn(p, x, cfg: ArchConfig, dist: Optional[DistContext]):
         aux = {k: jax.lax.pmean(aux[k], tuple(dist.data_axes)) for k in aux}
         return out, aux
 
-    fn = shard_map(body, mesh=dist.mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=dist.mesh, in_specs=in_specs,
                        out_specs=(P(dA, None, None),
                                   {k: P() for k in ZERO_AUX}),
-                       check_rep=False)
+                       check_vma=False)
     out, aux = fn(moe_p, x)
     return out, aux
 

@@ -17,8 +17,8 @@ counts into wall time:
   on TPU: the per-grid-step issue cost).
 
 ``get_calibration()`` is cheap by default: it returns the disk-cached
-measurement for this backend if one exists, else the conservative
-per-backend defaults — it never measures unless asked
+measurement for this device kind if one exists, else the conservative
+per-device-kind defaults — it never measures unless asked
 (``measure=True`` or ``REPRO_CALIB_MEASURE=1``), so library code (the
 autotuner) can call it at trace time without timing anything. The fig4
 harness calls ``get_calibration(measure=True)`` once and persists the
@@ -43,7 +43,7 @@ _SCHEMA = 1
 class Calibration:
     """One host's practical speed-of-light constants (see module doc)."""
 
-    backend: str
+    backend: str                 # device kind the constants describe
     bw_hbm_gbs: float
     bw_res_gbs: float
     gops: float
@@ -69,23 +69,36 @@ class Calibration:
                    measured=bool(d.get("measured", False)))
 
 
-# Conservative uncalibrated defaults. TPU numbers follow the public v5e-ish
-# datasheet shape used by roofline/analysis (819 GB/s HBM); the VPU u32
-# rate and VMEM bandwidth are order-of-magnitude placeholders — a measured
-# calibration always supersedes them. CPU numbers describe a mid-range
-# server core running jnp ops (and the large interpret-mode step cost).
+# Uncalibrated constants, keyed by ``jax.devices()[0].device_kind``. A
+# device kind not listed here is an error, never a borrowed default.
+# "TPU v5 lite" (TPU v5e): HBM 819 GB/s from Google Cloud's "TPU v5e"
+# documentation; the VMEM gather bandwidth, VPU u32 rate and overheads are
+# order-of-magnitude placeholders until measured on the chip. "cpu": a
+# mid-range server core running jnp ops (and the large interpret-mode step
+# cost), not measured. A measured calibration supersedes either.
 _DEFAULTS = {
-    "tpu": dict(bw_hbm_gbs=819.0, bw_res_gbs=8000.0, gops=4000.0,
-                launch_us=3.0, step_us=0.5),
+    "TPU v5 lite": dict(bw_hbm_gbs=819.0, bw_res_gbs=8000.0, gops=4000.0,
+                        launch_us=3.0, step_us=0.5),
     "cpu": dict(bw_hbm_gbs=12.0, bw_res_gbs=40.0, gops=8.0,
                 launch_us=50.0, step_us=150.0),
 }
 
 
-def default_calibration(backend: str | None = None) -> Calibration:
-    b = backend or jax.default_backend()
-    base = _DEFAULTS.get(b, _DEFAULTS["cpu"])
-    return Calibration(backend=b, measured=False, **base)
+def device_kind() -> str:
+    return jax.devices()[0].device_kind
+
+
+def _defaults(kind: str) -> dict:
+    if kind not in _DEFAULTS:
+        raise ValueError(f"no calibration defaults for device kind "
+                         f"{kind!r}; known: {sorted(_DEFAULTS)}")
+    return dict(_DEFAULTS[kind])
+
+
+def default_calibration(kind: str | None = None) -> Calibration:
+    """Unmeasured constants for ``kind`` (default: this process's device)."""
+    kind = kind or device_kind()
+    return Calibration(backend=kind, measured=False, **_defaults(kind))
 
 
 def cache_path() -> str:
@@ -179,8 +192,9 @@ def measure_step_us(grid: int = 16) -> float:
     difference between a ``grid``-step and a 1-step launch, divided by the
     extra body executions (each body issues ~one vector op)."""
     from jax.experimental import pallas as pl
+    from repro.kernels import ops
 
-    interpret = jax.default_backend() != "tpu"
+    interpret = ops._interpret()
 
     def kern(x_ref, o_ref):
         o_ref[...] = x_ref[...] + jnp.uint32(1)
@@ -205,11 +219,13 @@ def measure_step_us(grid: int = 16) -> float:
 
 
 def measure_calibration() -> Calibration:
-    """Run the full microbench (~a second on CPU). Any individual probe
-    that fails falls back to the per-backend default for that constant —
-    a partially measured calibration beats an unmeasured one."""
-    b = jax.default_backend()
-    base = dict(_DEFAULTS.get(b, _DEFAULTS["cpu"]))
+    """Run the full microbench (~a second on CPU). On the CPU a probe that
+    fails keeps the default for that constant; on any accelerator a failed
+    probe raises, because ranking plans on unmeasured constants there
+    would hide the failure."""
+    from repro.kernels import ops
+    kind = device_kind()
+    base = _defaults(kind)
     probes = {
         "bw_hbm_gbs": measure_bw_hbm,
         "bw_res_gbs": measure_bw_res,
@@ -220,19 +236,21 @@ def measure_calibration() -> Calibration:
     for name, fn in probes.items():
         try:
             v = float(fn())
-            if np.isfinite(v) and v > 0:
-                base[name] = v
         except Exception:
-            pass                   # keep the default for this constant
-    return Calibration(backend=b, measured=True, **base)
+            if not ops._interpret():
+                raise
+            continue               # CPU: keep the default for this constant
+        if np.isfinite(v) and v > 0:
+            base[name] = v
+    return Calibration(backend=kind, measured=True, **base)
 
 
 def get_calibration(measure: bool | None = None) -> Calibration:
-    """The calibration for this backend: disk-cached measurement if one
-    exists, else (``measure`` falsy) the conservative defaults, else a
+    """The calibration for this device kind: disk-cached measurement if
+    one exists, else (``measure`` falsy) the conservative defaults, else a
     fresh measurement persisted to the disk cache."""
-    b = jax.default_backend()
-    key = f"calib|{_SCHEMA}|{b}"
+    kind = device_kind()
+    key = f"calib|{_SCHEMA}|{kind}"
     cached = _load_disk().get(key)
     if cached is not None:
         try:
@@ -242,7 +260,7 @@ def get_calibration(measure: bool | None = None) -> Calibration:
     if measure is None:
         measure = os.environ.get("REPRO_CALIB_MEASURE", "") == "1"
     if not measure:
-        return default_calibration(b)
+        return default_calibration(kind)
     calib = measure_calibration()
     _store_disk(key, calib.to_dict())
     return calib

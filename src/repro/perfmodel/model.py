@@ -105,12 +105,10 @@ def _unique_fraction(n_rows: int, tile: int) -> float:
 
 
 def _layout_trips(spec: FilterSpec, layout, tile: int) -> float:
-    """Loop-probe schedule trips per tile for a (Θ, Φ) layout."""
-    if layout is None:
-        theta, phi = 1, min(spec.s, 8)
-    else:
-        theta, phi = layout.theta, layout.phi
-    return (tile / max(theta, 1)) * (spec.s / max(phi, 1) + 1.0)
+    """Loop-probe schedule steps per tile for a Θ layout: one row load and
+    one compare or store per key, Θ keys per trip."""
+    theta = 1 if layout is None else layout.theta
+    return (tile / max(theta, 1)) * 2.0
 
 
 def op_cost(spec: FilterSpec, op: str, regime: str = "vmem", *,

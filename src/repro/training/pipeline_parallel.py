@@ -25,7 +25,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -75,10 +74,10 @@ def pipeline_apply(stage_fn: Callable, params_stacked, microbatches,
 
     in_specs = (jax.tree.map(lambda _: P(stage_axis), params_stacked),
                 P())
-    fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=in_specs,
                        out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     return fn(params_stacked, microbatches)
 
 

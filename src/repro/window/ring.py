@@ -4,9 +4,10 @@ A :class:`WindowedFilter` holds G same-spec generation sub-filters stacked
 ``(G, n_words)`` plus a head index:
 
 * ``add`` inserts into the **head** generation only;
-* ``contains`` ORs the whole ring *inside the probe* — one fused kernel
-  pass on TPU (``kernels.ring``), a fold + row-gather in jnp elsewhere;
-  the head index is irrelevant to queries, so advancing never invalidates
+* ``contains`` ORs the whole ring *inside the probe* — a fold + row
+  gather in jnp on every platform (the ``kernels.ring`` Pallas pass runs
+  in interpret mode only); the head index is irrelevant to queries, so
+  advancing never invalidates
   compiled query code;
 * ``advance()`` rotates the head to the oldest slot and zeroes it — O(1)
   in keys (one sub-filter memset, no rehashing), retiring every key whose
@@ -35,10 +36,6 @@ from repro.core import variants as V
 from repro.core.variants import FilterSpec
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # Pure ring transforms (engine seam)
 # ---------------------------------------------------------------------------
@@ -56,20 +53,14 @@ def ring_add(spec: FilterSpec, rings: jnp.ndarray, keys: jnp.ndarray,
     ``head`` may be a Python int or a traced/device int32 scalar — the
     dynamic index keeps add() retrace-free when the head is carried as
     traced state (see :class:`WindowedFilter` / ``Filter.state``)."""
-    if _on_tpu():
-        from repro.kernels import ops
-        gen = ops.bloom_add(spec, rings[head], keys)
-    else:
-        gen = V.add_rows(spec, rings[head], keys)
-    return rings.at[head].set(gen)
+    return rings.at[head].set(V.add_rows(spec, rings[head], keys))
 
 
 def ring_contains_dispatch(spec: FilterSpec, rings: jnp.ndarray,
                            keys: jnp.ndarray) -> jnp.ndarray:
-    """Fused OR-ring membership: Pallas kernel on TPU, jnp fold elsewhere."""
-    if _on_tpu():
-        from repro.kernels import ops
-        return ops.ring_contains(spec, rings, keys)
+    """Fused OR-ring membership (jnp fold). The ring Pallas kernels
+    (``ops.ring_contains``) do not lower for the TPU, so the windowed
+    engine runs this XLA path on every platform."""
     from repro.kernels.ring import ring_contains_ref
     return ring_contains_ref(spec, rings, keys)
 

@@ -16,7 +16,6 @@ from repro.core import variants as V
 from repro.core import hashing as H
 from repro.core import distributed as D
 from repro.core.distributed import or_allreduce
-from jax.experimental.shard_map import shard_map
 
 
 def main():
@@ -36,7 +35,7 @@ def main():
         jnp.arange(8 * 16, dtype=jnp.uint32).reshape(8, 16) * np.uint32(2654435761),
         NamedSharding(mesh, P("data")))
     for method in ("butterfly", "gather"):
-        out = shard_map(lambda v: or_allreduce(v, "data", method=method),
+        out = jax.shard_map(lambda v: or_allreduce(v, "data", method=method),
                         mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
         expect = np.bitwise_or.reduce(np.asarray(x), axis=0)
         for d in range(8):

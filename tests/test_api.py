@@ -271,6 +271,50 @@ def test_save_filter_restore_filter(tmp_path):
     assert bool(np.asarray(h.contains(keys)).all())
 
 
+def _drop_layout(tmp_path):
+    """Rewrite the newest manifest as a build without the layout field
+    (hash layout 1) would have written it."""
+    import json
+    import os
+    from repro.checkpoint import checkpoint as ckpt
+    path = os.path.join(str(tmp_path),
+                        f"step_{ckpt.latest_step(str(tmp_path)):08d}",
+                        "manifest.json")
+    with open(path) as fh:
+        manifest = json.load(fh)
+    del manifest["extra"]["filter_hash_layout"]
+    with open(path, "w") as fh:
+        json.dump(manifest, fh)
+
+
+@pytest.mark.parametrize("path", ["restore_filter", "inline", "from_state"])
+def test_state_from_another_hash_layout_is_refused(tmp_path, path):
+    """Words written under the old block stream would answer 'absent' for
+    keys they hold; every restore path refuses them instead."""
+    from repro.checkpoint import checkpoint as ckpt
+    from repro.core.hashing import HASH_LAYOUT
+    f = api.make_filter("sbf", m_bits=1 << 14, k=8,
+                        backend="jnp").add(_keys(100, seed=24))
+    if path == "restore_filter":
+        ckpt.save_filter(str(tmp_path), 1, f)
+        assert ckpt.manifest_extra(str(tmp_path))["filter_hash_layout"] \
+            == HASH_LAYOUT
+        _drop_layout(tmp_path)
+        restore = lambda: ckpt.restore_filter(str(tmp_path))
+    elif path == "inline":
+        state = {"dedup_filter": f}
+        ckpt.save(str(tmp_path), 1, state)
+        _drop_layout(tmp_path)
+        restore = lambda: ckpt.restore(str(tmp_path), state)
+    else:
+        st = f.to_state()
+        assert st["hash_layout"] == HASH_LAYOUT
+        del st["hash_layout"]
+        restore = lambda: api.Filter.from_state(st)
+    with pytest.raises(ValueError, match="hash layout 1"):
+        restore()
+
+
 # ---------------------------------------------------------------------------
 # Legacy spellings + shim removal
 # ---------------------------------------------------------------------------

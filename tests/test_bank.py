@@ -139,6 +139,25 @@ def test_vmem_bank_is_single_pallas_launch():
     assert jr.count("pallas_call") == 1
 
 
+def test_bank_past_vmem_reports_the_xla_reference():
+    """A bank too large for VMEM runs the jnp super-filter; the dispatch
+    says so instead of swapping silently."""
+    from repro.kernels import ops
+    spec = api.make_filter("sbf", m_bits=1 << 20).spec        # 128 KiB each
+    big, small = 64, 4                                        # 8 MiB, 512 KiB
+    keys = jnp.asarray(H.random_u64x2(64, seed=1))
+    member = jnp.arange(64, dtype=jnp.int32) % small
+    before = ops.dispatched()
+    ops.bloom_bank_add(spec, jnp.zeros((big, spec.n_words), jnp.uint32),
+                       keys, member)
+    ops.bloom_bank_add(spec, jnp.zeros((small, spec.n_words), jnp.uint32),
+                       keys, member, probe="loop", mix="full")
+    after = ops.dispatched()
+    for impl in ("xla", "pallas-vmem"):
+        key = ("bank_add", impl)
+        assert after.get(key, 0) == before.get(key, 0) + 1, impl
+
+
 def test_vmap_over_bank_axis():
     """vmap over the leading words dim sees scalar filters — no protocol."""
     keys = _bank_keys(seed0=20)

@@ -19,6 +19,8 @@ from repro.core import quotient as Q
 from repro.core import tuning
 from repro.core import variants as V
 from repro.kernels import ops, ref
+from repro.kernels import sbf as sbf_k
+from repro.kernels.sbf import default_layout
 from repro.perfmodel.calibrate import Calibration
 
 M = 1 << 16
@@ -192,6 +194,29 @@ def test_tune_plan_picks_coop_when_model_says_so(fresh_tuner):
     assert plan.coop == "subtile"
     assert plan.mix == "cheap"                     # fewer flops, tie-broken
     assert plan.probe == "gather"                  # coop canonical spelling
+
+
+def test_tune_plan_off_cpu_offers_only_what_lowers(fresh_tuner):
+    """Off the CPU the tuner ranks only schedules the TPU compiler accepts:
+    the same machine that picks coop above gets the per-key loop in VMEM,
+    the HBM regime keeps its cooperative form, and a VMEM coop pin that
+    cannot lower raises instead of failing deep in the compiler."""
+    import repro.perfmodel as PM
+    fresh_tuner.setattr(PM, "get_calibration",
+                        lambda measure=None: _calib(bw_res_gbs=1e-3))
+    fresh_tuner.setattr(ops, "_interpret", lambda: False)
+    spec = V.FilterSpec("sbf", 1 << 18, 16, block_bits=512)
+    plan = tuning.tune_plan(spec, "contains", "vmem")
+    assert (plan.probe, plan.coop) == ("loop", "none")
+    assert tuning.tune_plan(spec, "contains", "hbm").coop in ("none",
+                                                              "subtile")
+    with pytest.raises(ValueError, match="lowers"):
+        tuning.tune_plan(spec, "contains", "vmem", coop="subtile")
+    keys = jnp.asarray(H.random_u64x2(256, seed=0))
+    with pytest.raises(ValueError, match="does not lower"):
+        sbf_k.contains_vmem(spec, V.init(spec), keys,
+                            default_layout(spec, "contains"),
+                            interpret=False, probe="gather")
 
 
 def test_tune_plan_keeps_baseline_when_steps_dominate(fresh_tuner):

@@ -32,12 +32,34 @@ def test_xxh32_known_vectors():
 
 def test_seed_streams_are_independent():
     keys = jnp.asarray(H.random_u64x2(4096, seed=0))
-    a = np.asarray(H.xxh32_u64x2(keys, H.SEED_PATTERN))
-    b = np.asarray(H.xxh32_u64x2(keys, H.SEED_BLOCK))
+    a, b = (np.asarray(h) for h in H.hash_keys(keys))
     assert not np.array_equal(a, b)
     # correlation between streams should be negligible
     corr = np.corrcoef(a.astype(np.float64), b.astype(np.float64))[0, 1]
     assert abs(corr) < 0.05
+
+
+def test_pattern_collisions_do_not_share_a_block():
+    """Keys whose pattern hashes collide (~n^2 / 2^33 pairs) must still
+    scatter over blocks; if they shared one, each pair would be a false
+    positive the analytic FPR never counts."""
+    keys = jnp.asarray(H.random_u64x2(1 << 20, seed=9))
+    h1, h2 = (np.asarray(h) for h in H.hash_keys(keys))
+    order = np.argsort(h1, kind="stable")
+    same = np.flatnonzero(h1[order][1:] == h1[order][:-1])
+    assert len(same) >= 64                        # ~128 expected
+    lo, hi = order[same], order[same + 1]
+    shared = np.mean((h2[lo] & 0xFFFFF) == (h2[hi] & 0xFFFFF))
+    assert shared < 0.05, shared
+
+
+def test_fused_pair_matches_separate_streams():
+    keys = jnp.asarray(H.random_u64x2(4096, seed=2))
+    p, b = H.xxh32_u64x2_pair(keys)
+    np.testing.assert_array_equal(np.asarray(p),
+                                  np.asarray(H.xxh32_u64x2(keys)))
+    np.testing.assert_array_equal(np.asarray(b),
+                                  np.asarray(H.block_hash(keys)))
 
 
 def test_hash_uniformity():

@@ -119,7 +119,14 @@ def test_calibration_roundtrip():
 def test_default_calibration_is_unmeasured():
     c = default_calibration("cpu")
     assert not c.measured and c.backend == "cpu"
-    assert default_calibration("tpu").launch_us < c.launch_us
+    v5e = default_calibration("TPU v5 lite")
+    assert v5e.launch_us < c.launch_us and v5e.bw_hbm_gbs == 819.0
+
+
+def test_unknown_device_kind_has_no_default():
+    """An unlisted device must never borrow another's constants."""
+    with pytest.raises(ValueError, match="device kind"):
+        default_calibration("tpu")
 
 
 def test_get_calibration_defaults_without_measure(tmp_path, monkeypatch):

@@ -3,7 +3,7 @@ HBM pipeline, device-resident partitioned add, cached-jit donation layer,
 and the tile-aware tuning cache.
 
 The parity sweeps pin the acceptance criterion "gather-probe kernels are
-bit-identical to kernels/ref across variants x regimes x (Θ, Φ) x probe
+bit-identical to kernels/ref across variants x regimes x Θ x probe
 strategy"; the jit/scan tests prove the partitioned bulk add never leaves
 the device (no host numpy partition, no callbacks in the jaxpr).
 """
@@ -53,14 +53,15 @@ def test_gather_probe_matches_ref(spec, probe):
         np.asarray(c_ker), np.asarray(ref.bloom_contains_ref(spec, f_ref, keys)))
 
 
-@pytest.mark.parametrize("theta,phi", [(1, 1), (1, 8), (2, 4), (8, 1)])
+@pytest.mark.parametrize("theta,block_bits", [(1, 256), (1, 512), (2, 256),
+                                              (8, 128)])
 @pytest.mark.parametrize("probe", ["loop", "gather"])
-def test_gather_probe_layout_invariance(theta, phi, probe):
-    """The gather engine ignores (Θ, Φ) — results must match the loop path
+def test_gather_probe_layout_invariance(theta, block_bits, probe):
+    """The gather engine ignores Θ — results must match the loop path
     under every layout (layouts affect schedule, never semantics)."""
-    spec = V.FilterSpec("sbf", M, 8, block_bits=256)
+    spec = V.FilterSpec("sbf", M, 8, block_bits=block_bits)
     keys = _keys(513, seed=9)           # non-tile-multiple: padding on
-    lay = Layout(theta, phi)
+    lay = Layout(theta)
     f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
     f_ker = ops.bloom_add(spec, V.init(spec), keys, layout=lay, probe=probe)
     np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))

@@ -110,6 +110,18 @@ def test_fpr_tracks_theory(variant, kw):
     assert 0.5 * th <= fpr <= 2.0 * th, (fpr, th)
 
 
+def test_sbf_fpr_matches_theory_at_two_million_keys():
+    """At 2^21 keys the measured FPR of a 4 MiB sbf (16 bits/key) sits
+    within 1.15x of the analytic model, as on the chip (chip_smoke.py)."""
+    spec = V.FilterSpec("sbf", 1 << 25, 8)
+    n = 1 << 21
+    filt = V.add_rows(spec, V.init(spec), jnp.asarray(H.random_u64x2(n, 5)))
+    probes = jnp.asarray(H.probe_u64x2(n, seed=5))
+    fpr = float(np.asarray(V.contains_rows(spec, filt, probes)).mean())
+    th = V.fpr_sbf(spec.block_bits, 32, spec.m_bits / n, spec.k)
+    assert th / 1.15 <= fpr <= 1.15 * th, (fpr, th)
+
+
 def test_fpr_ordering_cbf_best_rbbf_worst():
     """Paper Fig. 4 x-axis ordering at iso space & k."""
     m, k, n = 1 << 19, 8, (1 << 19) // 12
