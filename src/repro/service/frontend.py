@@ -36,8 +36,11 @@ namespaced (``service.flushes``, ``admission.shed{reason=,tenant=}``)
 so filter health and service counters can merge into one ``health()``
 dict without key collisions — and ride in every flush-barrier
 checkpoint, bit-exactly. The flush pipeline is traced as nested spans
-(``service.flush`` wrapping ``pad -> launch -> sync -> results``) on the
-service clock, and each flush span is annotated with the perfmodel's
+(``service.flush`` wrapping ``pad -> h2d -> launch -> sync -> results``,
+and ``admission.refresh`` every ``health_every`` flushes) on the service
+clock, each also a profiler annotation on the device trace's clock. Each
+flush span carries the queue wait of the requests it took (``wait_s``,
+their summed wait; ``wait_max_s``, the head's) and the perfmodel's
 OpCost prediction; the drift monitor turns those annotations into
 rolling measured-vs-predicted gauges.
 """
@@ -55,11 +58,6 @@ from repro.service.admission import AdmissionController, AdmissionPolicy
 from repro.telemetry import Telemetry, TelemetryConfig
 
 OPS = ("add", "contains", "remove")
-
-# Legacy counter name -> registry metric name (the pre-telemetry flat
-# dict keys, kept as a deprecated read view for one release).
-_LEGACY_COUNTERS = ("submitted", "flushes", "size_flushes",
-                    "deadline_flushes", "flushed_ops", "padded_slots")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,14 +173,6 @@ class FilterService:
     def pending_total(self) -> int:
         return sum(p.count for p in self.pending.values())
 
-    @property
-    def counters(self) -> Dict[str, int]:
-        """DEPRECATED flat counter view (pre-§17 names). Reads from the
-        telemetry registry; mutate through telemetry, not this dict."""
-        reg = self.telemetry.registry
-        return {name: reg.counter(f"service.{name}").value
-                for name in _LEGACY_COUNTERS}
-
     def submit(self, op: str, key, tenant: int = 0,
                now: Optional[float] = None) -> int:
         """Enqueue one request; returns its seq id, or −1 if shed."""
@@ -262,9 +252,12 @@ class FilterService:
     def _flush_op(self, op: str, trigger: str) -> None:
         """Execute one fixed-shape batch of ``op`` (FIFO head, padded).
 
-        Traced as the span pipeline ``service.flush`` > ``pad`` >
-        ``launch`` > ``sync`` > ``results``; the flush span carries the
-        perfmodel OpCost annotation for its exact padded configuration.
+        Traced as the span pipeline ``service.flush`` > ``pad`` > ``h2d``
+        > ``launch`` > ``sync`` > ``results`` (> ``admission.refresh``
+        every ``health_every`` flushes); the flush span carries the queue
+        wait of the requests it took, from enqueue to the flush's start on
+        the service clock, and the perfmodel OpCost annotation for its
+        exact padded configuration.
         Launch+sync wall time is measured on the REAL clock for the
         drift monitor even when the service clock is virtual — drift is
         a report metric, not replayed service state."""
@@ -274,6 +267,9 @@ class FilterService:
             keys, tenants, t_enq, seq = self.pending[op].take(mb)
             take = keys.shape[0]
             sp.set(take=int(take), padded=int(mb - take))
+            if tracer.enabled:
+                wait = sp.t0 - t_enq
+                sp.set(wait_s=float(wait.sum()), wait_max_s=float(wait.max()))
             with tracer.span("service.flush.pad", op=op):
                 kb = np.zeros((mb, 2), np.uint32)
                 tb = np.zeros((mb,), np.int32)
@@ -281,7 +277,9 @@ class FilterService:
                 kb[:take] = keys
                 tb[:take] = tenants
                 vb[:take] = True
+            with tracer.span("service.flush.h2d", op=op):
                 kj, tj = jnp.asarray(kb), jnp.asarray(tb)
+                vj = None if op == "contains" else jnp.asarray(vb)
             t0_real = time.perf_counter()
             if op == "contains":
                 with tracer.span("service.flush.launch", op=op):
@@ -291,11 +289,10 @@ class FilterService:
             else:
                 with tracer.span("service.flush.launch", op=op):
                     if op == "add":
-                        self.filt = self.filt.add(kj, tenants=tj,
-                                                  valid=jnp.asarray(vb))
+                        self.filt = self.filt.add(kj, tenants=tj, valid=vj)
                     else:
                         self.filt = self.filt.remove(kj, tenants=tj,
-                                                     valid=jnp.asarray(vb))
+                                                     valid=vj)
                 with tracer.span("service.flush.sync", op=op):
                     jax.block_until_ready(self.filt.words)
             measured_s = time.perf_counter() - t0_real
@@ -313,7 +310,8 @@ class FilterService:
                 sp.set(**self.telemetry.drift.observe(self.filt, op, mb,
                                                       measured_s))
             if self._c_flushes.value % self.cfg.admission.health_every == 0:
-                self.admission.refresh(self.filt)
+                with tracer.span("admission.refresh"):
+                    self.admission.refresh(self.filt)
 
     # -- results / observability ----------------------------------------------
     def take_results(self) -> Dict[int, bool]:
@@ -324,33 +322,13 @@ class FilterService:
         """One namespaced, JSON-able operational snapshot: filter health
         under ``filter.*``, service counters and latency summaries under
         ``service.*``, admission under ``admission.*``, drift gauges
-        under ``perfmodel.*`` — no key collisions by construction (the
-        pre-§17 surface merged raw counter names into the filter-health
-        dict; :meth:`legacy_health` keeps that shape as a deprecated
-        view)."""
+        under ``perfmodel.*`` — no key collisions by construction."""
         out = {f"filter.{k}": v for k, v in self.filt.health().items()}
         out.update(self.telemetry.registry.snapshot())
         out["service.pending"] = self.pending_total
         sub = self._c_submitted.value
         out["admission.shed_rate"] = (
             (self.admission.shed_total / sub) if sub else 0.0)
-        return out
-
-    def legacy_health(self) -> dict:
-        """DEPRECATED pre-§17 health dict (raw filter-health keys with
-        flat counters merged on top — the key-collision surface). Kept
-        as a read-only view for one release; use :meth:`health`."""
-        import warnings
-        warnings.warn("FilterService.legacy_health() is deprecated; use "
-                      "health() (namespaced telemetry snapshot)",
-                      DeprecationWarning, stacklevel=2)
-        out = self.filt.health()
-        out.update(self.counters)
-        out["pending"] = self.pending_total
-        out["admitted"] = self.admission.admitted
-        out["shed"] = dict(self.admission.shed_counts)
-        sub = self._c_submitted.value
-        out["shed_rate"] = (self.admission.shed_total / sub) if sub else 0.0
         return out
 
     def latency_summary(self, op: Optional[str] = None,
@@ -381,14 +359,13 @@ class FilterService:
         besides the filter itself. Only meaningful at a flush barrier
         (pending queues empty — ``drain()`` first); in-flight requests are
         deliberately NOT checkpointed, they are re-fed by replay. The
-        telemetry registry (counters, histograms) rides along bit-exactly;
-        the ``counters`` dict is the deprecated flat view, written for
-        old readers."""
+        telemetry registry (counters, histograms) rides along
+        bit-exactly."""
         if self.pending_total:
             raise RuntimeError(
                 f"snapshot_state() at a non-barrier: {self.pending_total} "
                 f"requests pending — drain() first")
-        return {"seq": self._seq, "counters": dict(self.counters),
+        return {"seq": self._seq,
                 "admission": self.admission.snapshot_state(),
                 "telemetry": self.telemetry.snapshot_state()}
 

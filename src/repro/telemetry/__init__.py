@@ -1,17 +1,17 @@
 """``repro.telemetry`` — the unified observability subsystem.
 
-Replaces the four divergent ad-hoc stats surfaces that grew alongside
-the serving stack (``FilterService.counters``, ``Engine.stats()``,
-``AdmissionController.shed_counts``, bench-only ``latency_summary``)
-with one contract:
+One contract for the serving stack's counters, latencies and spans:
 
 * :class:`MetricsRegistry` — deterministic, namespaced, labeled
   :class:`Counter`/:class:`Gauge`/:class:`Histogram` metrics with
   fixed log-spaced bucket edges, bit-exact ``snapshot_state`` /
   ``restore_state`` through the service's flush-barrier checkpoints;
 * :class:`Tracer` — clock-parameterized span tracing of the service hot
-  path (``submit -> admit -> pad -> launch -> sync -> results``), JSONL
-  event export;
+  path (``submit -> admit``; ``flush -> pad -> h2d -> launch -> sync ->
+  results``, with ``admission.refresh`` every ``health_every`` flushes
+  and each flush's queue wait in ``wait_s``/``wait_max_s``), JSONL
+  event export, each span also a ``jax.profiler`` annotation so that a
+  profiler capture holds it on the device trace's clock;
 * :func:`prometheus_text` — deterministic Prometheus text snapshots;
 * :class:`DriftMonitor` — every flush annotated with the perfmodel's
   :class:`~repro.perfmodel.OpCost` prediction and rolling

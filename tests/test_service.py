@@ -37,6 +37,10 @@ def _requests(n, seed=0, n_tenants=T):
     return keys, tenants
 
 
+def _count(svc, name):
+    return svc.telemetry.registry.counter(f"service.{name}").value
+
+
 # -- batching front end -------------------------------------------------------
 
 def test_size_trigger_flushes_inline():
@@ -45,11 +49,11 @@ def test_size_trigger_flushes_inline():
     keys, tenants = _requests(40)
     svc.submit_many("add", keys, tenants)
     # 40 submitted at max_batch=16 -> two size flushes fired inline
-    assert svc.counters["size_flushes"] == 2
+    assert _count(svc, "size_flushes") == 2
     assert svc.pending_total == 8
     svc.drain()
     assert svc.pending_total == 0
-    assert svc.counters["flushed_ops"] == 40
+    assert _count(svc, "flushed_ops") == 40
 
 
 def test_deadline_trigger_via_pump():
@@ -62,7 +66,7 @@ def test_deadline_trigger_via_pump():
     assert svc.pump() == 0            # deadline not reached
     clock["t"] = 2.0
     assert svc.pump() == 1            # aged past deadline -> flushed
-    assert svc.counters["deadline_flushes"] == 1
+    assert _count(svc, "deadline_flushes") == 1
     assert svc.pending_total == 0
 
 
@@ -412,18 +416,6 @@ def test_service_health_is_namespaced():
     assert all("." in k for k in h)
 
 
-def test_service_legacy_health_view():
-    svc = FilterService(_bank(), ServiceConfig(max_batch=16))
-    keys, tenants = _requests(16, seed=14)
-    svc.submit_many("add", keys, tenants)
-    with pytest.warns(DeprecationWarning):
-        h = svc.legacy_health()
-    for k in ("fill_fraction", "flushes", "shed_rate", "pending",
-              "shed", "admitted"):
-        assert k in h
-    assert h["flushes"] == svc.counters["flushes"]
-
-
 def test_flush_spans_carry_perfmodel_prediction():
     svc = FilterService(_bank(), ServiceConfig(max_batch=16))
     keys, tenants = _requests(32, seed=15)
@@ -439,6 +431,45 @@ def test_flush_spans_carry_perfmodel_prediction():
             if s["name"].startswith("service.flush.")]
     flush_ids = {s["span"] for s in flushes}
     assert kids and all(k["parent"] in flush_ids for k in kids)
+
+
+def test_flush_span_carries_queue_wait():
+    """``wait_s`` sums, over the requests a flush takes, the flush's start
+    minus their enqueue on the service clock; ``wait_max_s`` is the
+    head's. Under a virtual clock both are exact."""
+    clock = {"t": 0.0}
+    svc = FilterService(_bank(), ServiceConfig(max_batch=16,
+                                               flush_deadline=None),
+                        clock=lambda: clock["t"])
+    keys, tenants = _requests(20, seed=17)
+    svc.submit_many("add", keys[:10], tenants[:10])
+    clock["t"] = 2.0
+    svc.submit_many("add", keys[10:], tenants[10:])   # size flush of 16
+    clock["t"] = 5.0
+    svc.drain()                                       # the 4 left
+    first, second = svc.telemetry.tracer.spans("service.flush")
+    assert (first["take"], first["wait_s"], first["wait_max_s"]) == (
+        16, 10 * 2.0, 2.0)
+    assert (second["take"], second["wait_s"], second["wait_max_s"]) == (
+        4, 4 * 3.0, 3.0)
+
+
+def test_flush_spans_the_transfer_and_the_admission_refresh():
+    """Each flush has one ``service.flush.h2d`` child; ``admission.refresh``
+    runs under every ``health_every``-th flush and no other."""
+    svc = FilterService(_bank(), ServiceConfig(
+        max_batch=4, flush_deadline=None,
+        admission=AdmissionPolicy(health_every=3)))
+    keys, tenants = _requests(4 * 7, seed=18)
+    svc.submit_many("add", keys, tenants)             # 7 size flushes
+    tr = svc.telemetry.tracer
+    nth = {f["span"]: i + 1 for i, f in enumerate(
+        sorted(tr.spans("service.flush"), key=lambda f: f["span"]))}
+    assert len(nth) == 7
+    assert sorted(nth[s["parent"]] for s in tr.spans("service.flush.h2d")
+                  ) == list(range(1, 8))
+    assert sorted(nth[s["parent"]] for s in tr.spans("admission.refresh")
+                  ) == [3, 6]
 
 
 def test_per_tenant_shed_counters():
@@ -463,6 +494,7 @@ def test_counter_continuity_across_snapshot_restore():
     svc.submit_many("add", keys, tenants)
     svc.drain()
     state = svc.snapshot_state()
+    assert "counters" not in state                # the registry carries them
     svc2 = FilterService(_bank(), ServiceConfig(max_batch=16,
                                                 flush_deadline=None))
     svc2.restore_state(svc.filt, state)
@@ -471,7 +503,29 @@ def test_counter_continuity_across_snapshot_restore():
     # restored counters keep counting from the restored totals
     svc2.submit_many("add", keys[:16], tenants[:16])
     svc2.drain()
-    assert svc2.counters["flushed_ops"] == svc.counters["flushed_ops"] + 16
+    assert _count(svc2, "flushed_ops") == _count(svc, "flushed_ops") + 16
+
+
+def test_pre_telemetry_checkpoint_restores():
+    """A checkpoint written before the telemetry registry holds only the
+    flat ``counters``; they restore into the registry's counters."""
+    svc = FilterService(_bank(), ServiceConfig(max_batch=16,
+                                               flush_deadline=None))
+    keys, tenants = _requests(32, seed=19)
+    svc.submit_many("add", keys, tenants)
+    state = svc.snapshot_state()
+    old = {"seq": state["seq"], "admission": state["admission"],
+           "counters": {name: _count(svc, name) for name in (
+               "submitted", "flushes", "size_flushes", "deadline_flushes",
+               "flushed_ops", "padded_slots")}}
+    svc2 = FilterService(_bank(), ServiceConfig(max_batch=16,
+                                                flush_deadline=None))
+    svc2.restore_state(svc.filt, old)
+    assert {n: _count(svc2, n) for n in old["counters"]} == old["counters"]
+    assert _count(svc2, "flushes") == 2
+    seqs = svc2.submit_many("add", keys[:16], tenants[:16])
+    assert seqs[0] == 32                          # the restored cursor
+    assert _count(svc2, "flushes") == 3
 
 
 def test_counter_continuity_across_reshard_and_grow():
@@ -481,13 +535,13 @@ def test_counter_continuity_across_reshard_and_grow():
     keys = np.ones((20, 2), np.uint32)
     svc.submit_many("add", keys, np.zeros(20, np.int64))
     shed_before = svc.admission.shed_by_tenant.copy()
-    flushes_before = svc.counters["flushes"]
+    flushes_before = _count(svc, "flushes")
     reshard_service(svc, bank=8)
     assert svc.admission.shed_by_tenant.shape == (8, 3)
     assert (svc.admission.shed_by_tenant[:T] == shed_before).all()
     # the registry is shared across the reshard: counters are continuous
     assert svc.telemetry.registry.counter(
         "admission.shed", reason="quota", tenant=0).value == 15
-    assert svc.counters["flushes"] >= flushes_before
+    assert _count(svc, "flushes") >= flushes_before
     assert svc.telemetry.registry.counter(
         "resharding.reshards").value == 1

@@ -135,6 +135,70 @@ def test_disabled_tracer_records_nothing():
     assert tr.spans() == [] and tr.n_started == 0
 
 
+def _profiled_annotations(log_dir, trace: bool):
+    """The service's spans that a profiler capture of two size flushes
+    (the second one refreshing admission) holds on its host planes, as
+    (name, start ns, end ns)."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro import api
+    from repro.service import AdmissionPolicy, FilterService, ServiceConfig
+    from repro.telemetry import TelemetryConfig
+
+    svc = FilterService(
+        api.make_filter_bank(2, m_bits=1 << 10),
+        ServiceConfig(max_batch=8, flush_deadline=None,
+                      admission=AdmissionPolicy(health_every=2),
+                      telemetry=TelemetryConfig(trace=trace)))
+    rng = np.random.RandomState(20)
+    keys = rng.randint(0, 2 ** 32, (16, 2)).astype(np.uint32)
+    tenants = rng.randint(0, 2, 16)
+    svc.submit_many("add", keys, tenants)        # compile outside the capture
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        svc.submit_many("add", keys, tenants)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(("service.", "admission."))]
+
+
+def test_enabled_tracer_annotates_the_profiler_trace(tmp_path):
+    """Every span is also a profiler annotation of its name alone, nested
+    as the spans are: the device trace's clock sees the program's
+    steps."""
+    anns = _profiled_annotations(tmp_path, trace=True)
+    names = [a[0] for a in anns]
+    for name, n in (("service.submit", 1), ("service.flush", 2),
+                    ("service.flush.h2d", 2), ("admission.refresh", 1)):
+        assert names.count(name) == n, names
+    flushes = [a for a in anns if a[0] == "service.flush"]
+    (submit,) = [a for a in anns if a[0] == "service.submit"]
+
+    def inside(a, b):
+        return b[1] <= a[1] and a[2] <= b[2]
+
+    assert all(inside(f, submit) for f in flushes)
+    for a in anns:
+        if a[0] in ("service.flush.h2d", "admission.refresh"):
+            assert sum(inside(a, f) for f in flushes) == 1
+    (refresh,) = [a for a in anns if a[0] == "admission.refresh"]
+    assert inside(refresh, max(flushes, key=lambda f: f[1]))
+
+
+def test_disabled_tracer_adds_no_annotation(tmp_path):
+    assert _profiled_annotations(tmp_path, trace=False) == []
+
+
 def test_trace_jsonl_golden():
     tr = Tracer(clock=_step_clock())
     with tr.span("flush", op="add"):
