@@ -9,6 +9,13 @@ This is the TPU-native realization of the paper's §4 design space:
   masks against the filter. Keeping block starts scalar is what the
   chip's compiler needs: a vector value cannot be indexed dynamically
   inside a kernel, a ref can.
+* **Deferred test.** A probe does not reduce each key's test to a scalar
+  inside the walk: each key stores the bits it misses into its row of a
+  ``(tile, lanes)`` scratch, and the tile's answers come from one
+  transpose and one reduction after the walk (:func:`_test_keys`). The
+  keys of a loop trip are then independent chains, with no vector-to-scalar
+  move and no loop-carried value between them, and a trip places all its
+  keys' masks before it waits for the first row.
 * **Row layout.** The filter words are viewed as ``(n_words / L, L)`` rows
   of ``L = min(128, n_words)`` lanes (:func:`row_view`, a free reshape).
   A block of s words never straddles a row, so a probe is one aligned
@@ -147,6 +154,9 @@ COOPS = ("none", "subtile")
 MIXES = ("full", "cheap")
 DMA_DEPTHS = (1, 2, 4, 8)
 DEFAULT_DMA_DEPTH = 2
+# Keys a trip of the HBM probe loop takes: one sublane tile of its
+# missing-bits scratch. Fixed, not tuned.
+HBM_PROBE_UNROLL = 8
 
 
 def lowers(regime: str, probe: str, coop: str) -> bool:
@@ -221,24 +231,55 @@ def _held(row):
     return row
 
 
-def _probe_loop_kernel(st_ref, m_ref, filt_ref, out_ref, *, s: int,
+def _test_keys(out_ref, miss_ref, mask_of, row_of, *, n_keys: int,
+               unroll: int, held):
+    """The deferred membership test every probe kernel shares.
+
+    ``mask_of(i)`` gives key ``i``'s mask placed in its lanes and
+    ``row_of(i)`` the filter row holding its block, both ``(1, lanes)``.
+    Inside the loop a key only stores the bits it misses,
+    ``mask & ~held(row)``, into row ``i`` of ``miss_ref``, a
+    ``(tile, lanes)`` scratch: no per-key reduction, no vector-to-scalar
+    move and no loop-carried value, so the ``unroll`` keys of one trip are
+    independent chains. A trip places all its keys' masks before it asks
+    for the first row, so the lane rotates overlap the row waits. After the
+    loop one transpose and one sublane reduction give the tile's
+    ``(1, tile)`` answers: a key is present iff its row of missing bits is
+    all zero.
+    """
+    def body(g, carry):
+        keys = [g * unroll + t for t in range(unroll)]   # static unroll
+        masks = [mask_of(i) for i in keys]
+        for i, m in zip(keys, masks):
+            miss_ref[pl.ds(i, 1), :] = m & ~held(row_of(i))
+        return carry
+
+    jax.lax.fori_loop(0, n_keys // unroll, body, jnp.int32(0))
+    missing = (miss_ref[...] != 0).astype(jnp.int32)           # (tile, lanes)
+    out_ref[0] = 1 - jnp.max(missing.T, axis=0, keepdims=True)
+
+
+def _mask_at(st_ref, m_ref, s: int, lanes: int):
+    """``mask_of`` for :func:`_test_keys`: key ``i``'s mask in the lanes
+    of its block."""
+    def mask_of(i):
+        _, c = _locate(st_ref, i, lanes)
+        return _key_mask(m_ref, i, c, s, lanes)
+    return mask_of
+
+
+def _probe_loop_kernel(st_ref, m_ref, filt_ref, out_ref, miss_ref, *, s: int,
                        theta: int, tile: int, held):
-    """Per-key membership against a VMEM-resident row view."""
+    """Membership against a VMEM-resident row view, Θ keys a loop trip,
+    tested once per tile (:func:`_test_keys`)."""
     lanes = filt_ref.shape[-1]
-    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
-    def group_body(g, acc):
-        for t in range(theta):                      # static unroll over Θ
-            i = g * theta + t
-            r, c = _locate(st_ref, i, lanes)
-            row = held(filt_ref[pl.ds(r, 1), :])
-            m = _key_mask(m_ref, i, c, s, lanes)
-            ok = jnp.all((row & m) == m).astype(jnp.int32)
-            acc = jnp.where(key_lane == i, ok, acc)
-        return acc
+    def row_of(i):
+        r, _ = _locate(st_ref, i, lanes)
+        return filt_ref[pl.ds(r, 1), :]
 
-    out_ref[0] = jax.lax.fori_loop(0, tile // theta, group_body,
-                                   jnp.zeros((1, tile), jnp.int32))
+    _test_keys(out_ref, miss_ref, _mask_at(st_ref, m_ref, s, lanes), row_of,
+               n_keys=tile, unroll=theta, held=held)
 
 
 def _update_loop(st_ref, m_ref, out_ref, *, s: int, theta: int, n_keys: int,
@@ -287,7 +328,9 @@ def _key_specs(tile: int, mask_tiles: jnp.ndarray):
 def probe_rows_vmem(words: jnp.ndarray, starts: jnp.ndarray,
                     masks: jnp.ndarray, *, theta: int, tile: int,
                     interpret: bool, held=_held) -> jnp.ndarray:
-    """(n,) bool: per-key probes of a VMEM-pinned row view of ``words``."""
+    """(n,) bool: probes of a VMEM-pinned row view of ``words``; each grid
+    step stores its keys' missing bits in a ``(tile, lanes)`` scratch and
+    tests the whole tile after its key loop."""
     n = starts.shape[0]
     lanes = row_lanes(words.shape[0])
     rows = row_view(words, lanes)
@@ -300,6 +343,7 @@ def probe_rows_vmem(words: jnp.ndarray, starts: jnp.ndarray,
             pl.BlockSpec(rows.shape, lambda i: (0, 0))],      # whole filter
         out_specs=pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((tile, lanes), jnp.uint32)],  # missing bits
         interpret=interpret,
     )(_tiles(starts, tile), mt, rows)
     return out.reshape(n) != 0
@@ -501,14 +545,16 @@ def _add_vmem_coop_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
 # HBM-resident kernels (DRAM-resident regime analogue) — explicit DMA
 # ---------------------------------------------------------------------------
 
-def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem, *,
-                         s: int, tile: int, depth: int, held):
+def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
+                         miss_ref, *, s: int, tile: int, depth: int, held):
     """Depth-``depth`` row-streaming pipeline: keep up to ``depth - 1``
     row DMAs in flight ahead of the one being tested — the TPU-explicit
     version of the paper's load pipelining, with the pipeline depth a
-    tunable instead of hardcoded double-buffering (depth=2)."""
+    tunable instead of hardcoded double-buffering (depth=2). Each loop trip
+    takes ``HBM_PROBE_UNROLL`` keys in one basic block: their masks are
+    placed first, then each key waits for its row and stores its missing
+    bits; the tile is tested after the loop (:func:`_test_keys`)."""
     lanes = filt_hbm.shape[-1]
-    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
     def dma(i, slot):
         r, _ = _locate(st_ref, i, lanes)
@@ -519,7 +565,7 @@ def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem, *,
     for d in range(depth - 1):             # static prologue: fill the pipe
         dma(d, d).start()
 
-    def body(i, acc):
+    def row_of(i):
         slot = jax.lax.rem(i, depth)
 
         # At depth=1 the offset is 0: the "prefetch" starts the current DMA
@@ -529,14 +575,10 @@ def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem, *,
             dma(i + depth - 1, jax.lax.rem(i + depth - 1, depth)).start()
 
         dma(i, slot).wait()
-        _, c = _locate(st_ref, i, lanes)
-        row = held(scratch[pl.ds(slot, 1), :])
-        m = _key_mask(m_ref, i, c, s, lanes)
-        ok = jnp.all((row & m) == m).astype(jnp.int32)
-        return jnp.where(key_lane == i, ok, acc)
+        return scratch[pl.ds(slot, 1), :]
 
-    out_ref[0] = jax.lax.fori_loop(0, tile, body,
-                                   jnp.zeros((1, tile), jnp.int32))
+    _test_keys(out_ref, miss_ref, _mask_at(st_ref, m_ref, s, lanes), row_of,
+               n_keys=tile, unroll=min(HBM_PROBE_UNROLL, tile), held=held)
 
 
 def _run_bounds(st_ref, i, tile: int, lanes: int):
@@ -549,16 +591,16 @@ def _run_bounds(st_ref, i, tile: int, lanes: int):
 
 
 def _contains_hbm_coop_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
-                              *, s: int, tile: int, held):
+                              miss_ref, *, s: int, tile: int, held):
     """Cooperative HBM contains over a tile sorted by row start (in XLA):
     the first key of each row run fetches the row, followers test against
     the scratch row already resident — each unique row moves over the HBM
-    bus once per tile. Results come out in sorted order."""
+    bus once per tile. The tile is tested after the loop
+    (:func:`_test_keys`); results come out in sorted order."""
     lanes = filt_hbm.shape[-1]
-    key_lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
 
-    def body(i, acc):
-        r, c = _locate(st_ref, i, lanes)
+    def row_of(i):
+        r, _ = _locate(st_ref, i, lanes)
         first, _ = _run_bounds(st_ref, i, tile, lanes)
 
         @pl.when(first)
@@ -568,12 +610,10 @@ def _contains_hbm_coop_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
             cp.start()
             cp.wait()
 
-        m = _key_mask(m_ref, i, c, s, lanes)
-        ok = jnp.all((held(scratch[...]) & m) == m).astype(jnp.int32)
-        return jnp.where(key_lane == i, ok, acc)
+        return scratch[...]
 
-    out_ref[0] = jax.lax.fori_loop(0, tile, body,
-                                   jnp.zeros((1, tile), jnp.int32))
+    _test_keys(out_ref, miss_ref, _mask_at(st_ref, m_ref, s, lanes), row_of,
+               n_keys=tile, unroll=1, held=held)
 
 
 def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
@@ -620,9 +660,11 @@ def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
 def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                    masks: jnp.ndarray, *, tile: int, depth: int, coop: str,
                    interpret: bool, held=_held) -> jnp.ndarray:
-    """(n,) bool: per-key probes streaming rows of HBM-resident ``words``.
+    """(n,) bool: probes streaming rows of HBM-resident ``words``.
     ``coop="subtile"`` sorts the keys by row in XLA so each row is fetched
-    once per tile; otherwise a depth-``depth`` DMA pipeline."""
+    once per tile; otherwise a depth-``depth`` DMA pipeline. Either way a
+    grid step stores its keys' missing bits in a ``(tile, lanes)`` scratch
+    and tests the whole tile after its key loop."""
     n = starts.shape[0]
     lanes = row_lanes(words.shape[0])
     if coop == "subtile":
@@ -638,6 +680,7 @@ def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                                  tile=tile, depth=depth, held=held)
         scratch = [pltpu.VMEM((depth, lanes), jnp.uint32),  # depth-slot ring
                    pltpu.SemaphoreType.DMA((depth,))]
+    scratch.append(pltpu.VMEM((tile, lanes), jnp.uint32))   # missing bits
     mt = _mask_tiles(masks, tile, lanes)
     out = pl.pallas_call(
         kern,

@@ -7,7 +7,9 @@ bit-identical to kernels/ref across variants x regimes x Θ x probe
 strategy"; the jit/scan tests prove the partitioned bulk add never leaves
 the device (no host numpy partition, no callbacks in the jaxpr).
 """
+import functools
 import os
+import re
 
 import numpy as np
 import jax
@@ -19,6 +21,7 @@ from repro.core import partition as P
 from repro.core import tuning
 from repro.core import variants as V
 from repro.kernels import ops, ref
+from repro.kernels import sbf as sbf_k
 from repro.kernels.sbf import Layout, default_layout
 
 M = 1 << 16
@@ -90,14 +93,22 @@ def test_counting_gather_matches_reference(probe):
 # HBM regime: depth-tunable contains, coalesced add
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("depth", [1, 2, 4, 8])
-def test_hbm_contains_depth_sweep(depth):
+@pytest.mark.parametrize("depth,n", [pytest.param(d, 512, id=str(d))
+                                     for d in (1, 2, 4, 8)] + [
+    pytest.param(8, 8, id="8-tile_clamped_to_8"),
+    pytest.param(2, 300, id="2-padded_300"),
+    pytest.param(8, 300, id="8-padded_300")])
+def test_hbm_contains_depth_sweep(depth, n):
+    """Tiles of 256, a tile clamped to 8 keys (one unrolled trip, depth
+    equal to the tile) and a batch padded by ``_pad_keys``."""
     spec = V.FilterSpec("sbf", M, 8, block_bits=256)
     keys = _keys(512, seed=31)
     f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
-    c = ops.bloom_contains(spec, f_ref, keys, regime="hbm", depth=depth)
-    np.testing.assert_array_equal(
-        np.asarray(c), np.asarray(ref.bloom_contains_ref(spec, f_ref, keys)))
+    probe = jnp.concatenate([keys[: n // 2], _keys(n - n // 2, seed=32)])
+    c = ops.bloom_contains(spec, f_ref, probe, regime="hbm", depth=depth)
+    want = np.asarray(ref.bloom_contains_ref(spec, f_ref, probe))
+    np.testing.assert_array_equal(np.asarray(c), want)
+    assert want[: n // 2].all() and not want.all()
 
 
 def test_hbm_coalesced_add_duplicate_blocks():
@@ -120,6 +131,142 @@ def test_counting_hbm_depth(depth):
     c = ops.counting_contains(spec, f_ref, keys, regime="hbm", depth=depth)
     np.testing.assert_array_equal(
         np.asarray(c), np.asarray(V.counting_contains(spec, f_ref, keys)))
+
+
+# ---------------------------------------------------------------------------
+# The deferred tile test shared by the probe kernels
+# ---------------------------------------------------------------------------
+
+PROBE_PATHS = [dict(regime="vmem"), dict(regime="hbm", coop="none"),
+               dict(regime="hbm", coop="subtile")]
+
+
+def _probe_rows(words, starts, masks, path, held):
+    if path["regime"] == "vmem":
+        return sbf_k.probe_rows_vmem(words, starts, masks, theta=1, tile=256,
+                                     interpret=True, held=held)
+    return sbf_k.probe_rows_hbm(words, starts, masks, tile=256, depth=8,
+                                coop=path["coop"], interpret=True, held=held)
+
+
+@pytest.mark.parametrize("path", PROBE_PATHS, ids=lambda p: "-".join(
+    p.values()))
+@pytest.mark.parametrize("n_words", [128, 4096], ids=["one_row", "32_rows"])
+@pytest.mark.parametrize("edge", ["lane0", "last_lanes"])
+@pytest.mark.parametrize("counting", [False, True], ids=["bits", "counts"])
+def test_probe_rows_blocks_at_row_edges(path, n_words, edge, counting):
+    """Every key's block at lane 0 or at ``lanes - s`` of its row, about
+    half of them present: each answer is the AND over exactly its own s
+    lanes of the row."""
+    s, n, lanes = 8, 512, sbf_k.row_lanes(n_words)
+    rng = np.random.default_rng(n_words + len(edge) + counting)
+    col = 0 if edge == "lane0" else lanes - s
+    starts = rng.integers(0, n_words // lanes, n) * lanes + col
+    held = V.nib_nonzero if counting else (lambda w: w)
+    bits = np.uint32(0x11111111) if counting else np.uint32(0xFFFFFFFF)
+    words = rng.integers(0, 1 << 32, n_words, dtype=np.uint32)
+    masks = (rng.integers(0, 1 << 32, (n, s), dtype=np.uint32)
+             & rng.integers(0, 1 << 32, (n, s), dtype=np.uint32)
+             & rng.integers(0, 1 << 32, (n, s), dtype=np.uint32) & bits)
+    h = np.asarray(held(jnp.asarray(words)))
+    keep = rng.random(n) < 0.5                # these keys' bits are all held
+    masks[keep] &= h[starts[keep, None] + np.arange(s)]
+    want = ((h[starts[:, None] + np.arange(s)] & masks) == masks).all(-1)
+    assert want.any() and not want.all()
+    got = _probe_rows(jnp.asarray(words), jnp.asarray(starts, jnp.int32),
+                      jnp.asarray(masks), path, held)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("path", PROBE_PATHS, ids=lambda p: "-".join(
+    p.values()))
+@pytest.mark.parametrize("fill", ["empty", "full", "built"])
+@pytest.mark.parametrize("variant", ["sbf", "countingbf"])
+def test_contains_whole_tiles_match_reference(path, fill, variant):
+    """Tiles all absent (an empty filter), all present (every bit or
+    every counter set) and mixed, through ``ops`` with a batch that
+    ``_pad_keys`` pads, against ``ref`` / ``V.counting_contains``."""
+    spec = V.FilterSpec(variant, M, 8, block_bits=256)
+    keys = _keys(600, seed=51)
+    counting = spec.is_counting
+    if fill == "built":
+        f = (V.counting_add(spec, V.init(spec), keys[:300]) if counting
+             else ref.bloom_add_ref(spec, V.init(spec), keys[:300]))
+    else:
+        word = 0 if fill == "empty" else (0x11111111 if counting
+                                          else 0xFFFFFFFF)
+        f = jnp.full((spec.storage_words,), word, jnp.uint32)
+    kw = dict(regime=path["regime"])
+    if "coop" in path:
+        kw["coop"] = path["coop"]
+    if counting:
+        got = ops.counting_contains(spec, f, keys, **kw)
+        want = V.counting_contains(spec, f, keys)
+    else:
+        got = ops.bloom_contains(spec, f, keys, **kw)
+        want = ref.bloom_contains_ref(spec, f, keys)
+    want = np.asarray(want)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    if fill == "empty":
+        assert not want.any()
+    else:
+        assert want.all() if fill == "full" else want[:300].all()
+
+
+def _mosaic_loop_bodies(fn, *args, monkeypatch, capsys):
+    """The bodies of the ``scf.for`` loops of the Mosaic module(s) that
+    lowering ``fn`` for the TPU prints, with ``pallas_call(debug=True)``."""
+    from jax.experimental import pallas as pl
+    orig = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call",
+                        lambda *a, **k: orig(*a, **dict(k, debug=True)))
+    jax.clear_caches()
+    capsys.readouterr()
+    jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    text = capsys.readouterr().out
+    assert "The Mosaic module" in text
+    mosaic = text[text.index("The Mosaic module"):]
+    bodies, at = [], mosaic.find("scf.for")
+    while at >= 0:
+        depth, j = 0, mosaic.index("{", at)
+        for j in range(j, len(mosaic)):
+            depth += {"{": 1, "}": -1}.get(mosaic[j], 0)
+            if depth == 0:
+                break
+        bodies.append(mosaic[at:j])
+        at = mosaic.find("scf.for", j)
+    return mosaic, bodies
+
+
+@pytest.mark.parametrize("kernel", ["hbm", "hbm_coop", "vmem"])
+def test_probe_key_loop_has_no_per_key_reduction(kernel, monkeypatch,
+                                                 capsys):
+    """The probe kernels' key loops hold no reduction and no vector-to-
+    scalar extract: every key stores its missing bits, and the tile is
+    reduced once, after the loop. The HBM pipeline unrolls 8 keys a trip."""
+    keys = jax.ShapeDtypeStruct((4096, 2), jnp.uint32)
+    if kernel == "vmem":
+        spec = V.FilterSpec("sbf", 1 << 20, 8)
+        fn = functools.partial(sbf_k.probe_rows_vmem, theta=1, tile=256,
+                               interpret=False)
+        args = (jax.ShapeDtypeStruct((16 * spec.n_words,), jnp.uint32),
+                jax.ShapeDtypeStruct((4096,), jnp.int32),
+                jax.ShapeDtypeStruct((4096, spec.s), jnp.uint32))
+    else:
+        spec = V.FilterSpec("sbf", 1 << 28, 8)
+        fn = functools.partial(
+            sbf_k.contains_hbm, spec, interpret=False, depth=8,
+            coop="subtile" if kernel == "hbm_coop" else "none")
+        args = (jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32), keys)
+    mosaic, bodies = _mosaic_loop_bodies(fn, *args, monkeypatch=monkeypatch,
+                                         capsys=capsys)
+    assert bodies
+    for body in bodies:
+        assert not re.search(r"vector\.extract\s", body), body
+        assert "vector.multi_reduction" not in body, body
+    assert "vector.multi_reduction" in mosaic      # the one tile test
+    if kernel == "hbm":
+        assert bodies[0].count("tpu.wait_dma2") == sbf_k.HBM_PROBE_UNROLL
 
 
 # ---------------------------------------------------------------------------
