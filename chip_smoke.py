@@ -21,7 +21,8 @@ Phases (one process, keys made on the device from ``--seed``):
      run on the chip over the same keys.
   --four-chips: a 16 GiB ``sharded`` filter over four chips (4 GiB each),
      2^24 keys added and looked up, each device's shard equal to a jnp
-     reference built on that device from the keys its block range owns.
+     reference built on that device from the keys its block range owns;
+     the adds and lookups must run the segments' Pallas row kernels.
 
 Each phase prints one line: engine, implementation, compile seconds, one
 warm wall time (informational only) and the device's peak bytes in use.
@@ -277,11 +278,12 @@ def phase_sharded(seed: int, clock: _CompileClock) -> None:
     from repro import api
     from repro.core import hashing as H
     from repro.core import variants as V
+    from repro.kernels import ops
 
     devices = jax.devices()[:4]
     _check(len(devices) == 4, f"--four-chips needs 4 devices, got "
                               f"{len(jax.devices())}")
-    c0 = clock.total
+    c0, d0 = clock.total, ops.dispatched()
     mesh = Mesh(np.array(devices), ("data",))
     f = api.make_filter("sbf", m_bits=1 << 37, k=8, backend="sharded",
                         mesh=mesh)
@@ -313,6 +315,10 @@ def phase_sharded(seed: int, clock: _CompileClock) -> None:
     for i in range(batches):
         _check(jnp.all(f.contains(_key_batch(seed, 400 + i, n))),
                f"batch {i}: an inserted key tests absent")
+    ran = _delta(d0, ops.dispatched())
+    _check(set(ran) == {"sharded_add:pallas-hbm",
+                        "sharded_contains:pallas-hbm"},
+           f"the segments did not run the Pallas row kernels: {ran}")
     # The references are built only now, once the adds (4 GiB shard + its
     # 4 GiB update per device) are done, and donated in place: a 4 GiB
     # reference segment beside both would leave too little of 16 GiB.
@@ -333,7 +339,8 @@ def phase_sharded(seed: int, clock: _CompileClock) -> None:
                f"device {d}: shard differs from its jnp reference")
     _report("4-chip", engine=f.backend, impl=f.engine.tpu_impl,
             filter_bytes=f.nbytes, keys_added=n * batches,
-            compile_s=clock.lap(c0), warm_add_s=warm,
+            compile_s=clock.lap(c0), warm_add_s=warm, dispatched=ran,
+            counters=f.engine.registry.snapshot(),
             peak_bytes_in_use=_peak_bytes(devices))
 
 

@@ -16,10 +16,13 @@ Semantics under the uniform protocol:
   device is always found (no false negatives). ``dense_words``/checkpoint
   state is the global OR.
 * ``sharded``: ``add``/``contains`` route keys to their segment owner via
-  fixed-capacity ``all_to_all``. Default capacity (``options.capacity`` is
-  None) is the per-device batch size — overflow-free by construction; an
-  explicit smaller capacity bounds memory and degrades conservatively
-  (dropped adds, "present" lookups — never a false negative).
+  fixed-capacity ``all_to_all``, and the owner runs the Pallas HBM row
+  kernels on its segment. The default capacity (``options.capacity`` is
+  None) is the per-device batch size, the exact one: nothing overflows.
+  An explicit smaller capacity bounds the exchange's memory and is lossy:
+  an overflowed lookup reports "present", and an overflowed add is
+  dropped, so that key later tests absent (a false negative).
+  ``ShardedBackend.registry`` counts calls, keys and exchange slots.
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import distributed as D
 from repro.core.variants import FilterSpec
+from repro.telemetry import MetricsRegistry
 from repro.api.registry import (Backend, SelectionContext, flat_members,
                                 register)
 
@@ -108,6 +112,22 @@ class ShardedBackend(_DistBackend):
 
     name = "sharded"
     supports_bank = True
+    tpu_impl = "pallas"
+
+    def __init__(self):
+        # Counters of the scalar add / contains calls, per ``op``:
+        # ``sharded.calls``, ``sharded.keys`` (keys offered) and
+        # ``sharded.exchange_slots`` (n_dev^2 x capacity, the slots the
+        # all_to_all moves).
+        self.registry = MetricsRegistry()
+
+    def count_call(self, op, spec, n_keys, options):
+        n_dev = _n_dev(options)
+        cap = self._capacity(options, -(-n_keys // n_dev))
+        reg = self.registry
+        reg.counter("sharded.calls", op=op).inc()
+        reg.counter("sharded.keys", op=op).inc(n_keys)
+        reg.counter("sharded.exchange_slots", op=op).inc(n_dev * n_dev * cap)
 
     def supports(self, spec: FilterSpec, ctx: SelectionContext) -> bool:
         if (ctx.mesh is None or spec.is_counting or spec.is_fingerprint

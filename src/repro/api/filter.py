@@ -243,6 +243,7 @@ class Filter:
             return _jit_add_valid(self, keys, jnp.asarray(valid))
         if keys.shape[0] == 0:
             return self
+        self.engine.count_call("add", self.spec, keys.shape[0], self.options)
         return _jit_add(self, keys)
 
     def contains(self, keys, tenants=None) -> jnp.ndarray:
@@ -264,6 +265,8 @@ class Filter:
             return _jit_contains_bank(self, keys)
         if keys.shape[0] == 0:
             return jnp.zeros((0,), jnp.bool_)
+        self.engine.count_call("contains", self.spec, keys.shape[0],
+                               self.options)
         return _jit_contains(self, keys)
 
     def remove(self, keys, tenants=None, valid=None) -> "Filter":

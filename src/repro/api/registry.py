@@ -204,6 +204,12 @@ class Backend:
         """(n,) bool membership for ``keys`` (n, 2) uint32."""
         raise NotImplementedError
 
+    def count_call(self, op: str, spec: FilterSpec, n_keys: int,
+                   options) -> None:
+        """Eager hook: ``Filter.add`` / ``Filter.contains`` call it on the
+        host once per call, before the jitted op (whose body runs only
+        when it is traced). Engines that count their calls override it."""
+
     def merge(self, spec: FilterSpec, a: jnp.ndarray, b: jnp.ndarray,
               options) -> jnp.ndarray:
         """OR-union of two same-shape word arrays (default: elementwise)."""

@@ -18,11 +18,17 @@ Two deployment shapes, both built on ``shard_map``:
     ranges — the distributed extension of the ownership model in
     core.partition). Bulk ops route each key to its segment owner with a
     fixed-capacity ``all_to_all`` (GShard-style: static capacity + validity
-    mask), the owner runs the single-core op on its VMEM-resident segment,
-    and lookup results ride the inverse all_to_all home. Capacity overflow
-    degrades *conservatively*: an overflowed lookup reports "present" (an
-    allowed false positive — never a false negative) and an overflowed add
-    is dropped (a missed dedup, not a correctness bug).
+    mask), the owner runs one chip's HBM-regime row engine on its segment
+    (``kernels.ops.segment_add`` / ``segment_contains``: phase 1 on the
+    segment's spec, phase 2 the Pallas HBM row kernels, padded slots making
+    no row DMA), and lookup results ride the inverse all_to_all home. A
+    segment lives in HBM: at 2^37 bits over four chips it is 4 GiB a chip.
+
+    The default capacity, one device's whole batch, is exact: no slot can
+    overflow. An explicit smaller capacity bounds the exchange's memory
+    and is lossy: an overflowed lookup reports "present" (an allowed false
+    positive), but an overflowed add is dropped, so that key later tests
+    absent — a false negative.
 
 This module holds the **pure collective transforms** (``replicated_*`` /
 ``sharded_*`` functions), consumed by the ``"replicated"`` / ``"sharded"``
@@ -31,14 +37,15 @@ the uniform ``Filter`` protocol. (The one-release ``ReplicatedFilter`` /
 ``ShardedFilter`` shims have been removed; use
 ``repro.api.make_filter(..., backend=..., mesh=...)``.)
 
-Scale note (1000+ nodes): the sharded shape keeps per-device memory at m/n
-and turns the paper's DRAM-random-access bound into a VMEM-resident-segment
-workload — the multi-device generalization of the paper's cache-resident
-fast path.
+Scale note: the sharded shape keeps per-device memory at m/n, so a filter
+too large for one chip's HBM is held by several; each owner's work is the
+single-chip DRAM-resident regime on its segment, with the keys it owns.
 """
 from __future__ import annotations
 
-import numpy as np
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -51,8 +58,15 @@ from repro.core.variants import FilterSpec
 def _zeros(shape, sharding) -> jnp.ndarray:
     """Zeroed uint32 words created in place on every device's shard; a
     host-side or single-device array would have to fit one device first."""
+    return _zeros_fn(shape, sharding)()
+
+
+@functools.lru_cache(maxsize=None)
+def _zeros_fn(shape, sharding):
+    # one jitted function per shape and sharding, so a new filter of a
+    # shape already made compiles nothing
     return jax.jit(lambda: jnp.zeros(shape, jnp.uint32),
-                   out_shardings=sharding)()
+                   out_shardings=sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -82,36 +96,29 @@ def or_allreduce(x: jnp.ndarray, axis_name: str, method: str = "butterfly"
 
 
 # ---------------------------------------------------------------------------
-# Localized single-device ops on a filter *segment*
+# One device's segment of a block-sharded filter
 # ---------------------------------------------------------------------------
 
-def _local_fingerprints(spec: FilterSpec, keys: jnp.ndarray, blocks_per_seg: int):
-    """(local word starts, masks) for keys known to belong to this segment."""
-    h1 = H.xxh32_u64x2(keys, H.SEED_PATTERN)
-    h2 = H.block_hash(keys)
-    blk = H.block_index(h2, spec.n_blocks)
-    blk_local = blk & jnp.uint32(blocks_per_seg - 1)
-    masks = V.block_patterns(spec, h1)
-    starts = (blk_local * jnp.uint32(spec.s)).astype(jnp.int32)
-    return starts, masks
+def segment_spec(spec: FilterSpec, n_dev: int) -> FilterSpec:
+    """The spec of one of ``n_dev`` block-range segments. Block indices
+    mask the hash's low bits (``hashing.block_index``), so a key's block
+    in its segment is its block in the filter masked to the segment:
+    ``blk & (blocks_per_segment - 1)``, and its owner is the high bits."""
+    return dataclasses.replace(spec, m_bits=spec.m_bits // n_dev)
 
 
-def _segment_contains(spec: FilterSpec, seg_words: jnp.ndarray,
-                      keys: jnp.ndarray, blocks_per_seg: int) -> jnp.ndarray:
-    starts, masks = _local_fingerprints(spec, keys, blocks_per_seg)
-    idx = starts[:, None] + jnp.arange(spec.s, dtype=jnp.int32)[None, :]
-    words = seg_words[idx]
-    return jnp.all((words & masks) == masks, axis=-1)
+def _segment_add(seg: FilterSpec, seg_words: jnp.ndarray, keys: jnp.ndarray,
+                 valid: jnp.ndarray) -> jnp.ndarray:
+    from repro.kernels import ops
+    with jax.named_scope("sharded.segment_update"):
+        return ops.segment_add(seg, seg_words, keys, valid)
 
 
-def _segment_add(spec: FilterSpec, seg_words: jnp.ndarray, keys: jnp.ndarray,
-                 valid: jnp.ndarray, blocks_per_seg: int) -> jnp.ndarray:
-    """OR the keys' masks into the resident segment (``variants.or_rows``:
-    row gathers and scatters, no temporary the size of the segment)."""
-    starts, masks = _local_fingerprints(spec, keys, blocks_per_seg)
-    masks = masks * valid[:, None].astype(jnp.uint32)
-    return V.or_rows(spec, seg_words, starts // spec.s, masks,
-                     n_rows=blocks_per_seg)
+def _segment_contains(seg: FilterSpec, seg_words: jnp.ndarray,
+                      keys: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
+    from repro.kernels import ops
+    with jax.named_scope("sharded.segment_probe"):
+        return ops.segment_contains(seg, seg_words, keys, valid)
 
 
 # ---------------------------------------------------------------------------
@@ -215,23 +222,37 @@ def _route(spec: FilterSpec, keys: jnp.ndarray, n_dev: int, capacity: int):
     return send, valid, seg, rank, keep
 
 
+def _exchange(send: jnp.ndarray, valid: jnp.ndarray, axis: str):
+    """Each device's per-owner buckets to their owners: the owner gets
+    (n_dev * cap, 2) keys and (n_dev * cap,) validity, one bucket from
+    each device."""
+    with jax.named_scope("sharded.exchange"):
+        recv_k = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
+        recv_v = jax.lax.all_to_all(valid, axis, 0, 0, tiled=False)
+    return recv_k.reshape(-1, 2), recv_v.reshape(-1)
+
+
+# The sharded bodies run with ``check_vma=False``: the Pallas row kernels
+# inside them carry no varying-axes type, and under interpret mode their
+# block slicing mixes varying and invariant values, which the check
+# refuses. Every value in these bodies is per device anyway.
+
 def sharded_add(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
                 words: jnp.ndarray, keys_sharded: jnp.ndarray) -> jnp.ndarray:
     """Route each device's (n_dev, n_local, 2) key shard to its segment owner
-    (all_to_all), then bit-plane OR into the owner's resident segment."""
+    (all_to_all), then run the row engine's update on the owner's
+    segment."""
     n_dev = mesh.shape[axis]
-    bps = spec.n_blocks // n_dev
+    seg = segment_spec(spec, n_dev)
 
     def body(w, keys):
-        send, valid, *_ = _route(spec, keys[0], n_dev, capacity)
-        recv_k = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
-        recv_v = jax.lax.all_to_all(valid, axis, 0, 0, tiled=False)
-        flat_k = recv_k.reshape(-1, 2)
-        flat_v = recv_v.reshape(-1)
-        return _segment_add(spec, w, flat_k, flat_v, bps)
+        with jax.named_scope("sharded.route"):
+            send, valid, *_ = _route(spec, keys[0], n_dev, capacity)
+        flat_k, flat_v = _exchange(send, valid, axis)
+        return _segment_add(seg, w, flat_k, flat_v)
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                       out_specs=P(axis), check_vma=True)
+                       out_specs=P(axis), check_vma=False)
     return fn(words, keys_sharded)
 
 
@@ -243,9 +264,10 @@ def sharded_add(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
 # ops compose TENANT routing with the existing key-routing machinery: keys
 # ride a fixed-capacity all_to_all to their member's owner device, the
 # owner runs the fused local bank op (core.variants.bank_*), and lookup
-# results ride the inverse all_to_all home. Same conservative overflow
-# contract as the block-sharded filter: overflowed adds drop (missed
-# dedup), overflowed lookups report "present" (an allowed FP, never an FN).
+# results ride the inverse all_to_all home. Same overflow contract as the
+# block-sharded filter: the default capacity is exact; under a smaller
+# explicit one an overflowed lookup reports "present" (an allowed FP) and
+# an overflowed add is dropped, a false negative when that key is asked.
 
 
 def bankshard_init(spec: FilterSpec, mesh: Mesh, axis: str, bank: int
@@ -335,23 +357,25 @@ def bankshard_contains(spec: FilterSpec, mesh: Mesh, axis: str,
 def sharded_contains(spec: FilterSpec, mesh: Mesh, axis: str, capacity: int,
                      words: jnp.ndarray, keys_sharded: jnp.ndarray
                      ) -> jnp.ndarray:
-    """Returns (n_dev, n_local) bool, sharded like the keys. Overflowed keys
-    conservatively report "present" (allowed FP, never an FN)."""
+    """Returns (n_dev, n_local) bool, sharded like the keys. A key that
+    overflowed its bucket reports "present" (an allowed false positive)."""
     n_dev = mesh.shape[axis]
-    bps = spec.n_blocks // n_dev
+    seg_spec = segment_spec(spec, n_dev)
 
     def body(w, keys):
-        k = keys[0]
-        send, valid, seg, rank, keep = _route(spec, k, n_dev, capacity)
-        recv_k = jax.lax.all_to_all(send, axis, 0, 0, tiled=False)
-        res = _segment_contains(spec, w, recv_k.reshape(-1, 2), bps)
-        res = res.reshape(n_dev, capacity)
-        back = jax.lax.all_to_all(res, axis, 0, 0, tiled=False)  # (n_dev, cap)
+        with jax.named_scope("sharded.route"):
+            send, valid, seg, rank, keep = _route(spec, keys[0], n_dev,
+                                                  capacity)
+        flat_k, flat_v = _exchange(send, valid, axis)
+        res = _segment_contains(seg_spec, w, flat_k, flat_v)
+        with jax.named_scope("sharded.exchange"):
+            back = jax.lax.all_to_all(res.reshape(n_dev, capacity), axis, 0,
+                                      0, tiled=False)     # (n_dev, cap)
         mine = back.reshape(-1)[seg * capacity + rank]
         return jnp.where(keep, mine, True)[None]
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                       out_specs=P(axis), check_vma=True)
+                       out_specs=P(axis), check_vma=False)
     return fn(words, keys_sharded)
 
 

@@ -262,6 +262,47 @@ def bloom_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# Sharded segments — one device's block range of a block-sharded filter
+# ---------------------------------------------------------------------------
+# Inside the sharded engine's shard_map body, the owner of a segment runs
+# the same two phases as one chip's filter in the HBM regime: phase 1 on
+# the segment's own spec (its block index is the filter's, masked to the
+# segment), phase 2 the HBM row kernels. ``valid`` marks the exchange's
+# slots that carry a key: padded slots set nothing and make no row DMA.
+
+def segment_add(seg_spec: FilterSpec, seg_words: jnp.ndarray,
+                keys: jnp.ndarray, valid: jnp.ndarray,
+                tile: int = DEFAULT_TILE, mix: str = "auto") -> jnp.ndarray:
+    n = keys.shape[0]
+    tile = _clamp_tile(n, tile)
+    keys, valid = _pad_keys_valid(keys, tile, valid)
+    _ran("sharded_add", "pallas-hbm")
+    _, _, m = _resolve_pcm(seg_spec, "add", "hbm", tile, "gather", "auto",
+                           mix)
+    starts, masks = sbf_k.fingerprints(seg_spec, keys, m)
+    return sbf_k.update_rows_hbm(seg_words, starts, masks, tile=tile,
+                                 interpret=_interpret(), valid=valid > 0)
+
+
+def segment_contains(seg_spec: FilterSpec, seg_words: jnp.ndarray,
+                     keys: jnp.ndarray, valid: jnp.ndarray,
+                     tile: int = DEFAULT_TILE, depth: Optional[int] = None,
+                     coop: str = "auto", mix: str = "auto") -> jnp.ndarray:
+    """(n,) bool; a padded slot's answer is meaningless."""
+    n = keys.shape[0]
+    tile = _clamp_tile(n, tile)
+    keys, valid = _pad_keys_valid(keys, tile, valid)
+    _ran("sharded_contains", "pallas-hbm")
+    _, c, m = _resolve_pcm(seg_spec, "contains", "hbm", tile, "gather", coop,
+                           mix)
+    starts, masks = sbf_k.fingerprints(seg_spec, keys, m)
+    return sbf_k.probe_rows_hbm(
+        seg_words, starts, masks, tile=tile, coop=c,
+        depth=_resolve_depth(seg_spec, "contains", depth, tile),
+        interpret=_interpret(), valid=valid > 0)[:n]
+
+
+# ---------------------------------------------------------------------------
 # Bank dispatch — B small filters, one fused device op (FilterBank)
 # ---------------------------------------------------------------------------
 # Native form: flat routed keys (keys (N, 2), member (N,)). A VMEM-resident

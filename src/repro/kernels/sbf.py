@@ -313,11 +313,33 @@ def _update_loop_kernel(st_ref, m_ref, filt_ref, out_ref, *, s: int,
                  apply=apply)
 
 
-def _key_specs(tile: int, mask_tiles: jnp.ndarray):
-    return [pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0),
+def _key_specs(tile: int, mask_tiles: jnp.ndarray, carried: bool = False):
+    """Block specs of a tile's starts (SMEM) and masks. ``carried``: the
+    grid's first operand is the scalar count of tiles that carry a key,
+    and a step past it maps to the last such tile, which the pipeline
+    then does not fetch again."""
+    if carried:
+        def index(i, n_carried):
+            return jnp.minimum(i, jnp.maximum(n_carried[0] - 1, 0)), 0, 0
+    else:
+        def index(i):
+            return i, 0, 0
+    return [pl.BlockSpec((1, 1, tile), index,
                          memory_space=pltpu.SMEM),            # block starts
-            pl.BlockSpec((1,) + mask_tiles.shape[1:],
-                         lambda i: (i, 0, 0))]                # lane-dense masks
+            pl.BlockSpec((1,) + mask_tiles.shape[1:], index)]  # lane-dense masks
+
+
+def _carried_first(valid: jnp.ndarray, starts: jnp.ndarray, n_words: int,
+                   s: int, tile: int):
+    """Sort keys so that padded slots (``valid`` false) come after every
+    carried key and carried keys are in row order. Returns (order, sorted
+    starts, count of tiles holding a carried key); padded slots take the
+    last block, so a tile's starts stay sorted."""
+    key = jnp.where(valid, starts, n_words)
+    order = jnp.argsort(key)
+    n_carried = jnp.sum(valid.astype(jnp.int32))
+    return (order, jnp.minimum(key[order], n_words - s),
+            ((n_carried + tile - 1) // tile).reshape(1))
 
 
 # The row engine. Every compiled kernel of this module and of countingbf.py
@@ -616,6 +638,15 @@ def _contains_hbm_coop_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
                n_keys=tile, unroll=1, held=held)
 
 
+def _seed_output(filt_hbm, out_hbm, sem):
+    """Seed the output filter once (full-array DMA HBM->HBM)."""
+    @pl.when(pl.program_id(0) == 0)
+    def _seed():
+        cp = pltpu.make_async_copy(filt_hbm, out_hbm, sem.at[0])
+        cp.start()
+        cp.wait()
+
+
 def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
                        s: int, tile: int, apply):
     """HBM update over a tile sorted by row start (in XLA): one
@@ -623,14 +654,28 @@ def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
     row, every key of the run applies its masks to the scratch row in
     order, the last writes it back. Runs of distinct rows never overlap,
     so the ownership argument holds with no atomics."""
-    lanes = filt_hbm.shape[-1]
+    _seed_output(filt_hbm, out_hbm, sem)
+    _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, s=s, tile=tile,
+                     apply=apply)
 
-    # Seed the output filter once (full-array DMA HBM->HBM).
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        cp = pltpu.make_async_copy(filt_hbm, out_hbm, sem.at[0])
-        cp.start()
-        cp.wait()
+
+def _update_hbm_carried_kernel(n_carried, st_ref, m_ref, filt_hbm, out_hbm,
+                               scratch, sem, *, s: int, tile: int, apply):
+    """:func:`_update_hbm_kernel` on the tiles that carry a key; the
+    tiles of padding after them make no row DMA. The output aliases the
+    input filter, so there is no seed copy: one DMA of a segment of 4 GiB
+    or more is past what a DMA can carry."""
+    del filt_hbm
+
+    @pl.when(pl.program_id(0) < n_carried[0])
+    def _update():
+        _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, s=s,
+                         tile=tile, apply=apply)
+
+
+def _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, *, s: int,
+                     tile: int, apply):
+    lanes = out_hbm.shape[-1]
 
     def body(i, carry):
         r, c = _locate(st_ref, i, lanes)
@@ -657,19 +702,46 @@ def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
     jax.lax.fori_loop(0, tile, body, jnp.int32(0))
 
 
+def _probe_carried_kernel(n_carried, *refs, probe):
+    """``probe`` on the tiles that carry a key; a tile of padding makes no
+    row DMA and answers 0."""
+    carried = pl.program_id(0) < n_carried[0]
+    out_ref = refs[3]                  # after starts, masks and the filter
+
+    @pl.when(carried)
+    def _probe():
+        probe(*refs)
+
+    @pl.when(jnp.logical_not(carried))
+    def _padding():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+
 def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                    masks: jnp.ndarray, *, tile: int, depth: int, coop: str,
-                   interpret: bool, held=_held) -> jnp.ndarray:
+                   interpret: bool, held=_held, valid=None) -> jnp.ndarray:
     """(n,) bool: probes streaming rows of HBM-resident ``words``.
     ``coop="subtile"`` sorts the keys by row in XLA so each row is fetched
     once per tile; otherwise a depth-``depth`` DMA pipeline. Either way a
     grid step stores its keys' missing bits in a ``(tile, lanes)`` scratch
-    and tests the whole tile after its key loop."""
+    and tests the whole tile after its key loop.
+
+    ``valid`` (n,) marks the slots that carry a key (an exchange's padded
+    slots are false): the keys are then sorted by row with the padding
+    last, and tiles that hold only padding make no row DMA. A padded
+    slot's answer is meaningless."""
     n = starts.shape[0]
     lanes = row_lanes(words.shape[0])
-    if coop == "subtile":
+    s = masks.shape[1]
+    n_carried = None
+    if valid is not None:
+        order, starts, n_carried = _carried_first(valid, starts,
+                                                  words.shape[0], s, tile)
+        masks = masks[order]
+    elif coop == "subtile":
         order = jnp.argsort(starts)
         starts, masks = starts[order], masks[order]
+    if coop == "subtile":
         kern = functools.partial(_contains_hbm_coop_kernel, s=masks.shape[1],
                                  tile=tile, held=held)
         scratch = [pltpu.VMEM((1, lanes), jnp.uint32),      # shared row
@@ -682,43 +754,89 @@ def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                    pltpu.SemaphoreType.DMA((depth,))]
     scratch.append(pltpu.VMEM((tile, lanes), jnp.uint32))   # missing bits
     mt = _mask_tiles(masks, tile, lanes)
-    out = pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=_key_specs(tile, mt) + [
-            pl.BlockSpec(memory_space=pl.ANY)],               # filter in HBM
-        out_specs=pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32),
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(_tiles(starts, tile), mt, row_view(words, lanes))
+    operands = (_tiles(starts, tile), mt, row_view(words, lanes))
+    out_shape = jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32)
+    if n_carried is None:
+        out = pl.pallas_call(
+            kern,
+            grid=(n // tile,),
+            in_specs=_key_specs(tile, mt) + [
+                pl.BlockSpec(memory_space=pl.ANY)],           # filter in HBM
+            out_specs=pl.BlockSpec((1, 1, tile), lambda i: (i, 0, 0)),
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(*operands)
+    else:
+        out = pl.pallas_call(
+            functools.partial(_probe_carried_kernel, probe=kern),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n // tile,),
+                in_specs=_key_specs(tile, mt, carried=True) + [
+                    pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((1, 1, tile),
+                                       lambda i, n_carried: (i, 0, 0)),
+                scratch_shapes=scratch),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(n_carried, *operands)
     ok = out.reshape(n) != 0
-    if coop == "subtile":                  # back to the caller's key order
+    if coop == "subtile" or valid is not None:   # back to the caller's order
         ok = jnp.zeros((n,), jnp.bool_).at[order].set(ok)
     return ok
 
 
 def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                     masks: jnp.ndarray, *, tile: int, interpret: bool,
-                    apply=jnp.bitwise_or) -> jnp.ndarray:
+                    apply=jnp.bitwise_or, valid=None) -> jnp.ndarray:
     """Row-sorted RMW of HBM-resident ``words`` (the sort runs in XLA);
-    returns the updated flat words."""
+    returns the updated flat words.
+
+    ``valid`` (n,) marks the slots that carry a key (an exchange's padded
+    slots are false): padded slots then apply a zero mask, sort after
+    every carried key, and the tiles that hold only padding make no row
+    DMA, so the row work follows the keys carried, not the slots."""
     n = starts.shape[0]
     lanes = row_lanes(words.shape[0])
     rows = row_view(words, lanes)
-    order = jnp.argsort(starts)
+    s = masks.shape[1]
+    scratch = [pltpu.VMEM((1, lanes), jnp.uint32),
+               pltpu.SemaphoreType.DMA((1,))]
+    out_shape = jax.ShapeDtypeStruct(rows.shape, jnp.uint32)
+    if valid is None:
+        order = jnp.argsort(starts)
+        mt = _mask_tiles(masks[order], tile, lanes)
+        out = pl.pallas_call(
+            functools.partial(_update_hbm_kernel, s=s, tile=tile,
+                              apply=apply),
+            grid=(n // tile,),
+            in_specs=_key_specs(tile, mt) + [
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            out_shape=out_shape,
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(_tiles(starts[order], tile), mt, rows)
+        return out.reshape(-1)
+    order, starts, n_carried = _carried_first(valid, starts, words.shape[0],
+                                              s, tile)
+    masks = jnp.where(valid[:, None], masks, jnp.uint32(0))
     mt = _mask_tiles(masks[order], tile, lanes)
     out = pl.pallas_call(
-        functools.partial(_update_hbm_kernel, s=masks.shape[1], tile=tile,
+        functools.partial(_update_hbm_carried_kernel, s=s, tile=tile,
                           apply=apply),
-        grid=(n // tile,),
-        in_specs=_key_specs(tile, mt) + [pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((1, lanes), jnp.uint32),
-                        pltpu.SemaphoreType.DMA((1,))],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // tile,),
+            in_specs=_key_specs(tile, mt, carried=True) + [
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        input_output_aliases={3: 0},
         interpret=interpret,
-    )(_tiles(starts[order], tile), mt, rows)
+    )(n_carried, _tiles(starts, tile), mt, rows)
     return out.reshape(-1)
 
 

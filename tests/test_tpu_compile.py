@@ -11,9 +11,11 @@ from __future__ import annotations
 import functools
 import os
 
+import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.variants import FilterSpec
@@ -29,7 +31,7 @@ N_KEYS = 1 << 12
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -42,8 +44,18 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.array(topo.devices), ("data",))
 
 
 def _compile(fn, *shapes):
@@ -84,6 +96,32 @@ def test_hbm_add_compiles_at_1gib(one_chip):
     c = _compile(fn, _words(HBM_1GIB, one_chip), _keys(one_chip))
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < HBM_1GIB.n_words * 4
+
+
+@pytest.mark.parametrize("op", ["contains", "add"])
+def test_sharded_engine_compiles_at_16gib_on_four_chips(four_chips,
+                                                        monkeypatch, op):
+    """The sharded engine's add and contains over four v5e chips, 4 GiB a
+    segment, 2^18 keys a device: the segment's row kernel is in the
+    program, beside the all_to_all. The update aliases its segment: one
+    DMA of a whole 4 GiB segment is past what the chip's DMA carries."""
+    from repro.core import distributed as D
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)   # the chip's path
+    spec = FilterSpec("sbf", 1 << 37, 8)
+    n_local = 1 << 18
+    on_mesh = NamedSharding(four_chips, P("data"))
+    words = jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32,
+                                 sharding=on_mesh)
+    keys = jax.ShapeDtypeStruct((4, n_local, 2), jnp.uint32,
+                                sharding=on_mesh)
+    entry = D.sharded_add if op == "add" else D.sharded_contains
+    c = _compile(functools.partial(entry, spec, four_chips, "data", n_local),
+                 words, keys)
+    text = c.as_text()
+    assert "tpu_custom_call" in text and "all-to-all" in text
+    # the segment's row view is a bitcast: no temporary a segment in size
+    assert c.memory_analysis().temp_size_in_bytes < spec.n_words * 4 // 8
 
 
 @pytest.mark.parametrize("op", ["contains", "add"])
