@@ -359,8 +359,11 @@ def update_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                tile: int = DEFAULT_TILE, coop: str = "none",
                mix: str = "full") -> jnp.ndarray:
     """Row-sorted RMW of DMA-streamed counter rows. Like sbf.add_hbm the
-    schedule is already cooperative (one read and write per unique row per
-    tile); coop is validated and both values run the same kernel."""
+    schedule is already cooperative: one read and one write per unique row
+    per tile, the tile's reads in flight together and its writes drained
+    at the tile's end. A run's keys still apply in order to the one copy
+    of its row, so the saturating counts stay exact; coop is validated
+    and both values run the same kernel."""
     n = keys.shape[0]
     assert n % tile == 0
     assert coop in COOPS, coop

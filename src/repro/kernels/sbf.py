@@ -33,6 +33,9 @@ This is the TPU-native realization of the paper's §4 design space:
 * **Ownership instead of atomics**: TPU Pallas grids execute sequentially on
   a core, and the partitioned bulk path gives each grid step an exclusive
   filter segment, so read-modify-write needs no atomics (DESIGN.md §2).
+  The HBM update keeps a tile's row DMAs in flight together: the runs of
+  a row-sorted tile are distinct rows, and the tile waits for its writes
+  before the next tile reads, which orders a row shared across the edge.
 
 The whole-tile gather probe and the VMEM cooperative kernels use an
 in-kernel gather or sort, which the chip's compiler refuses; they run only
@@ -647,20 +650,20 @@ def _seed_output(filt_hbm, out_hbm, sem):
         cp.wait()
 
 
-def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, scratch, sem, *,
-                       s: int, tile: int, apply):
+def _update_hbm_kernel(st_ref, m_ref, filt_hbm, out_hbm, rows_ref, run_rows,
+                       sem, *, s: int, tile: int, apply):
     """HBM update over a tile sorted by row start (in XLA): one
-    read-modify-write per unique row. The first key of a row run reads the
-    row, every key of the run applies its masks to the scratch row in
-    order, the last writes it back. Runs of distinct rows never overlap,
-    so the ownership argument holds with no atomics."""
+    read-modify-write per unique row, with the tile's row DMAs in flight
+    together (:func:`_update_hbm_tile`). The first grid step seeds the
+    output with the input filter before its first row read."""
     _seed_output(filt_hbm, out_hbm, sem)
-    _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, s=s, tile=tile,
-                     apply=apply)
+    _update_hbm_tile(st_ref, m_ref, out_hbm, rows_ref, run_rows, sem, s=s,
+                     tile=tile, apply=apply)
 
 
 def _update_hbm_carried_kernel(n_carried, st_ref, m_ref, filt_hbm, out_hbm,
-                               scratch, sem, *, s: int, tile: int, apply):
+                               rows_ref, run_rows, sem, *, s: int, tile: int,
+                               apply):
     """:func:`_update_hbm_kernel` on the tiles that carry a key; the
     tiles of padding after them make no row DMA. The output aliases the
     input filter, so there is no seed copy: one DMA of a segment of 4 GiB
@@ -669,37 +672,69 @@ def _update_hbm_carried_kernel(n_carried, st_ref, m_ref, filt_hbm, out_hbm,
 
     @pl.when(pl.program_id(0) < n_carried[0])
     def _update():
-        _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, s=s,
-                         tile=tile, apply=apply)
+        _update_hbm_tile(st_ref, m_ref, out_hbm, rows_ref, run_rows, sem,
+                         s=s, tile=tile, apply=apply)
 
 
-def _update_hbm_tile(st_ref, m_ref, out_hbm, scratch, sem, *, s: int,
-                     tile: int, apply):
+def _update_hbm_tile(st_ref, m_ref, out_hbm, rows_ref, run_rows, sem, *,
+                     s: int, tile: int, apply):
+    """One tile of row-sorted keys in three phases. Reads: the first key of
+    each row run j notes its row in ``run_rows[j]`` and starts the row's
+    DMA into slot j of ``rows_ref`` (``(tile, lanes)`` VMEM), with no wait.
+    Apply: the keys in order; run j's first key waits for its read, every
+    key applies its mask to slot j, and the last starts the row's write,
+    again with no wait. Drain: the tile waits for every write it started.
+
+    The runs of a sorted tile are distinct rows, so their DMAs never
+    overlap; a row can end one tile and begin the next, and the drain
+    lands this tile's write before the next tile reads it. Run j's read
+    and write share semaphore j, since the read is waited for before the
+    write starts."""
     lanes = out_hbm.shape[-1]
 
-    def body(i, carry):
+    def copy(j, r, read: bool):
+        row, slot = out_hbm.at[pl.ds(r, 1)], rows_ref.at[pl.ds(j, 1)]
+        src, dst = (row, slot) if read else (slot, row)
+        return pltpu.make_async_copy(src, dst, sem.at[j])
+
+    def read(i, carry):
+        j, prev = carry
+        r, _ = _locate(st_ref, i, lanes)
+        first = r != prev
+
+        @pl.when(first)
+        def _start():
+            run_rows[j] = r
+            copy(j, r, True).start()
+
+        return j + first.astype(jnp.int32), r
+
+    n_runs, _ = jax.lax.fori_loop(0, tile, read, (jnp.int32(0), jnp.int32(-1)))
+
+    def update(i, j):
         r, c = _locate(st_ref, i, lanes)
         first, last = _run_bounds(st_ref, i, tile, lanes)
 
         @pl.when(first)
-        def _read():
-            rd = pltpu.make_async_copy(out_hbm.at[pl.ds(r, 1)], scratch,
-                                       sem.at[0])
-            rd.start()
-            rd.wait()
+        def _arrive():
+            copy(j, r, True).wait()
 
-        scratch[...] = apply(scratch[...], _key_mask(m_ref, i, c, s, lanes))
+        rows_ref[pl.ds(j, 1), :] = apply(rows_ref[pl.ds(j, 1), :],
+                                         _key_mask(m_ref, i, c, s, lanes))
 
         @pl.when(last)
         def _write():
-            wr = pltpu.make_async_copy(scratch, out_hbm.at[pl.ds(r, 1)],
-                                       sem.at[0])
-            wr.start()
-            wr.wait()
+            copy(j, r, False).start()
 
+        return j + last.astype(jnp.int32)
+
+    jax.lax.fori_loop(0, tile, update, jnp.int32(0))
+
+    def drain(j, carry):
+        copy(j, run_rows[j], False).wait()
         return carry
 
-    jax.lax.fori_loop(0, tile, body, jnp.int32(0))
+    jax.lax.fori_loop(0, n_runs, drain, jnp.int32(0))
 
 
 def _probe_carried_kernel(n_carried, *refs, probe):
@@ -791,7 +826,10 @@ def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
                     masks: jnp.ndarray, *, tile: int, interpret: bool,
                     apply=jnp.bitwise_or, valid=None) -> jnp.ndarray:
     """Row-sorted RMW of HBM-resident ``words`` (the sort runs in XLA);
-    returns the updated flat words.
+    returns the updated flat words. Each grid step reads every row its
+    tile touches at once, applies the keys, and writes each row back as
+    its run ends, waiting for the writes once at the tile's end
+    (:func:`_update_hbm_tile`).
 
     ``valid`` (n,) marks the slots that carry a key (an exchange's padded
     slots are false): padded slots then apply a zero mask, sort after
@@ -801,8 +839,9 @@ def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
     lanes = row_lanes(words.shape[0])
     rows = row_view(words, lanes)
     s = masks.shape[1]
-    scratch = [pltpu.VMEM((1, lanes), jnp.uint32),
-               pltpu.SemaphoreType.DMA((1,))]
+    scratch = [pltpu.VMEM((tile, lanes), jnp.uint32),    # a slot a run
+               pltpu.SMEM((tile,), jnp.int32),           # each run's row
+               pltpu.SemaphoreType.DMA((tile,))]
     out_shape = jax.ShapeDtypeStruct(rows.shape, jnp.uint32)
     if valid is None:
         order = jnp.argsort(starts)
@@ -858,10 +897,10 @@ def add_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
             interpret: bool, tile: int = DEFAULT_TILE, coop: str = "none",
             mix: str = "full") -> jnp.ndarray:
     # The HBM add is already fully cooperative: the row-sorted schedule
-    # touches each unique row once per tile, which is exactly the
-    # coop="subtile" memory schedule. The axis is accepted (and validated)
-    # so dispatch can thread a uniform plan; both values run the same
-    # kernel.
+    # reads and writes each unique row once per tile, with the tile's row
+    # DMAs in flight together, which is exactly the coop="subtile" memory
+    # schedule. The axis is accepted (and validated) so dispatch can thread
+    # a uniform plan; both values run the same kernel.
     n = keys.shape[0]
     assert n % tile == 0
     assert coop in COOPS, coop
