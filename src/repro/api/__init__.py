@@ -29,7 +29,6 @@ from repro.core.variants import FilterSpec
 from repro.api import registry
 from repro.api.filter import BackendOptions, Filter, as_keys
 from repro.api import backends as _backends
-from repro.api.backends import tuned_options
 from repro.api import dist_backends as _dist_backends
 
 _backends.register_all()
@@ -50,8 +49,7 @@ registry.register_alias("pallas", _legacy_pallas)
 def make_filter(variant: str = "sbf", m_bits: int = 1 << 20, k: int = 8,
                 block_bits: int = 256, z: int = 1, backend: str = "auto",
                 layout=None, tile: Optional[int] = None,
-                probe: str = "auto", depth: Optional[int] = None,
-                coop: str = "auto", mix: str = "auto", mesh=None,
+                coop: str = "auto", mesh=None,
                 axis: str = "data", capacity: Optional[int] = None,
                 generations: Optional[int] = None,
                 slot_bits: int = 8, slots_per_bucket: int = 4,
@@ -67,16 +65,15 @@ def make_filter(variant: str = "sbf", m_bits: int = 1 << 20, k: int = 8,
     geometry, ``impl`` pins its jnp vs Pallas path);
     ``variant="quotient"`` selects the counting quotient engine
     (``remove`` + lossless ``merge``/``resize``; ``r_bits`` sets the
-    stored remainder width). Kernel knobs (``layout``, ``tile``,
-    ``probe``, ``depth``, ``coop``, ``mix``) default to the autotuner's
-    model-driven plan (``core.tuning.tune_plan``); pass explicit values to
-    pin them (``coop="subtile"`` forces lane-group cooperative probing,
-    ``mix="cheap"`` the fused double-hash — both bit-exact)."""
+    stored remainder width). Kernel knobs: ``layout`` (Θ) and ``tile``
+    override the defaults of the Bloom and counting kernels, whose
+    schedule follows from the filter's bytes; ``coop`` picks the cuckoo
+    and quotient contains schedule (``"subtile"`` the bit-exact early
+    exit, ``"auto"`` the perfmodel's choice)."""
     spec = FilterSpec(variant=variant, m_bits=m_bits, k=k,
                       block_bits=block_bits, z=z, slot_bits=slot_bits,
                       slots_per_bucket=slots_per_bucket, r_bits=r_bits)
-    options = BackendOptions(layout=layout, tile=tile, probe=probe,
-                             depth=depth, coop=coop, mix=mix, mesh=mesh,
+    options = BackendOptions(layout=layout, tile=tile, coop=coop, mesh=mesh,
                              axis=axis, capacity=capacity,
                              generations=generations, impl=impl)
     eng = registry.select(spec, backend, options.ctx())
@@ -87,9 +84,8 @@ def make_filter(variant: str = "sbf", m_bits: int = 1 << 20, k: int = 8,
 def make_filter_bank(bank, variant: str = "sbf", m_bits: int = 1 << 14,
                      k: int = 8, block_bits: int = 256, z: int = 1,
                      backend: str = "auto", layout=None,
-                     tile: Optional[int] = None, probe: str = "auto",
-                     depth: Optional[int] = None, coop: str = "auto",
-                     mix: str = "auto", mesh=None,
+                     tile: Optional[int] = None, coop: str = "auto",
+                     mesh=None,
                      axis: str = "data", capacity: Optional[int] = None,
                      generations: Optional[int] = None,
                      slot_bits: int = 8, slots_per_bucket: int = 4,
@@ -115,8 +111,7 @@ def make_filter_bank(bank, variant: str = "sbf", m_bits: int = 1 << 14,
     spec = FilterSpec(variant=variant, m_bits=m_bits, k=k,
                       block_bits=block_bits, z=z, slot_bits=slot_bits,
                       slots_per_bucket=slots_per_bucket, r_bits=r_bits)
-    options = BackendOptions(layout=layout, tile=tile, probe=probe,
-                             depth=depth, coop=coop, mix=mix, mesh=mesh,
+    options = BackendOptions(layout=layout, tile=tile, coop=coop, mesh=mesh,
                              axis=axis, capacity=capacity,
                              generations=generations, impl=impl)
     total = 1
@@ -259,4 +254,4 @@ def get_backend(name: str) -> registry.Backend:
 __all__ = ["Filter", "FilterSpec", "BackendOptions", "as_keys", "registry",
            "make_filter", "make_filter_bank", "route", "filter_for_n_items",
            "filter_for_workload", "union", "backends", "describe_backends",
-           "get_backend", "tuned_options"]
+           "get_backend"]

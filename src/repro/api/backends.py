@@ -115,8 +115,7 @@ class _PallasBackend(Backend):
         return V.init(spec)
 
     def _kw(self, options):
-        kw = {"regime": self.regime, "probe": options.probe,
-              "coop": options.coop, "mix": options.mix}
+        kw = {"regime": self.regime}
         if options.layout is not None:
             kw["layout"] = options.layout
         if options.tile is not None:
@@ -129,10 +128,7 @@ class _PallasBackend(Backend):
 
     def contains(self, spec, words, keys, options):
         from repro.kernels import ops
-        # depth only applies to the HBM streaming pipeline; the kernels
-        # resolve None through core.tuning.tune_plan.
-        return ops.bloom_contains(spec, words, keys, depth=options.depth,
-                                  **self._kw(options))
+        return ops.bloom_contains(spec, words, keys, **self._kw(options))
 
 
 class PallasVmemBackend(_PallasBackend):
@@ -146,7 +142,7 @@ class PallasVmemBackend(_PallasBackend):
     supports_bank = True
 
     def _bank_kw(self, options):
-        kw = {"probe": options.probe, "mix": options.mix}
+        kw = {}
         if options.layout is not None:
             kw["layout"] = options.layout
         if options.tile is not None:
@@ -194,8 +190,8 @@ class PallasVmemBackend(_PallasBackend):
 
 
 class PallasHbmBackend(_PallasBackend):
-    """Pallas TPU kernels with the filter left in HBM, blocks streamed
-    through a double-buffered DMA scratch — the DRAM-resident regime."""
+    """Pallas TPU kernels with the filter left in HBM, rows streamed
+    through a ring of DMA slots — the DRAM-resident regime."""
 
     name = "pallas-hbm"
     regime = "hbm"
@@ -249,8 +245,7 @@ class CountingBackend(Backend):
         return not ops._interpret()
 
     def _kw(self, options):
-        kw = {"layout": options.layout, "probe": options.probe,
-              "coop": options.coop, "mix": options.mix}
+        kw = {"layout": options.layout}
         if options.tile is not None:
             kw["tile"] = options.tile
         return kw
@@ -271,7 +266,6 @@ class CountingBackend(Backend):
         if self._tpu():
             from repro.kernels import ops
             return ops.counting_contains(spec, words, keys,
-                                         depth=options.depth,
                                          **self._kw(options))
         return V.counting_contains(spec, words, keys)
 
@@ -300,8 +294,7 @@ class CountingBackend(Backend):
     def _bank_update(self, spec, words, keys, member, valid, op, options):
         if self._tpu():
             from repro.kernels import ops
-            kw = {"probe": options.probe, "layout": options.layout,
-                  "mix": options.mix}
+            kw = {"layout": options.layout}
             if options.tile is not None:
                 kw["tile"] = options.tile
             return ops.counting_bank_update(spec, words, keys, member, op,
@@ -628,26 +621,6 @@ class QuotientBackend(CuckooBackend):
                     f"{cap}")
         out = jax.vmap(lambda w: Q.quotient_resize(spec, w, new_spec))(flat)
         return new_spec, out.reshape(words.shape[:-1] + (new_spec.n_words,))
-
-
-def tuned_options(spec: FilterSpec, op: str = "contains",
-                  regime: str = "auto", tile: int = None):
-    """Pin a ``BackendOptions`` to the autotuner's plan for (spec, op).
-
-    ``make_filter(probe="auto")`` already resolves lazily per call; this
-    helper materializes the tuned (layout, probe, depth) eagerly — useful
-    when the caller wants the plan recorded in the pytree aux data (one
-    cached-jit executable per pinned plan) or inspected/logged.
-    """
-    from repro.core import tuning
-    from repro.kernels import ops as kops
-    from repro.kernels.sbf import DEFAULT_TILE
-    from repro.api.filter import BackendOptions
-    tile = tile or DEFAULT_TILE
-    plan = tuning.tune_plan(spec, op, regime=kops._regime(spec, regime),
-                            tile=tile)
-    return BackendOptions(layout=plan.layout, tile=tile, probe=plan.probe,
-                          depth=plan.depth, coop=plan.coop, mix=plan.mix)
 
 
 def register_all():

@@ -46,15 +46,12 @@ from repro.api import registry
 class BackendOptions:
     """Static (hashable) engine parameters carried in the pytree aux data.
 
-    Unused fields are ignored by engines that don't need them: ``layout`` /
-    ``tile`` / ``probe`` / ``depth`` steer the Pallas kernels,
-    ``mesh``/``axis``/``capacity`` the distributed engines.
-
-    ``probe="auto"``, ``coop="auto"``, ``mix="auto"`` and ``depth=None``
-    resolve through ``core.tuning.tune_plan`` at trace time — the tuned
-    plan (probe strategy, cooperation mode, hash mix, DMA pipeline depth,
-    layout) flows from the disk-persisted tuning cache into every kernel
-    launched through the API.
+    Unused fields are ignored by engines that don't need them: ``layout``
+    / ``tile`` steer the Pallas kernels, ``mesh``/``axis``/``capacity`` the
+    distributed engines. The Bloom and counting kernels have one schedule
+    per regime, chosen from the filter's bytes (``repro.kernels.ops``), so
+    there is nothing else to pick; ``coop`` is read by the cuckoo and
+    quotient engines alone.
 
     Note the windowed ring *head* is NOT here: it is traced per-filter
     state (``Filter.state``), so ``advance()`` never changes the pytree
@@ -63,10 +60,8 @@ class BackendOptions:
 
     layout: Optional[object] = None    # kernels.sbf.Layout
     tile: Optional[int] = None         # Pallas key-tile override
-    probe: str = "auto"                # vmem phase 2: "loop"|"gather"|"auto"
-    depth: Optional[int] = None        # HBM contains DMA pipeline depth
-    coop: str = "auto"                 # "none"|"subtile"|"auto" lane groups
-    mix: str = "auto"                  # "full"|"cheap"|"auto" fused hash
+    coop: str = "auto"                 # cuckoo/quotient contains schedule:
+                                       # "none"|"subtile"|"auto"
     mesh: Optional[object] = None      # jax.sharding.Mesh
     axis: str = "data"
     capacity: Optional[int] = None     # sharded routing capacity per (src,dst)

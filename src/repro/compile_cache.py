@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache for this repository's entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``launch/*``,
+Entry points (``chip_smoke.py``, ``bench/run.py``, ``launch/*``,
 ``examples/*``) call :func:`enable_compile_cache` once at start; nothing
 calls it at import. Compiled kernels and programs are then written to and
 read back from one fixed directory, so a second run on the same machine

@@ -128,9 +128,10 @@ def xxh32_u64x2_pair(keys: jnp.ndarray):
     only enters xxHash32 through the accumulator initial value, so the
     per-lane multiplies (the expensive u32 ops on a 32-bit VPU) are shared:
     2 of the 8 multiplies drop out relative to two independent evaluations.
-    This is the ``mix="cheap"`` engine option (paper §4.2's fused
-    multi-hash): identical uint32 arithmetic, merely restructured, which is
-    what keeps every kernel built on it bit-exact with ``mix="full"``.
+    This is the row engine's phase-1 hash (``kernels.sbf.fingerprints``;
+    paper §4.2's fused multi-hash): identical uint32 arithmetic, merely
+    restructured, which is what keeps every kernel built on it bit-exact
+    with the reference's two independent evaluations.
     """
     keys = _u32(keys)
     hi = keys[..., 0]

@@ -228,15 +228,14 @@ class FilterSpec:
             # the q/r split and metadata layout ARE the spec: a quotient
             # table at the same m as an sbf or cuckoo spec (or the same
             # quotient table pre/post resize, same p different split) must
-            # never print — or cache-key (core.tuning._plan_key) —
-            # identically
+            # never print identically
             return (f"quotient(m=2^{_log2i(self.m_bits)}b, "
                     f"q{self.q_bits}+r{self.r_bits}, "
                     f"u{self.slot_bits}[occ|cont|shift])")
         if self.variant == "cuckoo":
             # slot geometry IS the spec for fingerprint filters: two cuckoo
             # specs with equal m but different slot widths must never print
-            # (or cache-key, see core.tuning._plan_key) identically
+            # identically
             return (f"cuckoo(m=2^{_log2i(self.m_bits)}b, "
                     f"{self.slots_per_bucket}xu{self.slot_bits})")
         return (f"{self.variant}(m=2^{_log2i(self.m_bits)}b, B={self.block_bits}, "
@@ -422,31 +421,6 @@ def add_scatter(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray) -> jnp.n
     return acc
 
 
-def segment_totals(sorted_ids: jnp.ndarray, vals: jnp.ndarray,
-                   combine) -> jnp.ndarray:
-    """Per-row full-segment reduction of ``vals`` grouped by ``sorted_ids``.
-
-    ``sorted_ids``: (n,) nondecreasing segment ids; ``vals``: (n, w) rows;
-    ``combine``: associative elementwise op (e.g. ``jnp.bitwise_or``,
-    :func:`nib_sat_add_words`). Returns (n, w) where every row holds the
-    reduction of its *whole* segment (broadcast back from the segment end),
-    via one segmented associative scan — no data-dependent loop, so it runs
-    identically in the jnp reference and inside Pallas kernel bodies.
-    """
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]])
-
-    def seg_combine(a, b):
-        m1, f1 = a
-        m2, f2 = b
-        return jnp.where(f2[:, None], m2, combine(m1, m2)), f1 | f2
-
-    scanned, _ = jax.lax.associative_scan(seg_combine, (vals, seg_start),
-                                          axis=0)
-    end_idx = jnp.searchsorted(sorted_ids, sorted_ids, side="right") - 1
-    return scanned[end_idx]
-
-
 def contains_rows(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray
                   ) -> jnp.ndarray:
     """Row-gather membership test (§Perf iteration B1).
@@ -595,13 +569,9 @@ def decay_word(w: jnp.ndarray) -> jnp.ndarray:
     return w - nib_nonzero(w)
 
 
-# Multi-count nibble arithmetic (whole-tile gather/scatter probe engine).
-# The per-key kernels apply 0/1 increments one key at a time; the gather
-# engine instead segment-reduces all same-block increments first and applies
-# the TOTAL in one RMW. Saturation makes that exact: counts clip at 15
-# during the reduction, and min(old + c, 15) / max(old - c, 0) for c >= 15
-# equal the c = 15 results, so the batched formulas below reproduce the
-# sequential per-key semantics bit-for-bit.
+# Multi-count nibble arithmetic: the counter-true union of two counting
+# filters (``CountingBackend.merge``) adds them nibble by nibble, clipped
+# at 15.
 _NIB_EVEN = np.uint32(0x0F0F0F0F)     # even-nibble byte lanes
 _BYTE_BIT4 = np.uint32(0x10101010)    # bit 4 of every byte (carry/borrow flag)
 
@@ -614,9 +584,7 @@ def _halves(w: jnp.ndarray):
 
 def nib_sat_add_words(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Nibble-wise saturating add of two packed counter words: min(a+b, 15).
-
-    Associative and commutative, so it is a valid segmented-scan combiner
-    (the counting analogue of the bit filters' segment OR)."""
+    Associative and commutative, so merges compose in any order."""
     def half(x, y):
         s = x + y                               # per-byte sums <= 30
         ov = s & _BYTE_BIT4                     # set iff the byte is >= 16
@@ -624,21 +592,6 @@ def nib_sat_add_words(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     ae, ao = _halves(a)
     be, bo = _halves(b)
     return half(ae, be) | (half(ao, bo) << jnp.uint32(4))
-
-
-def nib_guard_sub_words(w: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    """Nibble-wise guarded multi-decrement: where(w == 15, 15, max(w - c, 0)).
-
-    The batched form of ``c`` applications of :func:`guard_dec_word` —
-    sticky saturation and the 0 floor are preserved per nibble."""
-    def half(x, y):
-        d = (x | _BYTE_BIT4) - y                # bias: per-byte in [1, 31]
-        ok = d & _BYTE_BIT4                     # set iff x >= y (no borrow)
-        return d & (ok - (ok >> jnp.uint32(4))) & _NIB_EVEN
-    we, wo = _halves(w)
-    ce, co = _halves(c)
-    sub = half(we, ce) | (half(wo, co) << jnp.uint32(4))
-    return sub | (nib_saturated(w) * jnp.uint32(COUNTER_MAX))   # 15 sticks
 
 
 def expand_mask_words(masks: jnp.ndarray) -> jnp.ndarray:

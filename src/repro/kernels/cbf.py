@@ -2,9 +2,8 @@
 
 The CBF touches k scattered single words per key (no block locality), which
 is exactly why the paper moves to blocked designs; we implement it anyway as
-the faithful baseline for the Fig. 9 optimization-breakdown benchmark.
-VMEM-resident only: a DRAM CBF on TPU would need k independent DMAs per key,
-which the roofline in benchmarks/gups quantifies instead of executing.
+the faithful baseline. VMEM-resident and interpret-only: a DRAM CBF on TPU
+would need k independent DMAs per key.
 """
 from __future__ import annotations
 

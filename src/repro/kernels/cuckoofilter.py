@@ -35,7 +35,11 @@ from jax.experimental import pallas as pl
 
 from repro.core import fingerprint as F
 from repro.core.variants import FilterSpec
-from repro.kernels.sbf import COOPS, DEFAULT_TILE
+from repro.kernels.sbf import DEFAULT_TILE
+
+# The contains schedules: "none" the plain bucket probe, "subtile" its
+# bit-exact tile-wide early exit.
+COOPS = ("none", "subtile")
 
 
 def _contains_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,

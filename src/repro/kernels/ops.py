@@ -1,25 +1,17 @@
-"""jit'd dispatch wrappers around the Pallas Bloom kernels.
+"""jit'd dispatch wrappers around the Pallas filter kernels.
 
-``bloom_contains`` / ``bloom_add`` pick the right kernel for the spec:
+``bloom_contains`` / ``bloom_add`` pick the kernel from what the call
+shows, never from a tuner or a file:
 
-* variant: blocked variants -> ``kernels.sbf`` (layout-parameterized);
-  classical -> ``kernels.cbf``;
-* regime: filter words <= VMEM budget -> ``*_vmem`` (cache-resident
-  analogue), else ``*_hbm`` (DMA streaming) — mirroring the paper's §5.3/§5.2
-  split;
-* probe strategy (vmem regime): ``probe="loop"`` is the per-key row walk
-  (the only VMEM schedule that compiles for TPU, ``sbf.lowers``),
-  ``probe="gather"`` the whole-tile vectorized engine (one gather + one
-  fused compare / conflict-free segment-reduced scatter); ``"auto"``
-  resolves through ``core.tuning.tune_plan``. The HBM regime instead
-  exposes the DMA pipeline ``depth``;
-* cooperation axes: ``coop="subtile"`` switches to the lane-group
-  cooperative kernels (column-major early-exit contains, word-granular
-  flat-lane adds, sorted unique-row DMA sharing in HBM), ``mix="cheap"``
-  to the fused double-hash that shares the seed-independent lane
-  products — both bit-exact vs the baselines; ``"auto"`` resolves through
-  the model-driven tuner (bloom/counting) or the lru-cached perfmodel
-  helper (cuckoo/quotient);
+* variant: blocked variants -> ``kernels.sbf``; classical ->
+  ``kernels.cbf``;
+* regime: filter words <= ``VMEM_FILTER_BYTES`` -> ``*_vmem`` (the whole
+  filter pinned in VMEM, keys walked Θ at a time; the cache-resident
+  analogue), else ``*_hbm`` (rows streamed by DMA: the probe through a
+  fixed ring of ``sbf.HBM_PROBE_DEPTH`` slots, the update over row-sorted
+  tiles) — the paper's §5.3/§5.2 split. Each regime has one schedule, and
+  it is the one the chip compiles, so the CPU's interpret-mode tests run
+  the chip's kernels. Banks follow the same rule on the bank's bytes;
 * ``bloom_add_partitioned`` offers the partitioned ownership path — our
   beyond-paper TPU-native optimization. The partition step is
   **device-resident by default** (``core.partition.partition_jit``):
@@ -29,7 +21,7 @@
   ``partition="host"`` fallback;
 * the ``*_jit`` entry points are a **cached-jit dispatch layer**: one
   compiled executable per static configuration (spec/layout/regime/tile/
-  probe/batch shape), with ``donate_argnums`` on the filter buffer so
+  batch shape), with ``donate_argnums`` on the filter buffer so
   streaming bulk adds update the filter in place — no O(m) copy and no
   re-trace per call. Donation invalidates the caller's input array
   (`x.is_deleted()`); pass ``donate=False`` to keep it;
@@ -43,8 +35,9 @@ jnp semantics) — bit-exact, which is what the test sweeps rely on.
 Everywhere else they are compiled. Families whose kernels the chip's
 compiler refuses (cuckoo, quotient, ring, classical) run XLA on TPU
 through their engines; calling their kernels here on a TPU raises
-instead (:func:`_interpret_only`). Dispatches that choose between a
-kernel and the XLA reference by size record which one ran
+instead (:func:`_interpret_only`); their ``coop="auto"`` resolves through
+the perfmodel (:func:`_resolve_coop_fp`). Dispatches that choose between
+a kernel and the XLA reference by size record which one ran
 (:func:`dispatched`).
 """
 from __future__ import annotations
@@ -68,8 +61,7 @@ from repro.kernels import cuckoofilter as ckoo_k
 from repro.kernels import quotientfilter as qf_k
 from repro.kernels import ring as ring_k
 from repro.kernels import sbf as sbf_k
-from repro.kernels.sbf import (DEFAULT_DMA_DEPTH, DEFAULT_TILE, DMA_DEPTHS,
-                               Layout, PROBES, VMEM_FILTER_BYTES,
+from repro.kernels.sbf import (DEFAULT_TILE, Layout, VMEM_FILTER_BYTES,
                                default_layout)
 
 
@@ -121,57 +113,6 @@ def _clamp_tile(n: int, tile: int) -> int:
     return min(tile, max(8, 1 << int(np.ceil(np.log2(n)))))
 
 
-def _resolve_probe(spec: FilterSpec, op: str, probe: str, regime: str,
-                   tile: int, bank: int = 1) -> str:
-    """``"auto"`` consults the model-driven tuner (lru + disk cached; all
-    arguments static, so this also runs at trace time under jit)."""
-    if probe != "auto":
-        assert probe in PROBES, probe
-        return probe
-    from repro.core import tuning
-    return tuning.tune_plan(spec, op, regime=regime, tile=tile,
-                            bank=bank).probe
-
-
-def _resolve_pcm(spec: FilterSpec, op: str, regime: str, tile: int,
-                 probe: str = "auto", coop: str = "auto",
-                 mix: str = "auto", bank: int = 1):
-    """Resolve the (probe, coop, mix) triple: pinned values pass through,
-    ``"auto"`` axes come from ONE ``tune_plan`` query keyed to the pinned
-    axes (so a pinned coop never reuses a plan tuned under another)."""
-    from repro.kernels.sbf import COOPS, MIXES
-    if probe != "auto" and coop != "auto" and mix != "auto":
-        assert probe in PROBES and coop in COOPS and mix in MIXES
-        return probe, coop, mix
-    from repro.core import tuning
-    plan = tuning.tune_plan(spec, op, regime=regime, tile=tile, bank=bank,
-                            coop=coop, mix=mix)
-    return (probe if probe != "auto" else plan.probe,
-            coop if coop != "auto" else plan.coop,
-            mix if mix != "auto" else plan.mix)
-
-
-def _resolve_mix(spec: FilterSpec, op: str, mix: str, regime: str,
-                 tile: int, bank: int = 1) -> str:
-    """Mix-only resolution for the bank paths (no cooperative bank
-    kernels — the bank already amortizes the working set)."""
-    from repro.kernels.sbf import MIXES
-    if mix != "auto":
-        assert mix in MIXES, mix
-        return mix
-    from repro.core import tuning
-    return tuning.tune_plan(spec, op, regime=regime, tile=tile,
-                            bank=bank).mix
-
-
-def _resolve_depth(spec: FilterSpec, op: str, depth: Optional[int],
-                   tile: int) -> int:
-    if depth is not None:
-        return depth
-    from repro.core import tuning
-    return tuning.tune_plan(spec, op, regime="hbm", tile=tile).depth
-
-
 def _pad_keys(keys: jnp.ndarray, tile: int) -> jnp.ndarray:
     """Pad to a tile multiple by repeating the last key — valid ONLY for the
     OR-idempotent bit-filter ops: a repeated add ORs the same mask twice
@@ -204,9 +145,7 @@ def _pad_keys_valid(keys: jnp.ndarray, tile: int,
 
 def bloom_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                    layout: Optional[Layout] = None, regime: str = "auto",
-                   tile: int = DEFAULT_TILE, probe: str = "auto",
-                   depth: Optional[int] = None, coop: str = "auto",
-                   mix: str = "auto") -> jnp.ndarray:
+                   tile: int = DEFAULT_TILE) -> jnp.ndarray:
     assert not spec.is_counting, "use counting_contains for countingbf"
     n = keys.shape[0]
     if n == 0:
@@ -219,26 +158,19 @@ def bloom_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                                   interpret=_interpret_only("cbf"))
     elif _regime(spec, regime) == "vmem":
         _ran("bloom_contains", "pallas-vmem")
-        p, c, m = _resolve_pcm(spec, "contains", "vmem", tile, probe, coop,
-                               mix)
         out = sbf_k.contains_vmem(
             spec, filt, padded, layout or default_layout(spec, "contains"),
-            tile=tile, interpret=interp, probe=p, coop=c, mix=m)
+            tile=tile, interpret=interp)
     else:
         _ran("bloom_contains", "pallas-hbm")
-        _, c, m = _resolve_pcm(spec, "contains", "hbm", tile, "gather",
-                               coop, mix)
-        out = sbf_k.contains_hbm(
-            spec, filt, padded, tile=tile, interpret=interp,
-            depth=_resolve_depth(spec, "contains", depth, tile),
-            coop=c, mix=m)
+        out = sbf_k.contains_hbm(spec, filt, padded, tile=tile,
+                                 interpret=interp)
     return out[:n]
 
 
 def bloom_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
               layout: Optional[Layout] = None, regime: str = "auto",
-              tile: int = DEFAULT_TILE, probe: str = "auto",
-              coop: str = "auto", mix: str = "auto") -> jnp.ndarray:
+              tile: int = DEFAULT_TILE) -> jnp.ndarray:
     assert not spec.is_counting, "use counting_add/counting_remove"
     n = keys.shape[0]
     if n == 0:
@@ -251,14 +183,11 @@ def bloom_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                               interpret=_interpret_only("cbf"))
     if _regime(spec, regime) == "vmem":
         _ran("bloom_add", "pallas-vmem")
-        p, c, m = _resolve_pcm(spec, "add", "vmem", tile, probe, coop, mix)
         return sbf_k.add_vmem(
             spec, filt, padded, layout or default_layout(spec, "add"),
-            tile=tile, interpret=interp, probe=p, coop=c, mix=m)
+            tile=tile, interpret=interp)
     _ran("bloom_add", "pallas-hbm")
-    _, c, m = _resolve_pcm(spec, "add", "hbm", tile, "gather", coop, mix)
-    return sbf_k.add_hbm(spec, filt, padded, tile=tile, interpret=interp,
-                         coop=c, mix=m)
+    return sbf_k.add_hbm(spec, filt, padded, tile=tile, interpret=interp)
 
 
 # ---------------------------------------------------------------------------
@@ -272,34 +201,27 @@ def bloom_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 def segment_add(seg_spec: FilterSpec, seg_words: jnp.ndarray,
                 keys: jnp.ndarray, valid: jnp.ndarray,
-                tile: int = DEFAULT_TILE, mix: str = "auto") -> jnp.ndarray:
+                tile: int = DEFAULT_TILE) -> jnp.ndarray:
     n = keys.shape[0]
     tile = _clamp_tile(n, tile)
     keys, valid = _pad_keys_valid(keys, tile, valid)
     _ran("sharded_add", "pallas-hbm")
-    _, _, m = _resolve_pcm(seg_spec, "add", "hbm", tile, "gather", "auto",
-                           mix)
-    starts, masks = sbf_k.fingerprints(seg_spec, keys, m)
+    starts, masks = sbf_k.fingerprints(seg_spec, keys)
     return sbf_k.update_rows_hbm(seg_words, starts, masks, tile=tile,
                                  interpret=_interpret(), valid=valid > 0)
 
 
 def segment_contains(seg_spec: FilterSpec, seg_words: jnp.ndarray,
                      keys: jnp.ndarray, valid: jnp.ndarray,
-                     tile: int = DEFAULT_TILE, depth: Optional[int] = None,
-                     coop: str = "auto", mix: str = "auto") -> jnp.ndarray:
+                     tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """(n,) bool; a padded slot's answer is meaningless."""
     n = keys.shape[0]
     tile = _clamp_tile(n, tile)
     keys, valid = _pad_keys_valid(keys, tile, valid)
     _ran("sharded_contains", "pallas-hbm")
-    _, c, m = _resolve_pcm(seg_spec, "contains", "hbm", tile, "gather", coop,
-                           mix)
-    starts, masks = sbf_k.fingerprints(seg_spec, keys, m)
-    return sbf_k.probe_rows_hbm(
-        seg_words, starts, masks, tile=tile, coop=c,
-        depth=_resolve_depth(seg_spec, "contains", depth, tile),
-        interpret=_interpret(), valid=valid > 0)[:n]
+    starts, masks = sbf_k.fingerprints(seg_spec, keys)
+    return sbf_k.probe_rows_hbm(seg_words, starts, masks, tile=tile,
+                                interpret=_interpret(), valid=valid > 0)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -345,8 +267,7 @@ def _pad_flat_valid(keys: jnp.ndarray, member: jnp.ndarray,
 def bloom_bank_contains(spec: FilterSpec, bank: jnp.ndarray,
                         keys: jnp.ndarray, member: jnp.ndarray,
                         layout: Optional[Layout] = None,
-                        tile: int = DEFAULT_TILE, probe: str = "auto",
-                        mix: str = "auto") -> jnp.ndarray:
+                        tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """(N,) bool membership of flat routed keys against a (B, n_words) bank."""
     assert not spec.is_counting
     n = keys.shape[0]
@@ -362,16 +283,14 @@ def bloom_bank_contains(spec: FilterSpec, bank: jnp.ndarray,
     pk, pm = _pad_flat(keys, jnp.asarray(member, jnp.int32), tile)
     out = sbf_k.bank_contains_vmem(
         spec, bank, pk, pm, layout or default_layout(spec, "contains"),
-        tile=tile, interpret=_interpret(),
-        probe=_resolve_probe(spec, "contains", probe, "vmem", tile, bank=B),
-        mix=_resolve_mix(spec, "contains", mix, "vmem", tile, bank=B))
+        tile=tile, interpret=_interpret())
     return out[:n]
 
 
 def bloom_bank_add(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
                    member: jnp.ndarray, valid: Optional[jnp.ndarray] = None,
-                   layout: Optional[Layout] = None, tile: int = DEFAULT_TILE,
-                   probe: str = "auto", mix: str = "auto") -> jnp.ndarray:
+                   layout: Optional[Layout] = None,
+                   tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Valid-masked bulk OR of flat routed keys into a (B, n_words) bank."""
     assert not spec.is_counting
     n = keys.shape[0]
@@ -387,9 +306,7 @@ def bloom_bank_add(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
     pk, pm, pv = _pad_flat_valid(keys, member, valid, tile)
     return sbf_k.bank_add_vmem(
         spec, bank, pk, pm, pv, layout or default_layout(spec, "add"),
-        tile=tile, interpret=_interpret(),
-        probe=_resolve_probe(spec, "add", probe, "vmem", tile, bank=B),
-        mix=_resolve_mix(spec, "add", mix, "vmem", tile, bank=B))
+        tile=tile, interpret=_interpret())
 
 
 def counting_bank_update(spec: FilterSpec, bank: jnp.ndarray,
@@ -397,8 +314,7 @@ def counting_bank_update(spec: FilterSpec, bank: jnp.ndarray,
                          op: str = "add",
                          valid: Optional[jnp.ndarray] = None,
                          layout: Optional[Layout] = None,
-                         tile: int = DEFAULT_TILE, probe: str = "auto",
-                         mix: str = "auto") -> jnp.ndarray:
+                         tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Flat routed counter increment/decrement of a (B, 4*n_words) bank."""
     assert spec.is_counting
     n = keys.shape[0]
@@ -414,9 +330,7 @@ def counting_bank_update(spec: FilterSpec, bank: jnp.ndarray,
     pk, pm, pv = _pad_flat_valid(keys, member, valid, tile)
     return cnt_k.bank_update_vmem(
         spec, bank, pk, pm, pv, op, layout=layout, tile=tile,
-        interpret=_interpret(),
-        probe=_resolve_probe(spec, "add", probe, "vmem", tile, bank=B),
-        mix=_resolve_mix(spec, "add", mix, "vmem", tile, bank=B))
+        interpret=_interpret())
 
 
 def counting_bank_contains(spec: FilterSpec, bank: jnp.ndarray,
@@ -557,8 +471,7 @@ def _cached_jit(key, make):
 
 def bloom_add_jit(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                   *, layout: Optional[Layout] = None, regime: str = "auto",
-                  tile: int = DEFAULT_TILE, probe: str = "auto",
-                  coop: str = "auto", mix: str = "auto",
+                  tile: int = DEFAULT_TILE,
                   donate: bool = True) -> jnp.ndarray:
     """Cached-jit bulk add with the filter buffer DONATED to the update:
     repeated streaming adds reuse one compiled executable per static
@@ -567,13 +480,13 @@ def bloom_add_jit(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     (``filt.is_deleted()`` afterwards); pass ``donate=False`` to keep it.
     """
     keys = jnp.asarray(keys)
-    key = ("bloom_add", spec, layout, regime, tile, probe, coop, mix,
-           keys.shape, str(keys.dtype), bool(donate))
+    key = ("bloom_add", spec, layout, regime, tile, keys.shape,
+           str(keys.dtype), bool(donate))
 
     def make():
         def run(f, k):
             return bloom_add(spec, f, k, layout=layout, regime=regime,
-                             tile=tile, probe=probe, coop=coop, mix=mix)
+                             tile=tile)
         return jax.jit(run, donate_argnums=(0,) if donate else ())
 
     return _cached_jit(key, make)(filt, keys)
@@ -581,19 +494,17 @@ def bloom_add_jit(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
 
 def bloom_contains_jit(spec: FilterSpec, filt: jnp.ndarray,
                        keys: jnp.ndarray, *, layout: Optional[Layout] = None,
-                       regime: str = "auto", tile: int = DEFAULT_TILE,
-                       probe: str = "auto", depth: Optional[int] = None,
-                       coop: str = "auto", mix: str = "auto") -> jnp.ndarray:
+                       regime: str = "auto",
+                       tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Cached-jit bulk membership (read-only — nothing to donate)."""
     keys = jnp.asarray(keys)
-    key = ("bloom_contains", spec, layout, regime, tile, probe, depth,
-           coop, mix, keys.shape, str(keys.dtype))
+    key = ("bloom_contains", spec, layout, regime, tile, keys.shape,
+           str(keys.dtype))
 
     def make():
         def run(f, k):
             return bloom_contains(spec, f, k, layout=layout, regime=regime,
-                                  tile=tile, probe=probe, depth=depth,
-                                  coop=coop, mix=mix)
+                                  tile=tile)
         return jax.jit(run)
 
     return _cached_jit(key, make)(filt, keys)
@@ -602,20 +513,18 @@ def bloom_contains_jit(spec: FilterSpec, filt: jnp.ndarray,
 def counting_update_jit(spec: FilterSpec, filt: jnp.ndarray,
                         keys: jnp.ndarray, op: str = "add", *,
                         layout: Optional[Layout] = None, regime: str = "auto",
-                        tile: int = DEFAULT_TILE, probe: str = "auto",
-                        coop: str = "auto", mix: str = "auto",
+                        tile: int = DEFAULT_TILE,
                         donate: bool = True) -> jnp.ndarray:
     """Cached-jit counting increment/decrement with a donated counter
     buffer — the counting analogue of :func:`bloom_add_jit`."""
     keys = jnp.asarray(keys)
-    key = ("counting_update", spec, op, layout, regime, tile, probe, coop,
-           mix, keys.shape, str(keys.dtype), bool(donate))
+    key = ("counting_update", spec, op, layout, regime, tile, keys.shape,
+           str(keys.dtype), bool(donate))
 
     def make():
         fn = counting_add if op == "add" else counting_remove
         def run(f, k):
-            return fn(spec, f, k, layout=layout, regime=regime, tile=tile,
-                      probe=probe, coop=coop, mix=mix)
+            return fn(spec, f, k, layout=layout, regime=regime, tile=tile)
         return jax.jit(run, donate_argnums=(0,) if donate else ())
 
     return _cached_jit(key, make)(filt, keys)
@@ -627,9 +536,7 @@ def counting_update_jit(spec: FilterSpec, filt: jnp.ndarray,
 
 def _counting_update(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                      op: str, layout: Optional[Layout], regime: str,
-                     tile: int, valid: Optional[jnp.ndarray],
-                     probe: str = "auto", coop: str = "auto",
-                     mix: str = "auto") -> jnp.ndarray:
+                     tile: int, valid: Optional[jnp.ndarray]) -> jnp.ndarray:
     assert spec.is_counting
     n = keys.shape[0]
     if n == 0:
@@ -639,43 +546,34 @@ def _counting_update(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     interp = _interpret()
     if _regime(spec, regime) == "vmem":
         _ran(f"counting_{op}", "pallas-vmem")
-        p, c, m = _resolve_pcm(spec, "add", "vmem", tile, probe, coop, mix)
-        return cnt_k.update_vmem(
-            spec, filt, padded, pvalid, op, layout=layout, tile=tile,
-            interpret=interp, probe=p, coop=c, mix=m)
+        return cnt_k.update_vmem(spec, filt, padded, pvalid, op,
+                                 layout=layout, tile=tile, interpret=interp)
     _ran(f"counting_{op}", "pallas-hbm")
-    _, c, m = _resolve_pcm(spec, "add", "hbm", tile, "gather", coop, mix)
     return cnt_k.update_hbm(spec, filt, padded, pvalid, op, tile=tile,
-                            interpret=interp, coop=c, mix=m)
+                            interpret=interp)
 
 
 def counting_add(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                  layout: Optional[Layout] = None, regime: str = "auto",
                  tile: int = DEFAULT_TILE,
-                 valid: Optional[jnp.ndarray] = None,
-                 probe: str = "auto", coop: str = "auto",
-                 mix: str = "auto") -> jnp.ndarray:
+                 valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Bulk saturating increment of each key's k counters."""
     return _counting_update(spec, filt, keys, "add", layout, regime, tile,
-                            valid, probe, coop, mix)
+                            valid)
 
 
 def counting_remove(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                     layout: Optional[Layout] = None, regime: str = "auto",
                     tile: int = DEFAULT_TILE,
-                    valid: Optional[jnp.ndarray] = None,
-                    probe: str = "auto", coop: str = "auto",
-                    mix: str = "auto") -> jnp.ndarray:
+                    valid: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Bulk guarded decrement (0 floors, saturated counters stick)."""
     return _counting_update(spec, filt, keys, "remove", layout, regime, tile,
-                            valid, probe, coop, mix)
+                            valid)
 
 
 def counting_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
                       layout: Optional[Layout] = None, regime: str = "auto",
-                      tile: int = DEFAULT_TILE, probe: str = "auto",
-                      depth: Optional[int] = None, coop: str = "auto",
-                      mix: str = "auto") -> jnp.ndarray:
+                      tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Bulk membership against the counter occupancy (read-only, so
     repeat-key padding is safe here — results are sliced off)."""
     assert spec.is_counting
@@ -687,19 +585,12 @@ def counting_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
     interp = _interpret()
     if _regime(spec, regime) == "vmem":
         _ran("counting_contains", "pallas-vmem")
-        p, c, m = _resolve_pcm(spec, "contains", "vmem", tile, probe, coop,
-                               mix)
-        out = cnt_k.contains_vmem(
-            spec, filt, padded, layout=layout, tile=tile, interpret=interp,
-            probe=p, coop=c, mix=m)
+        out = cnt_k.contains_vmem(spec, filt, padded, layout=layout,
+                                  tile=tile, interpret=interp)
     else:
         _ran("counting_contains", "pallas-hbm")
-        _, c, m = _resolve_pcm(spec, "contains", "hbm", tile, "gather",
-                               coop, mix)
-        out = cnt_k.contains_hbm(
-            spec, filt, padded, tile=tile, interpret=interp,
-            depth=_resolve_depth(spec, "contains", depth, tile),
-            coop=c, mix=m)
+        out = cnt_k.contains_hbm(spec, filt, padded, tile=tile,
+                                 interpret=interp)
     return out[:n]
 
 
@@ -765,14 +656,12 @@ def cuckoo_vmem_resident(spec: FilterSpec) -> bool:
 
 def _resolve_coop_fp(spec: FilterSpec, coop: str, tile: int) -> str:
     """``"auto"`` cooperation for the fingerprint/quotient engines: the
-    lru-cached perfmodel helper (these engines have no layout grid, so
-    they bypass ``tune_plan``; all-static, trace-time safe)."""
+    lru-cached perfmodel helper (all-static, trace-time safe)."""
     if coop != "auto":
-        from repro.kernels.sbf import COOPS
-        assert coop in COOPS, coop
+        assert coop in ckoo_k.COOPS, coop
         return coop
     from repro import perfmodel as PM
-    return PM.choose_coop(spec, "contains", "vmem", tile)[0]
+    return PM.choose_coop(spec, "contains", "vmem", tile)
 
 
 def cuckoo_contains(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,

@@ -37,10 +37,11 @@ This is the TPU-native realization of the paper's §4 design space:
   a row-sorted tile are distinct rows, and the tile waits for its writes
   before the next tile reads, which orders a row shared across the edge.
 
-The whole-tile gather probe and the VMEM cooperative kernels use an
-in-kernel gather or sort, which the chip's compiler refuses; they run only
-in interpret mode (:func:`lowers`). All kernels are validated bit-exactly
-against ``repro.core.variants`` in interpret mode.
+One schedule per regime: the VMEM kernels walk the keys Θ at a time, the
+HBM probe streams rows through a fixed ring of ``HBM_PROBE_DEPTH`` DMA
+slots, and the HBM update works a row-sorted tile's runs together. The
+CPU runs these same kernels in interpret mode, where they are validated
+bit-exactly against ``repro.core.variants``.
 """
 from __future__ import annotations
 
@@ -101,37 +102,10 @@ def default_layout(spec: FilterSpec, op: str) -> Layout:
 # Phase 1 — lockstep fingerprint generation (shared by all kernels)
 # ---------------------------------------------------------------------------
 
-def _hash_streams(keys: jnp.ndarray, mix: str):
-    """(pattern, block) hash pair under the chosen mixing schedule.
-
-    ``mix="full"`` evaluates the two seeded xxh32 streams independently;
-    ``mix="cheap"`` shares the seed-independent lane products between them
-    (one wide mix feeding all k indices) — bit-identical outputs either
-    way (see ``hashing.xxh32_u64x2_pair``)."""
-    assert mix in MIXES, mix
-    if mix == "cheap":
-        return H.xxh32_u64x2_pair(keys)
-    return (H.xxh32_u64x2(keys, H.SEED_PATTERN),
-            H.block_hash(keys))
-
-
-def _fingerprints(spec: FilterSpec, keys: jnp.ndarray, mix: str = "full"):
-    """In-kernel hash + pattern phase: (starts[int32], masks[uint32 (n,s)]).
-
-    batched=False: inside a pallas_call the salts must stay scalar literals
-    (kernel bodies may not capture array constants). Used by the
-    interpret-only kernels; the row-layout kernels get both from
-    :func:`fingerprints` in XLA."""
-    h1, h2 = _hash_streams(keys, mix)
-    blk = H.block_index(h2, spec.n_blocks)
-    masks = V.block_patterns(spec, h1, batched=False)
-    starts = (blk * jnp.uint32(spec.s)).astype(jnp.int32)
-    return starts, masks
-
-
-def fingerprints(spec: FilterSpec, keys: jnp.ndarray, mix: str = "full"):
-    """Phase 1 in XLA: (starts (n,) int32, masks (n, s) uint32)."""
-    h1, h2 = _hash_streams(keys, mix)
+def fingerprints(spec: FilterSpec, keys: jnp.ndarray):
+    """Phase 1 in XLA: (starts (n,) int32, masks (n, s) uint32). Both hash
+    streams come from one fused mix (``hashing.xxh32_u64x2_pair``)."""
+    h1, h2 = H.xxh32_u64x2_pair(keys)
     blk = H.block_index(h2, spec.n_blocks)
     starts = (blk * jnp.uint32(spec.s)).astype(jnp.int32)
     return starts, V.block_patterns(spec, h1)
@@ -145,37 +119,12 @@ def _mask_row(masks: jnp.ndarray, i, s: int) -> jnp.ndarray:
     return jax.lax.dynamic_slice(masks, (i, 0), (1, s))[0]
 
 
-PROBES = ("loop", "gather")
-# Cooperation axis (paper §4.3 / McCoy et al.): "none" keeps the per-key
-# probe schedules; "subtile" shares one key's probe row across a lane
-# sub-tile — column-major early-exit contains, word-granular flat-lane
-# segmented adds, sorted row sharing in HBM. Every coop path is bit-exact
-# with its "none" baseline.
-COOPS = ("none", "subtile")
-# Hash mixing schedule: "full" = two independent seeded xxh32 streams;
-# "cheap" = one fused wide mix feeding both streams (bit-identical).
-MIXES = ("full", "cheap")
-DMA_DEPTHS = (1, 2, 4, 8)
-DEFAULT_DMA_DEPTH = 2
 # Keys a trip of the HBM probe loop takes: one sublane tile of its
-# missing-bits scratch. Fixed, not tuned.
+# missing-bits scratch.
 HBM_PROBE_UNROLL = 8
-
-
-def lowers(regime: str, probe: str, coop: str) -> bool:
-    """Whether the chip's compiler accepts this schedule. It has no
-    in-kernel sort and no general gather, so in the VMEM regime only the
-    per-key loop without cooperation lowers. Every HBM schedule does:
-    their sort runs in XLA before the kernel."""
-    return regime == "hbm" or (probe == "loop" and coop == "none")
-
-
-def _check_lowers(regime: str, probe: str, coop: str, interpret: bool):
-    if not (interpret or lowers(regime, probe, coop)):
-        raise ValueError(
-            f"probe={probe!r} coop={coop!r} does not lower for the TPU in "
-            f"the {regime} regime (in-kernel gather/sort); use "
-            f"probe='loop', coop='none'")
+# Row DMAs the HBM probe keeps in its ring: ``HBM_PROBE_DEPTH - 1`` in
+# flight ahead of the row being tested.
+HBM_PROBE_DEPTH = 8
 
 
 # ---------------------------------------------------------------------------
@@ -401,169 +350,25 @@ def update_rows_vmem(words: jnp.ndarray, starts: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def contains_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-                  layout: Layout, *, interpret: bool, tile: int = DEFAULT_TILE,
-                  probe: str = "loop", coop: str = "none",
-                  mix: str = "full") -> jnp.ndarray:
+                  layout: Layout, *, interpret: bool,
+                  tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Bulk membership test, whole filter pinned in VMEM via BlockSpec."""
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert probe in PROBES, probe
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    _check_lowers("vmem", probe, coop, interpret)
-    # An explicit layout is ALWAYS validated, even though the gather engine
-    # ignores it — probe is a schedule choice and must never change which
-    # (layout, tile) combinations are accepted.
+    assert keys.shape[0] % tile == 0
     layout = layout.validate(spec, tile)
-    if coop == "none" and probe == "loop":
-        starts, masks = fingerprints(spec, keys, mix)
-        return probe_rows_vmem(filt, starts, masks, theta=layout.theta,
-                               tile=tile, interpret=interpret)
-    if coop == "subtile":      # cooperative schedule supersedes the probe
-        kern = functools.partial(_contains_vmem_coop_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    else:
-        kern = functools.partial(_contains_vmem_gather_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),          # key tile
-            pl.BlockSpec((spec.n_words,), lambda i: (0,)),      # whole filter
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        interpret=interpret,
-    )(keys, filt)
+    starts, masks = fingerprints(spec, keys)
+    return probe_rows_vmem(filt, starts, masks, theta=layout.theta,
+                           tile=tile, interpret=interpret)
 
 
 def add_vmem(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray,
-             layout: Layout, *, interpret: bool, tile: int = DEFAULT_TILE,
-             probe: str = "loop", coop: str = "none",
-             mix: str = "full") -> jnp.ndarray:
+             layout: Layout, *, interpret: bool,
+             tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Bulk insert, whole filter pinned in VMEM; sequential-grid RMW."""
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert probe in PROBES, probe
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    _check_lowers("vmem", probe, coop, interpret)
-    layout = layout.validate(spec, tile)     # validated even on gather
-    if coop == "none" and probe == "loop":
-        starts, masks = fingerprints(spec, keys, mix)
-        return update_rows_vmem(filt, starts, masks, theta=layout.theta,
-                                tile=tile, interpret=interpret)
-    if coop == "subtile":      # cooperative schedule supersedes the probe
-        kern = functools.partial(_add_vmem_coop_kernel, spec=spec, tile=tile,
-                                 mix=mix)
-    else:
-        kern = functools.partial(_add_vmem_gather_kernel, spec=spec,
-                                 tile=tile, mix=mix)
-    return pl.pallas_call(
-        kern,
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((spec.n_words,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((spec.n_words,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32),
-        interpret=interpret,
-    )(keys, filt)
-
-
-# ---------------------------------------------------------------------------
-# Whole-tile gather-probe kernels (probe="gather")
-# ---------------------------------------------------------------------------
-# Interpret-only (see ``lowers``): these kernels hash in-kernel and keep
-# phase 2 on the vector unit too. contains: build the full (tile, s)
-# word-index matrix, ONE gather, ONE fused compare — no per-key loop at
-# all. add: sort the tile by block, segment-OR the masks of same-block
-# keys, then one row gather + one conflict-free row scatter (duplicate
-# indices carry identical rows). The Θ layout is irrelevant here —
-# the whole tile IS the vector.
-
-def _contains_vmem_gather_kernel(keys_ref, filt_ref, out_ref, *,
-                                 spec: FilterSpec, tile: int, mix: str):
-    s = spec.s
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
-    idx = starts[:, None] + jax.lax.broadcasted_iota(jnp.int32, (tile, s), 1)
-    words = jnp.take(filt_ref[...], idx, axis=0)         # (tile, s) gather
-    out_ref[...] = jnp.all((words & masks) == masks, axis=-1)
-
-
-def _add_vmem_gather_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
-                            tile: int, mix: str):
-    s = spec.s
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
-    blk = jax.lax.div(starts, jnp.int32(s))
-    out_ref[...] = V.or_rows(spec, out_ref[...], blk, masks)
-
-
-# ---------------------------------------------------------------------------
-# Cooperative sub-tile kernels (coop="subtile"; VMEM forms interpret-only)
-# ---------------------------------------------------------------------------
-# The cooperation axis re-slices phase 2 at WORD granularity instead of KEY
-# granularity — the TPU analogue of a lane group sharing one key's k probes:
-#
-# * contains: column-major early-exit. The whole tile probes word column c
-#   together (ONE flat gather of tile words), folds the column test into a
-#   per-key `alive` mask, and the next column only runs while any key is
-#   still alive (`lax.cond` — the cooperative ballot). Bit-exact because
-#   the result is the same AND over the s per-column tests, and a dead key
-#   stays dead regardless of skipped columns.
-# * add: word-granular flat-lane scatter. Every (key, word) pair becomes
-#   one lane of a (tile*s,) flat stream, sorted by absolute word index and
-#   OR-collapsed with the segmented scan — one flat gather + one
-#   conflict-free flat scatter touches each unique WORD once (the "none"
-#   gather engine collapses at block granularity; this is the finer
-#   cooperative tiling of the same associative reduction).
-
-def _contains_vmem_coop_kernel(keys_ref, filt_ref, out_ref, *,
-                               spec: FilterSpec, tile: int, mix: str):
-    s = spec.s
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
-    filt = filt_ref[...]
-    alive = jnp.ones((tile,), jnp.bool_)
-    for c in range(s):                          # static unroll over columns
-        m = masks[:, c]
-
-        def probe_col(al, m=m, c=c):
-            w = jnp.take(filt, starts + c, axis=0)        # (tile,) flat gather
-            return al & ((w & m) == m)
-
-        alive = jax.lax.cond(jnp.any(alive), probe_col, lambda al: al, alive)
-    out_ref[...] = alive
-
-
-def _add_vmem_coop_kernel(keys_ref, filt_ref, out_ref, *, spec: FilterSpec,
-                          tile: int, mix: str):
-    s = spec.s
-
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    starts, masks = _fingerprints(spec, keys_ref[...], mix=mix)
-    idx = (starts[:, None]
-           + jax.lax.broadcasted_iota(jnp.int32, (tile, s), 1)
-           ).reshape(tile * s)
-    vals = masks.reshape(tile * s)
-    order = jnp.argsort(idx)
-    si = idx[order]
-    or_w = V.segment_totals(si, vals[order][:, None], jnp.bitwise_or)[:, 0]
-    f = out_ref[...]
-    words = jnp.take(f, si, axis=0)
-    # duplicate indices carry identical segment totals -> deterministic set
-    out_ref[...] = f.at[si].set(words | or_w)
-
-
+    assert keys.shape[0] % tile == 0
+    layout = layout.validate(spec, tile)
+    starts, masks = fingerprints(spec, keys)
+    return update_rows_vmem(filt, starts, masks, theta=layout.theta,
+                            tile=tile, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -574,8 +379,7 @@ def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
                          miss_ref, *, s: int, tile: int, depth: int, held):
     """Depth-``depth`` row-streaming pipeline: keep up to ``depth - 1``
     row DMAs in flight ahead of the one being tested — the TPU-explicit
-    version of the paper's load pipelining, with the pipeline depth a
-    tunable instead of hardcoded double-buffering (depth=2). Each loop trip
+    version of the paper's load pipelining. Each loop trip
     takes ``HBM_PROBE_UNROLL`` keys in one basic block: their masks are
     placed first, then each key waits for its row and stores its missing
     bits; the tile is tested after the loop (:func:`_test_keys`)."""
@@ -593,8 +397,6 @@ def _contains_hbm_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
     def row_of(i):
         slot = jax.lax.rem(i, depth)
 
-        # At depth=1 the offset is 0: the "prefetch" starts the current DMA
-        # (fully serial); at depth>=2 it keeps depth-1 copies in flight.
         @pl.when(i + depth - 1 < tile)
         def _prefetch():
             dma(i + depth - 1, jax.lax.rem(i + depth - 1, depth)).start()
@@ -613,32 +415,6 @@ def _run_bounds(st_ref, i, tile: int, lanes: int):
     prev = st_ref[0, 0, jnp.maximum(i - 1, 0)] // lanes
     nxt = st_ref[0, 0, jnp.minimum(i + 1, tile - 1)] // lanes
     return (i == 0) | (r != prev), (i == tile - 1) | (r != nxt)
-
-
-def _contains_hbm_coop_kernel(st_ref, m_ref, filt_hbm, out_ref, scratch, sem,
-                              miss_ref, *, s: int, tile: int, held):
-    """Cooperative HBM contains over a tile sorted by row start (in XLA):
-    the first key of each row run fetches the row, followers test against
-    the scratch row already resident — each unique row moves over the HBM
-    bus once per tile. The tile is tested after the loop
-    (:func:`_test_keys`); results come out in sorted order."""
-    lanes = filt_hbm.shape[-1]
-
-    def row_of(i):
-        r, _ = _locate(st_ref, i, lanes)
-        first, _ = _run_bounds(st_ref, i, tile, lanes)
-
-        @pl.when(first)
-        def _fetch():                      # one DMA per unique row
-            cp = pltpu.make_async_copy(filt_hbm.at[pl.ds(r, 1)], scratch,
-                                       sem.at[0])
-            cp.start()
-            cp.wait()
-
-        return scratch[...]
-
-    _test_keys(out_ref, miss_ref, _mask_at(st_ref, m_ref, s, lanes), row_of,
-               n_keys=tile, unroll=1, held=held)
 
 
 def _seed_output(filt_hbm, out_hbm, sem):
@@ -753,11 +529,10 @@ def _probe_carried_kernel(n_carried, *refs, probe):
 
 
 def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
-                   masks: jnp.ndarray, *, tile: int, depth: int, coop: str,
-                   interpret: bool, held=_held, valid=None) -> jnp.ndarray:
-    """(n,) bool: probes streaming rows of HBM-resident ``words``.
-    ``coop="subtile"`` sorts the keys by row in XLA so each row is fetched
-    once per tile; otherwise a depth-``depth`` DMA pipeline. Either way a
+                   masks: jnp.ndarray, *, tile: int, interpret: bool,
+                   held=_held, valid=None) -> jnp.ndarray:
+    """(n,) bool: probes streaming rows of HBM-resident ``words`` through a
+    ring of ``HBM_PROBE_DEPTH`` DMA slots (fewer for a smaller tile). A
     grid step stores its keys' missing bits in a ``(tile, lanes)`` scratch
     and tests the whole tile after its key loop.
 
@@ -767,31 +542,21 @@ def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
     slot's answer is meaningless."""
     n = starts.shape[0]
     lanes = row_lanes(words.shape[0])
-    s = masks.shape[1]
-    n_carried = None
     if valid is not None:
         order, starts, n_carried = _carried_first(valid, starts,
-                                                  words.shape[0], s, tile)
+                                                  words.shape[0],
+                                                  masks.shape[1], tile)
         masks = masks[order]
-    elif coop == "subtile":
-        order = jnp.argsort(starts)
-        starts, masks = starts[order], masks[order]
-    if coop == "subtile":
-        kern = functools.partial(_contains_hbm_coop_kernel, s=masks.shape[1],
-                                 tile=tile, held=held)
-        scratch = [pltpu.VMEM((1, lanes), jnp.uint32),      # shared row
-                   pltpu.SemaphoreType.DMA((1,))]
-    else:
-        depth = min(depth, tile)
-        kern = functools.partial(_contains_hbm_kernel, s=masks.shape[1],
-                                 tile=tile, depth=depth, held=held)
-        scratch = [pltpu.VMEM((depth, lanes), jnp.uint32),  # depth-slot ring
-                   pltpu.SemaphoreType.DMA((depth,))]
-    scratch.append(pltpu.VMEM((tile, lanes), jnp.uint32))   # missing bits
+    depth = min(HBM_PROBE_DEPTH, tile)
+    kern = functools.partial(_contains_hbm_kernel, s=masks.shape[1],
+                             tile=tile, depth=depth, held=held)
+    scratch = [pltpu.VMEM((depth, lanes), jnp.uint32),      # the row ring
+               pltpu.SemaphoreType.DMA((depth,)),
+               pltpu.VMEM((tile, lanes), jnp.uint32)]       # missing bits
     mt = _mask_tiles(masks, tile, lanes)
     operands = (_tiles(starts, tile), mt, row_view(words, lanes))
     out_shape = jax.ShapeDtypeStruct((n // tile, 1, tile), jnp.int32)
-    if n_carried is None:
+    if valid is None:
         out = pl.pallas_call(
             kern,
             grid=(n // tile,),
@@ -802,24 +567,22 @@ def probe_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
             scratch_shapes=scratch,
             interpret=interpret,
         )(*operands)
-    else:
-        out = pl.pallas_call(
-            functools.partial(_probe_carried_kernel, probe=kern),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(n // tile,),
-                in_specs=_key_specs(tile, mt, carried=True) + [
-                    pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec((1, 1, tile),
-                                       lambda i, n_carried: (i, 0, 0)),
-                scratch_shapes=scratch),
-            out_shape=out_shape,
-            interpret=interpret,
-        )(n_carried, *operands)
-    ok = out.reshape(n) != 0
-    if coop == "subtile" or valid is not None:   # back to the caller's order
-        ok = jnp.zeros((n,), jnp.bool_).at[order].set(ok)
-    return ok
+        return out.reshape(n) != 0
+    out = pl.pallas_call(
+        functools.partial(_probe_carried_kernel, probe=kern),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // tile,),
+            in_specs=_key_specs(tile, mt, carried=True) + [
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, tile),
+                                   lambda i, n_carried: (i, 0, 0)),
+            scratch_shapes=scratch),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(n_carried, *operands)
+    # back to the caller's order
+    return jnp.zeros((n,), jnp.bool_).at[order].set(out.reshape(n) != 0)
 
 
 def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
@@ -880,32 +643,19 @@ def update_rows_hbm(words: jnp.ndarray, starts: jnp.ndarray,
 
 
 def contains_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
-                 interpret: bool, tile: int = DEFAULT_TILE,
-                 depth: int = DEFAULT_DMA_DEPTH, coop: str = "none",
-                 mix: str = "full") -> jnp.ndarray:
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert depth in DMA_DEPTHS, f"depth={depth} not in {DMA_DEPTHS}"
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    starts, masks = fingerprints(spec, keys, mix)
-    return probe_rows_hbm(filt, starts, masks, tile=tile, depth=depth,
-                          coop=coop, interpret=interpret)
+                 interpret: bool, tile: int = DEFAULT_TILE) -> jnp.ndarray:
+    assert keys.shape[0] % tile == 0
+    starts, masks = fingerprints(spec, keys)
+    return probe_rows_hbm(filt, starts, masks, tile=tile,
+                          interpret=interpret)
 
 
 def add_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
-            interpret: bool, tile: int = DEFAULT_TILE, coop: str = "none",
-            mix: str = "full") -> jnp.ndarray:
-    # The HBM add is already fully cooperative: the row-sorted schedule
-    # reads and writes each unique row once per tile, with the tile's row
-    # DMAs in flight together, which is exactly the coop="subtile" memory
-    # schedule. The axis is accepted (and validated) so dispatch can thread
-    # a uniform plan; both values run the same kernel.
-    n = keys.shape[0]
-    assert n % tile == 0
-    assert coop in COOPS, coop
-    assert mix in MIXES, mix
-    starts, masks = fingerprints(spec, keys, mix)
+            interpret: bool, tile: int = DEFAULT_TILE) -> jnp.ndarray:
+    """Row-sorted bulk insert: each unique row of a tile is read and
+    written once, with the tile's row DMAs in flight together."""
+    assert keys.shape[0] % tile == 0
+    starts, masks = fingerprints(spec, keys)
     return update_rows_hbm(filt, starts, masks, tile=tile,
                            interpret=interpret)
 
@@ -921,104 +671,36 @@ def add_hbm(spec: FilterSpec, filt: jnp.ndarray, keys: jnp.ndarray, *,
 # get from fusing many small structures into one kernel. Adds are
 # valid-masked (zero mask = OR no-op) so routed/padded batches stay exact.
 
-def _bank_starts(spec: FilterSpec, keys, member, mix: str = "full"):
-    starts, masks = _fingerprints(spec, keys, mix=mix)
-    return starts + member * jnp.int32(spec.n_words), masks
-
-
-def _bank_contains_vmem_gather_kernel(keys_ref, member_ref, filt_ref, out_ref,
-                                      *, spec: FilterSpec, tile: int,
-                                      mix: str):
-    s = spec.s
-    starts, masks = _bank_starts(spec, keys_ref[...], member_ref[...], mix)
-    idx = starts[:, None] + jax.lax.broadcasted_iota(jnp.int32, (tile, s), 1)
-    words = jnp.take(filt_ref[...], idx, axis=0)         # (tile, s) gather
-    out_ref[...] = jnp.all((words & masks) == masks, axis=-1)
-
-
-def _bank_add_vmem_gather_kernel(keys_ref, member_ref, valid_ref, filt_ref,
-                                 out_ref, *, spec: FilterSpec, tile: int,
-                                 bank: int, mix: str):
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        out_ref[...] = filt_ref[...]
-
-    starts, masks = _bank_starts(spec, keys_ref[...], member_ref[...], mix)
-    masks = masks * valid_ref[...][:, None].astype(jnp.uint32)
-    blk = jax.lax.div(starts, jnp.int32(spec.s))    # member-offset block ids
-    out_ref[...] = V.or_rows(spec, out_ref[...], blk, masks,
-                             n_rows=bank * spec.n_blocks)
-
-
 def bank_contains_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
                        member: jnp.ndarray, layout: Layout, *,
-                       interpret: bool, tile: int = DEFAULT_TILE,
-                       probe: str = "loop", mix: str = "full") -> jnp.ndarray:
+                       interpret: bool,
+                       tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Flat routed membership against a (B, n_words) bank — one launch."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,)
-    assert probe in PROBES, probe
-    assert mix in MIXES, mix
-    _check_lowers("vmem", probe, "none", interpret)
-    B, flat = bank.shape[0], bank.reshape(-1)
     layout = layout.validate(spec, tile)
-    member = member.astype(jnp.int32)
-    if probe == "loop":
-        starts, masks = fingerprints(spec, keys, mix)
-        return probe_rows_vmem(
-            flat, starts + member * jnp.int32(spec.n_words), masks,
-            theta=layout.theta, tile=tile, interpret=interpret)
-    return pl.pallas_call(
-        functools.partial(_bank_contains_vmem_gather_kernel, spec=spec,
-                          tile=tile, mix=mix),
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),               # member ids
-            pl.BlockSpec((B * spec.n_words,), lambda i: (0,)),   # whole bank
-        ],
-        out_specs=pl.BlockSpec((tile,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.bool_),
-        interpret=interpret,
-    )(keys, member, flat)
+    starts, masks = fingerprints(spec, keys)
+    return probe_rows_vmem(
+        bank.reshape(-1),
+        starts + member.astype(jnp.int32) * jnp.int32(spec.n_words), masks,
+        theta=layout.theta, tile=tile, interpret=interpret)
 
 
 def bank_add_vmem(spec: FilterSpec, bank: jnp.ndarray, keys: jnp.ndarray,
                   member: jnp.ndarray, valid: jnp.ndarray, layout: Layout, *,
-                  interpret: bool, tile: int = DEFAULT_TILE,
-                  probe: str = "loop", mix: str = "full") -> jnp.ndarray:
+                  interpret: bool, tile: int = DEFAULT_TILE) -> jnp.ndarray:
     """Flat routed valid-masked insert into a (B, n_words) bank — one
     launch, sequential-grid RMW over the whole VMEM-resident bank."""
     n = keys.shape[0]
     assert n % tile == 0 and member.shape == (n,) and valid.shape == (n,)
-    assert probe in PROBES, probe
-    assert mix in MIXES, mix
-    _check_lowers("vmem", probe, "none", interpret)
-    B, flat = bank.shape[0], bank.reshape(-1)
     layout = layout.validate(spec, tile)
-    member = member.astype(jnp.int32)
-    if probe == "loop":
-        starts, masks = fingerprints(spec, keys, mix)
-        masks = masks * valid[:, None].astype(jnp.uint32)
-        out = update_rows_vmem(
-            flat, starts + member * jnp.int32(spec.n_words), masks,
-            theta=layout.theta, tile=tile, interpret=interpret)
-        return out.reshape(B, spec.n_words)
-    out = pl.pallas_call(
-        functools.partial(_bank_add_vmem_gather_kernel, spec=spec,
-                          tile=tile, bank=B, mix=mix),
-        grid=(n // tile,),
-        in_specs=[
-            pl.BlockSpec((tile, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),               # valid mask
-            pl.BlockSpec((B * spec.n_words,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((B * spec.n_words,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((B * spec.n_words,), jnp.uint32),
-        interpret=interpret,
-    )(keys, member, valid, flat)
-    return out.reshape(B, spec.n_words)
+    starts, masks = fingerprints(spec, keys)
+    masks = masks * valid[:, None].astype(jnp.uint32)
+    out = update_rows_vmem(
+        bank.reshape(-1),
+        starts + member.astype(jnp.int32) * jnp.int32(spec.n_words), masks,
+        theta=layout.theta, tile=tile, interpret=interpret)
+    return out.reshape(bank.shape[0], spec.n_words)
 
 
 # ---------------------------------------------------------------------------
@@ -1067,13 +749,11 @@ def update_rows_partitioned(words: jnp.ndarray, starts: jnp.ndarray,
 
 def add_partitioned(spec: FilterSpec, filt: jnp.ndarray,
                     keys_by_seg: jnp.ndarray, valid: jnp.ndarray,
-                    n_segments: int, *, interpret: bool,
-                    mix: str = "full") -> jnp.ndarray:
+                    n_segments: int, *, interpret: bool) -> jnp.ndarray:
     """keys_by_seg: (n_segments, capacity, 2); valid: (n_segments, capacity)."""
     assert spec.n_words % n_segments == 0
-    assert mix in MIXES, mix
     seg_words = spec.n_words // n_segments
-    starts, masks = fingerprints(spec, keys_by_seg.reshape(-1, 2), mix)
+    starts, masks = fingerprints(spec, keys_by_seg.reshape(-1, 2))
     masks = masks * valid.reshape(-1, 1).astype(jnp.uint32)
     return update_rows_partitioned(
         filt, jax.lax.rem(starts, jnp.int32(seg_words)), masks, n_segments,

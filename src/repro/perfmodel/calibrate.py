@@ -20,10 +20,10 @@ counts into wall time:
 measurement for this device kind if one exists, else the conservative
 per-device-kind defaults — it never measures unless asked
 (``measure=True`` or ``REPRO_CALIB_MEASURE=1``), so library code (the
-autotuner) can call it at trace time without timing anything. The fig4
-harness calls ``get_calibration(measure=True)`` once and persists the
-result (``REPRO_CALIB_CACHE`` env var, default
-``~/.cache/repro/calibration.json``).
+drift monitor, the cuckoo and quotient ``coop`` choice) can call it at
+trace time without timing anything. ``get_calibration(measure=True)``
+measures once and persists the result (``REPRO_CALIB_CACHE`` env var,
+default ``~/.cache/repro/calibration.json``).
 """
 from __future__ import annotations
 
@@ -221,7 +221,7 @@ def measure_step_us(grid: int = 16) -> float:
 def measure_calibration() -> Calibration:
     """Run the full microbench (~a second on CPU). On the CPU a probe that
     fails keeps the default for that constant; on any accelerator a failed
-    probe raises, because ranking plans on unmeasured constants there
+    probe raises, because predicting on unmeasured constants there
     would hide the failure."""
     from repro.kernels import ops
     kind = device_kind()

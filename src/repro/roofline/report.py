@@ -1,8 +1,7 @@
 """Aggregate TRANSFORMER dry-run JSON reports into the EXPERIMENTS.md
 tables (compile stats, collective counts, macro-model rooflines). The
-filter kernels have their own performance-model reporting in
-``repro.perfmodel`` + ``benchmarks/fig4_frontier``; the generic helpers
-both sides share live in :mod:`repro.roofline.report_utils`.
+filter kernels have their own performance model in ``repro.perfmodel``;
+the generic helpers live in :mod:`repro.roofline.report_utils`.
 
     PYTHONPATH=src python -m repro.roofline.report [--dir experiments/dryrun]
 """

@@ -1,10 +1,8 @@
 """Generic report/formatting helpers shared by the performance tooling.
 
 Nothing in here knows about transformers OR filters: these are the plain
-JSON-report-directory and human-unit formatters used by both
-``roofline.report`` (the transformer dry-run tables) and
-``repro.perfmodel`` / ``benchmarks.fig4_frontier`` (the filter
-speed-of-light report).
+JSON-report-directory and human-unit formatters that ``roofline.report``
+(the transformer dry-run tables) uses.
 """
 from __future__ import annotations
 

@@ -23,8 +23,8 @@ metrics registry (namespaced ``health()`` keys, checkpointed counters),
 span tracing of the submit/flush pipeline, and the §16 perfmodel drift
 monitor annotating every flush (DESIGN.md §17).
 
-See DESIGN.md §14 for the architecture and its recovery invariants, and
-``benchmarks/replay.py`` for the traffic-replay harness that measures it.
+See DESIGN.md §14 for the architecture and its recovery invariants; the
+benchmark's ``ycsb_c.service`` cell (``bench/``) measures it on the chip.
 """
 from repro.service.admission import (AdmissionController, AdmissionPolicy,
                                      SHED_REASONS, member_fill)

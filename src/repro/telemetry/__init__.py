@@ -15,8 +15,8 @@ One contract for the serving stack's counters, latencies and spans:
 * :func:`prometheus_text` — deterministic Prometheus text snapshots;
 * :class:`DriftMonitor` — every flush annotated with the perfmodel's
   :class:`~repro.perfmodel.OpCost` prediction and rolling
-  measured/predicted drift gauges that flag stale calibration or plan
-  regressions at runtime instead of only in ``fig4_frontier``;
+  measured/predicted drift gauges that flag stale calibration or
+  schedule regressions at runtime;
 * :class:`Telemetry` — the per-service bundle of all three.
 
 See DESIGN.md §17 for the determinism rules, the namespacing scheme and

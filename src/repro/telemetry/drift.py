@@ -1,15 +1,15 @@
 """Perfmodel drift detection: measured-vs-predicted flush cost at runtime.
 
-``fig4_frontier`` checks the calibrated performance model (PR 9,
-``repro.perfmodel``) against measurement *offline*; production needs the
-same check *continuously* — a plan regression (an extra pass, a lost
-fusion, a stale tuning cache) or stale machine calibration shows up as a
-drifting measured/predicted ratio long before anyone reruns a bench.
+The performance model (``repro.perfmodel``) predicts each flush's cost;
+the service checks that prediction *continuously* — a schedule
+regression (an extra pass, a lost fusion) or stale machine calibration
+shows up as a drifting measured/predicted ratio long before anyone reruns
+a benchmark.
 
 Every service flush is annotated with the model's :class:`OpCost`
-prediction for its exact configuration — (spec, op, regime, resolved
-plan, padded batch size, bank) — and the monitor maintains, per op, a
-rolling window of ``measured_us / predicted_us`` ratios:
+prediction for its exact configuration — (spec, op, regime, tile, padded
+batch size, bank) — and the monitor maintains, per op, a rolling window
+of ``measured_us / predicted_us`` ratios:
 
 * ``perfmodel.predicted_us{op=}`` / ``perfmodel.ceiling_us{op=}`` — the
   model's full prediction and its speed-of-light floor for one flush;
@@ -17,11 +17,10 @@ rolling window of ``measured_us / predicted_us`` ratios:
   mean: one GC pause or checkpoint stall must not trip the gauge);
 * ``perfmodel.drift.alert{op=}`` — 1.0 when the window holds at least
   ``min_samples`` ratios and the median sits outside
-  ``[1/tolerance, tolerance]``. The default tolerance mirrors the
-  warn-only model-sanity factor in ``benchmarks/run.py`` (the
-  expectation constants steer ranking, not absolute time — §16), so an
-  alert means a model term or the calibration is *structurally* wrong
-  for this host, not mistuned.
+  ``[1/tolerance, tolerance]``. The tolerance is loose (the model's
+  constants are not calibrated on the chip), so an alert means a model
+  term or the calibration is *structurally* wrong for this host, not
+  mistuned.
 
 Flush wall time is measured with the real clock even when the service
 runs on the virtual clock — drift is a report metric, not service state
@@ -48,29 +47,16 @@ class DriftConfig:
 
 
 def resolve_flush_plan(filt, op: str) -> dict:
-    """The concrete (regime, probe, coop, mix, depth, tile, bank) a
-    service flush of ``op`` executes under — "auto" fields resolved the
-    same way the kernels resolve them (perfmodel ``choose_coop`` for the
-    coop/mix pair), engine regime read off the backend (engines without a
-    regime are modeled as vmem)."""
-    from repro import perfmodel as PM
-
-    opts = filt.options
+    """The (regime, tile, bank) a service flush of ``op`` executes under:
+    the regime read off the engine (engines without one are modeled as
+    vmem), the tile the options pin (else the default), the bank size.
+    The Bloom and counting kernels run one schedule per regime, so
+    nothing else is left to resolve."""
     regime = getattr(filt.engine, "regime", None)
     if regime not in ("vmem", "hbm"):
         regime = "vmem"
-    tile = int(opts.tile) if opts.tile else 256
-    probe = opts.probe if opts.probe in ("loop", "gather") else "gather"
-    coop, mix = opts.coop, opts.mix
-    if coop not in ("none", "subtile") or mix not in ("full", "cheap"):
-        auto_coop, auto_mix = PM.choose_coop(filt.spec, op, regime, tile)
-        if coop not in ("none", "subtile"):
-            coop = auto_coop
-        if mix not in ("full", "cheap"):
-            mix = auto_mix
-    depth = int(opts.depth) if opts.depth else 2
-    return {"regime": regime, "probe": probe, "coop": coop, "mix": mix,
-            "depth": depth, "tile": tile,
+    return {"regime": regime,
+            "tile": int(filt.options.tile) if filt.options.tile else 256,
             "bank": max(int(filt.bank_size), 1)}
 
 
@@ -104,8 +90,6 @@ class DriftMonitor:
             from repro import perfmodel as PM
             plan = resolve_flush_plan(filt, op)
             cost = PM.op_cost(filt.spec, op, plan["regime"],
-                              probe=plan["probe"], coop=plan["coop"],
-                              mix=plan["mix"], depth=plan["depth"],
                               tile=plan["tile"], n_keys=int(n_keys),
                               bank=plan["bank"])
             calib = self._calibration()
@@ -153,5 +137,4 @@ class DriftMonitor:
                 "ceiling_us": round(ceil_us, 3),
                 "measured_us": round(measured_us, 3),
                 "drift_ratio": round(ratio, 4),
-                "regime": plan["regime"], "probe": plan["probe"],
-                "coop": plan["coop"], "mix": plan["mix"]}
+                "regime": plan["regime"]}

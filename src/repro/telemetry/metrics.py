@@ -62,8 +62,7 @@ def nearest_rank(samples, q: float) -> float:
     the smallest observed sample s.t. at least q% of samples are <= it.
     Interpolating estimators invent values between the two largest
     samples — exactly where p999 lives — so tails are reported as rank
-    statistics on actual observations. The single shared implementation
-    behind both ``benchmarks.common.percentile`` and
+    statistics on actual observations. The implementation behind
     :meth:`Histogram.percentile`."""
     a = sorted(float(s) for s in _flatten(samples))
     if not a:

@@ -151,7 +151,7 @@ def test_bank_past_vmem_reports_the_xla_reference():
     ops.bloom_bank_add(spec, jnp.zeros((big, spec.n_words), jnp.uint32),
                        keys, member)
     ops.bloom_bank_add(spec, jnp.zeros((small, spec.n_words), jnp.uint32),
-                       keys, member, probe="loop", mix="full")
+                       keys, member)
     after = ops.dispatched()
     for impl in ("xla", "pallas-vmem"):
         key = ("bank_add", impl)

@@ -12,9 +12,8 @@ Pins the contracts DESIGN.md §13 documents:
 * bulk contains compiles to a **single pallas_call**;
 * API integration: registry claims, capability flags + memory-cost
   reporting, sizing helpers, checkpoint round-trip, banks (batched and
-  routed), dedup consumers, and the tune-plan cache-key fix.
+  routed) and dedup consumers.
 """
-import os
 
 import numpy as np
 import pytest
@@ -376,7 +375,7 @@ def test_bank_select_scatter_state():
 
 
 # ---------------------------------------------------------------------------
-# Consumers + tuning-key satellite
+# Consumers
 # ---------------------------------------------------------------------------
 
 def test_streaming_dedup_cuckoo_readmits_after_eviction():
@@ -412,27 +411,6 @@ def test_tenant_dedup_cuckoo_engine():
     td.filt = td.filt.remove(sigs[:12], tenants=np.asarray(tenants[:12]))
     keep3 = td.dedupe_batch(docs[:12], tenants[:12])
     assert len(keep3) == 12                          # forgotten -> fresh
-
-
-def test_tune_plan_key_disambiguates_variants(tmp_path, monkeypatch):
-    """Satellite: cuckoo, quotient and sbf plans for the same geometry —
-    and two slot/split geometries at the same m — get distinct cache
-    keys (the quotient __str__ spells out its q/r split and lane)."""
-    from repro.core import tuning
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "t.json"))
-    sbf = FilterSpec(variant="sbf", m_bits=1 << 14, k=8, block_bits=64)
-    ck8 = spec_of(1 << 14, slot_bits=8)
-    ck16 = spec_of(1 << 14, slot_bits=16)
-    qf8 = FilterSpec(variant="quotient", m_bits=1 << 14, k=1, slot_bits=8,
-                     r_bits=5)
-    qf16 = FilterSpec(variant="quotient", m_bits=1 << 14, k=1, slot_bits=16,
-                      r_bits=5)
-    qf16b = FilterSpec(variant="quotient", m_bits=1 << 14, k=1, slot_bits=16,
-                       r_bits=9)
-    keys = {tuning._plan_key(s, "contains", "vmem", "structural", 256)
-            for s in (sbf, ck8, ck16, qf8, qf16, qf16b)}
-    assert len(keys) == 6
-    assert os.environ["REPRO_TUNING_CACHE"]          # env respected
 
 
 def test_empty_batches_and_repr():

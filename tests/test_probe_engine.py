@@ -1,14 +1,11 @@
-"""PR-3 probe-engine contracts: whole-tile gather kernels, depth-tunable
-HBM pipeline, device-resident partitioned add, cached-jit donation layer,
-and the tile-aware tuning cache.
+"""Probe-engine contracts: the Θ layouts of the VMEM walk, the HBM probe's
+fixed DMA ring, the deferred tile test shared by the probe kernels, the
+device-resident partitioned add and the cached-jit donation layer.
 
-The parity sweeps pin the acceptance criterion "gather-probe kernels are
-bit-identical to kernels/ref across variants x regimes x Θ x probe
-strategy"; the jit/scan tests prove the partitioned bulk add never leaves
-the device (no host numpy partition, no callbacks in the jaxpr).
+The jit/scan tests prove the partitioned bulk add never leaves the device
+(no host numpy partition, no callbacks in the jaxpr).
 """
 import functools
-import os
 import re
 
 import numpy as np
@@ -18,11 +15,10 @@ import pytest
 
 from repro.core import hashing as H
 from repro.core import partition as P
-from repro.core import tuning
 from repro.core import variants as V
 from repro.kernels import ops, ref
 from repro.kernels import sbf as sbf_k
-from repro.kernels.sbf import Layout, default_layout
+from repro.kernels.sbf import Layout
 
 M = 1 << 16
 
@@ -31,83 +27,63 @@ def _keys(n, seed=0):
     return jnp.asarray(H.random_u64x2(n, seed=seed))
 
 
-SWEEP_SPECS = [
-    V.FilterSpec("sbf", M, 8, block_bits=256),
-    V.FilterSpec("sbf", M, 16, block_bits=512),
-    V.FilterSpec("bbf", M, 8, block_bits=256),
-    V.FilterSpec("rbbf", M, 4),
-    V.FilterSpec("csbf", M, 8, block_bits=512, z=2),
-]
-
-
 # ---------------------------------------------------------------------------
-# Whole-tile gather parity (vmem regime)
+# VMEM regime: the Θ layouts of the per-key walk
 # ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("spec", SWEEP_SPECS, ids=str)
-@pytest.mark.parametrize("probe", ["loop", "gather"])
-def test_gather_probe_matches_ref(spec, probe):
-    keys = _keys(900, seed=5)
-    f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
-    f_ker = ops.bloom_add(spec, V.init(spec), keys, probe=probe)
-    np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))
-    c_ker = ops.bloom_contains(spec, f_ref, keys, probe=probe)
-    np.testing.assert_array_equal(
-        np.asarray(c_ker), np.asarray(ref.bloom_contains_ref(spec, f_ref, keys)))
-
 
 @pytest.mark.parametrize("theta,block_bits", [(1, 256), (1, 512), (2, 256),
                                               (8, 128)])
-@pytest.mark.parametrize("probe", ["loop", "gather"])
-def test_gather_probe_layout_invariance(theta, block_bits, probe):
-    """The gather engine ignores Θ — results must match the loop path
-    under every layout (layouts affect schedule, never semantics)."""
+def test_layout_invariance(theta, block_bits):
+    """Θ keys a loop trip changes the schedule, never the words or the
+    answers: every layout matches the reference on a padded batch."""
     spec = V.FilterSpec("sbf", M, 8, block_bits=block_bits)
     keys = _keys(513, seed=9)           # non-tile-multiple: padding on
     lay = Layout(theta)
     f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
-    f_ker = ops.bloom_add(spec, V.init(spec), keys, layout=lay, probe=probe)
+    f_ker = ops.bloom_add(spec, V.init(spec), keys, layout=lay,
+                          regime="vmem")
     np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))
-    c = ops.bloom_contains(spec, f_ref, keys, layout=lay, probe=probe)
+    c = ops.bloom_contains(spec, f_ref, keys, layout=lay, regime="vmem")
     np.testing.assert_array_equal(
         np.asarray(c), np.asarray(ref.bloom_contains_ref(spec, f_ref, keys)))
 
 
-@pytest.mark.parametrize("probe", ["loop", "gather"])
-def test_counting_gather_matches_reference(probe):
-    spec = V.FilterSpec("countingbf", M, 8, block_bits=256)
-    keys = _keys(700, seed=21)
-    dups = jnp.concatenate([keys, keys[:350]])      # non-idempotent updates
-    f_ref = V.counting_add(spec, V.init(spec), dups)
-    f_ker = ops.counting_add(spec, V.init(spec), dups, probe=probe)
-    np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))
-    r_ref = V.counting_remove(spec, f_ref, keys[:200])
-    r_ker = ops.counting_remove(spec, f_ker, keys[:200], probe=probe)
-    np.testing.assert_array_equal(np.asarray(r_ker), np.asarray(r_ref))
-    c_ker = ops.counting_contains(spec, f_ref, keys, probe=probe)
-    np.testing.assert_array_equal(
-        np.asarray(c_ker), np.asarray(V.counting_contains(spec, f_ref, keys)))
-
-
 # ---------------------------------------------------------------------------
-# HBM regime: depth-tunable contains, coalesced add
+# HBM regime: the probe's fixed ring, the coalesced add
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("depth,n", [pytest.param(d, 512, id=str(d))
-                                     for d in (1, 2, 4, 8)] + [
-    pytest.param(8, 8, id="8-tile_clamped_to_8"),
-    pytest.param(2, 300, id="2-padded_300"),
-    pytest.param(8, 300, id="8-padded_300")])
-def test_hbm_contains_depth_sweep(depth, n):
-    """Tiles of 256, a tile clamped to 8 keys (one unrolled trip, depth
-    equal to the tile) and a batch padded by ``_pad_keys``."""
-    spec = V.FilterSpec("sbf", M, 8, block_bits=256)
-    keys = _keys(512, seed=31)
-    f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
-    probe = jnp.concatenate([keys[: n // 2], _keys(n - n // 2, seed=32)])
-    c = ops.bloom_contains(spec, f_ref, probe, regime="hbm", depth=depth)
-    want = np.asarray(ref.bloom_contains_ref(spec, f_ref, probe))
-    np.testing.assert_array_equal(np.asarray(c), want)
+@pytest.mark.parametrize("n,tile", [(6, 8), (16, 16), (27, 32), (600, 256)],
+                         ids=["tile8", "tile16", "tile32", "tile256"])
+@pytest.mark.parametrize("counting", [False, True], ids=["bits", "counts"])
+def test_hbm_probe_ring_over_tiles(counting, n, tile, monkeypatch):
+    """The ring of ``HBM_PROBE_DEPTH`` row slots over every key tile
+    ``_clamp_tile`` produces: at a tile of 8 the ring is as deep as the
+    tile (its prologue starts 7 of the tile's 8 rows), above it the ring
+    wraps within the tile. Half the batch is present, half absent."""
+    assert sbf_k.HBM_PROBE_DEPTH == 8
+    spec = V.FilterSpec("countingbf" if counting else "sbf", M, 8,
+                        block_bits=256)
+    tiles = []
+    real = sbf_k.probe_rows_hbm
+
+    def probe_rows_hbm(*a, **k):
+        tiles.append(k["tile"])
+        return real(*a, **k)
+
+    monkeypatch.setattr(sbf_k, "probe_rows_hbm", probe_rows_hbm)
+    present = _keys(n // 2, seed=31)
+    probe = jnp.concatenate([present,
+                             jnp.asarray(H.probe_u64x2(n - n // 2, seed=32))])
+    if counting:
+        f = V.counting_add(spec, V.init(spec), present)
+        got = ops.counting_contains(spec, f, probe, regime="hbm")
+        want = np.asarray(V.counting_contains(spec, f, probe))
+    else:
+        f = ref.bloom_add_ref(spec, V.init(spec), present)
+        got = ops.bloom_contains(spec, f, probe, regime="hbm")
+        want = np.asarray(ref.bloom_contains_ref(spec, f, probe))
+    assert tiles == [tile]
+    np.testing.assert_array_equal(np.asarray(got), want)
     assert want[: n // 2].all() and not want.all()
 
 
@@ -121,40 +97,23 @@ def test_hbm_coalesced_add_duplicate_blocks():
     np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))
 
 
-@pytest.mark.parametrize("depth", [2, 8])
-def test_counting_hbm_depth(depth):
-    spec = V.FilterSpec("countingbf", M, 8, block_bits=256)
-    keys = _keys(300, seed=13)
-    f_ref = V.counting_add(spec, V.init(spec), keys)
-    f_ker = ops.counting_add(spec, V.init(spec), keys, regime="hbm")
-    np.testing.assert_array_equal(np.asarray(f_ker), np.asarray(f_ref))
-    c = ops.counting_contains(spec, f_ref, keys, regime="hbm", depth=depth)
-    np.testing.assert_array_equal(
-        np.asarray(c), np.asarray(V.counting_contains(spec, f_ref, keys)))
-
-
 # ---------------------------------------------------------------------------
 # The deferred tile test shared by the probe kernels
 # ---------------------------------------------------------------------------
 
-PROBE_PATHS = [dict(regime="vmem"), dict(regime="hbm", coop="none"),
-               dict(regime="hbm", coop="subtile")]
-
-
-def _probe_rows(words, starts, masks, path, held):
-    if path["regime"] == "vmem":
+def _probe_rows(words, starts, masks, regime, held):
+    if regime == "vmem":
         return sbf_k.probe_rows_vmem(words, starts, masks, theta=1, tile=256,
                                      interpret=True, held=held)
-    return sbf_k.probe_rows_hbm(words, starts, masks, tile=256, depth=8,
-                                coop=path["coop"], interpret=True, held=held)
+    return sbf_k.probe_rows_hbm(words, starts, masks, tile=256,
+                                interpret=True, held=held)
 
 
-@pytest.mark.parametrize("path", PROBE_PATHS, ids=lambda p: "-".join(
-    p.values()))
+@pytest.mark.parametrize("regime", ["vmem", "hbm"])
 @pytest.mark.parametrize("n_words", [128, 4096], ids=["one_row", "32_rows"])
 @pytest.mark.parametrize("edge", ["lane0", "last_lanes"])
 @pytest.mark.parametrize("counting", [False, True], ids=["bits", "counts"])
-def test_probe_rows_blocks_at_row_edges(path, n_words, edge, counting):
+def test_probe_rows_blocks_at_row_edges(regime, n_words, edge, counting):
     """Every key's block at lane 0 or at ``lanes - s`` of its row, about
     half of them present: each answer is the AND over exactly its own s
     lanes of the row."""
@@ -174,15 +133,14 @@ def test_probe_rows_blocks_at_row_edges(path, n_words, edge, counting):
     want = ((h[starts[:, None] + np.arange(s)] & masks) == masks).all(-1)
     assert want.any() and not want.all()
     got = _probe_rows(jnp.asarray(words), jnp.asarray(starts, jnp.int32),
-                      jnp.asarray(masks), path, held)
+                      jnp.asarray(masks), regime, held)
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-@pytest.mark.parametrize("path", PROBE_PATHS, ids=lambda p: "-".join(
-    p.values()))
+@pytest.mark.parametrize("regime", ["vmem", "hbm"])
 @pytest.mark.parametrize("fill", ["empty", "full", "built"])
 @pytest.mark.parametrize("variant", ["sbf", "countingbf"])
-def test_contains_whole_tiles_match_reference(path, fill, variant):
+def test_contains_whole_tiles_match_reference(regime, fill, variant):
     """Tiles all absent (an empty filter), all present (every bit or
     every counter set) and mixed, through ``ops`` with a batch that
     ``_pad_keys`` pads, against ``ref`` / ``V.counting_contains``."""
@@ -196,14 +154,11 @@ def test_contains_whole_tiles_match_reference(path, fill, variant):
         word = 0 if fill == "empty" else (0x11111111 if counting
                                           else 0xFFFFFFFF)
         f = jnp.full((spec.storage_words,), word, jnp.uint32)
-    kw = dict(regime=path["regime"])
-    if "coop" in path:
-        kw["coop"] = path["coop"]
     if counting:
-        got = ops.counting_contains(spec, f, keys, **kw)
+        got = ops.counting_contains(spec, f, keys, regime=regime)
         want = V.counting_contains(spec, f, keys)
     else:
-        got = ops.bloom_contains(spec, f, keys, **kw)
+        got = ops.bloom_contains(spec, f, keys, regime=regime)
         want = ref.bloom_contains_ref(spec, f, keys)
     want = np.asarray(want)
     np.testing.assert_array_equal(np.asarray(got), want)
@@ -238,7 +193,7 @@ def _mosaic_loop_bodies(fn, *args, monkeypatch, capsys):
     return mosaic, bodies
 
 
-@pytest.mark.parametrize("kernel", ["hbm", "hbm_coop", "vmem"])
+@pytest.mark.parametrize("kernel", ["hbm", "vmem"])
 def test_probe_key_loop_has_no_per_key_reduction(kernel, monkeypatch,
                                                  capsys):
     """The probe kernels' key loops hold no reduction and no vector-to-
@@ -254,9 +209,7 @@ def test_probe_key_loop_has_no_per_key_reduction(kernel, monkeypatch,
                 jax.ShapeDtypeStruct((4096, spec.s), jnp.uint32))
     else:
         spec = V.FilterSpec("sbf", 1 << 28, 8)
-        fn = functools.partial(
-            sbf_k.contains_hbm, spec, interpret=False, depth=8,
-            coop="subtile" if kernel == "hbm_coop" else "none")
+        fn = functools.partial(sbf_k.contains_hbm, spec, interpret=False)
         args = (jax.ShapeDtypeStruct((spec.n_words,), jnp.uint32), keys)
     mosaic, bodies = _mosaic_loop_bodies(fn, *args, monkeypatch=monkeypatch,
                                          capsys=capsys)
@@ -384,59 +337,3 @@ def test_counting_update_jit_donation_path():
     f2 = ops.counting_update_jit(spec, f, keys[:100], "remove", donate=True)
     np.testing.assert_array_equal(
         np.asarray(f2), np.asarray(V.counting_remove(spec, f_ref, keys[:100])))
-
-
-# ---------------------------------------------------------------------------
-# Tuning: tile-aware cache key, plan sweep, disk persistence
-# ---------------------------------------------------------------------------
-
-def test_tune_layout_tile_in_cache_key():
-    """A layout tuned for tile=256 must not leak into tile=8 (where Θ > 8
-    candidates are invalid): each tile re-runs validation."""
-    spec = V.FilterSpec("sbf", M, 16, block_bits=512)
-    lay256, _ = tuning.tune_layout(spec, "contains", tile=256)
-    lay8, _ = tuning.tune_layout(spec, "contains", tile=8)
-    assert 256 % lay256.theta == 0
-    assert 8 % lay8.theta == 0          # would fail if the 256 entry leaked
-    lay8.validate(spec, 8)
-
-
-def test_tune_plan_axes_and_disk_cache(tmp_path, monkeypatch):
-    monkeypatch.setenv("REPRO_TUNING_CACHE", str(tmp_path / "tuning.json"))
-    tuning.tune_plan.cache_clear()
-    spec = V.FilterSpec("sbf", M, 8, block_bits=256)
-    plan = tuning.tune_plan(spec, "contains", regime="vmem", tile=256)
-    assert plan.probe in ("loop", "gather")
-    assert plan.depth in tuning.TUNABLE_DEPTHS
-    assert plan.n_segments in tuning.TUNABLE_SEGMENTS
-    plan.layout.validate(spec, 256)
-    assert os.path.exists(str(tmp_path / "tuning.json"))
-    # a fresh in-process cache must round-trip through the disk entry
-    tuning.tune_plan.cache_clear()
-    again = tuning.tune_plan(spec, "contains", regime="vmem", tile=256)
-    assert again == plan
-
-
-def test_auto_probe_dispatch_runs():
-    """probe="auto" resolves through tune_plan inside dispatch (trace-time
-    static) and still matches the reference."""
-    spec = V.FilterSpec("sbf", M, 8, block_bits=256)
-    keys = _keys(400, seed=83)
-    f_ref = ref.bloom_add_ref(spec, V.init(spec), keys)
-    f = ops.bloom_add(spec, V.init(spec), keys, probe="auto")
-    np.testing.assert_array_equal(np.asarray(f), np.asarray(f_ref))
-    c = ops.bloom_contains(spec, f, keys, probe="auto")
-    assert bool(np.asarray(c).all())
-
-
-def test_api_options_thread_probe_and_depth():
-    """BackendOptions.probe/depth reach the kernels through the Filter API."""
-    from repro import api
-    f = api.make_filter("sbf", m_bits=M, k=8, backend="pallas-vmem",
-                        probe="gather")
-    keys = _keys(300, seed=89)
-    f = f.add(keys)
-    assert bool(np.asarray(f.contains(keys)).all())
-    g = api.make_filter("sbf", m_bits=M, k=8, backend="pallas-hbm", depth=4)
-    g = g.add(keys)
-    assert bool(np.asarray(g.contains(keys)).all())

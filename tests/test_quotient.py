@@ -16,8 +16,8 @@ Pins the contracts DESIGN.md §15 documents:
 * bulk contains compiles to a **single pallas_call**;
 * API integration: registry claims + capability flags
   (supports_merge/supports_resize), workload selection, banks (batched,
-  routed, valid-masked), checkpoint round-trip, insert-failure signal,
-  and the tune-plan cache-key disambiguation.
+  routed, valid-masked), checkpoint round-trip and the insert-failure
+  signal.
 """
 import numpy as np
 import pytest

@@ -76,15 +76,17 @@ def test_vmem_kernels_compile_at_budget_edge(one_chip, op):
     entry = sbf.contains_vmem if op == "contains" else sbf.add_vmem
     fn = functools.partial(entry, VMEM_EDGE,
                            layout=default_layout(VMEM_EDGE, op),
-                           interpret=False, probe="loop", coop="none")
+                           interpret=False)
     c = _compile(fn, _words(VMEM_EDGE, one_chip), _keys(one_chip))
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("coop", ["none", "subtile"])
-def test_hbm_contains_compiles_at_1gib(one_chip, coop):
+@pytest.mark.parametrize("tile", [8, 256])
+def test_hbm_contains_compiles_at_1gib(one_chip, tile):
+    """The probe's ring of ``HBM_PROBE_DEPTH`` slots, at a full tile and
+    at the 8-key tile a small batch is clamped to."""
     fn = functools.partial(sbf.contains_hbm, HBM_1GIB, interpret=False,
-                           coop=coop)
+                           tile=tile)
     c = _compile(fn, _words(HBM_1GIB, one_chip), _keys(one_chip))
     assert "tpu_custom_call" in c.as_text()
     # the row view of the filter is a bitcast, not a 1 GiB copy
@@ -132,12 +134,12 @@ def test_bank_kernels_compile(one_chip, op):
     layout = default_layout(BANK, op)
     if op == "contains":
         fn = functools.partial(sbf.bank_contains_vmem, BANK, layout=layout,
-                               interpret=False, probe="loop")
+                               interpret=False)
         c = _compile(fn, bank, keys, member)
     else:
         valid = jax.ShapeDtypeStruct((4096,), jnp.uint8, sharding=one_chip)
         fn = functools.partial(sbf.bank_add_vmem, BANK, layout=layout,
-                               interpret=False, probe="loop")
+                               interpret=False)
         c = _compile(fn, bank, keys, member, valid)
     assert "tpu_custom_call" in c.as_text()
 
@@ -174,7 +176,7 @@ def test_counting_kernels_compile(one_chip, regime, op):
             c = _compile(fn, words, keys, member)
         else:
             fn = functools.partial(C.bank_update_vmem, spec, op="add",
-                                   interpret=False, probe="loop")
+                                   interpret=False)
             c = _compile(fn, words, keys, member, valid)
     elif op == "contains":
         entry = C.contains_vmem if regime == "vmem" else C.contains_hbm
